@@ -255,6 +255,9 @@ def measure_config(name, mesh_axes, cfg_kwargs, B, S):
 # 8-chip host. Ring all-reduce moves 2(n-1)/n x payload per chip.
 ASSUMPTIONS = {
     "chip": "TPU v5e",
+    # jax.devices()[0].device_kind as that chip reports it (chip run,
+    # PR 21); the rates below describe this kind and no other
+    "device_kind": "TPU v5 lite",
     "bf16_peak_tflops": 197.0,
     # Peak matmul throughput by dominant program dtype. bf16 is the
     # datasheet number; f32 runs the MXU at half rate; int8 doubles it
@@ -277,12 +280,32 @@ ASSUMPTIONS = {
 }
 
 
+def modeled_assumptions():
+    """ASSUMPTIONS, after checking that they describe the attached
+    device. On the CPU backend the table is a stated model of a v5e
+    (the CPU contract gates price against it); on any other backend a
+    ``device_kind`` the table does not name is an error — an MFU or a
+    roofline share against another chip's peak is a wrong number, not
+    an approximate one."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "cpu" \
+            and dev.device_kind != ASSUMPTIONS["device_kind"]:
+        raise ValueError(
+            "comm_model.ASSUMPTIONS holds the rates of %r; the attached "
+            "device is %r (%s) and has no entry"
+            % (ASSUMPTIONS["device_kind"], dev.device_kind,
+               dev.platform))
+    return ASSUMPTIONS
+
+
 def peak_tflops(dtype="bf16"):
-    """Peak TFLOP/s for a program whose dominant dtype is ``dtype``
-    (a short key: ``bf16``/``f16``/``f32``/``int8``). Unknown dtypes
-    fall back to the bf16 peak — the conservative default the modeled
-    compute time has always used."""
-    table = ASSUMPTIONS["peak_tflops"]
+    """Peak TFLOP/s of the attached device for a program whose dominant
+    dtype is ``dtype`` (a short key: ``bf16``/``f16``/``f32``/``int8``).
+    Unknown dtypes fall back to the bf16 peak — the conservative
+    default the modeled compute time has always used; an unknown
+    DEVICE raises (``modeled_assumptions``)."""
+    table = modeled_assumptions()["peak_tflops"]
     return table.get(str(dtype), table["bf16"])
 
 

@@ -75,6 +75,8 @@ def run(dim, layers, seq, batch=1, iters=3):
     n_params = sum(int(np.prod(p.shape))
                    for p in jax.tree_util.tree_leaves(state[0]))
     return {
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
         "dim": dim, "layers": layers, "seq": seq, "batch": batch,
         "params_m": round(n_params / 1e6, 1),
         "tokens_per_sec": round(batch * seq / dt, 1),
@@ -90,6 +92,13 @@ def main():
                     help="dim,layers,seq triples (default: the sweep)")
     ap.add_argument("--iters", type=int, default=3)
     args = ap.parse_args()
+    import jax
+    from mxnet_tpu.runtime import use_compilation_cache
+    if jax.default_backend() != "tpu":
+        sys.exit("longcontext.py measures one TPU chip; JAX's default "
+                 "backend here is %r" % jax.default_backend())
+    use_compilation_cache()
+    failed = []
     for raw in (args.configs or
                 ["%d,%d,%d" % c for c in DEFAULT_CONFIGS]):
         try:
@@ -97,9 +106,14 @@ def main():
             print(json.dumps(run(dim, layers, seq, iters=args.iters)),
                   flush=True)
         except Exception as e:  # noqa: BLE001 — an OOM or malformed
-            # config must not kill the remaining sweep
+            # config must not kill the remaining sweep; it fails the
+            # run once the sweep is through
             print(json.dumps({"config": raw, "error": str(e)[:200]}),
                   flush=True)
+            failed.append(raw)
+    if failed:
+        sys.exit("longcontext: %d of the configs failed: %s"
+                 % (len(failed), " ".join(failed)))
 
 
 if __name__ == "__main__":

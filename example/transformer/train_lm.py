@@ -53,7 +53,9 @@ def main():
     import mxnet_tpu  # noqa: F401
     from mxnet_tpu.parallel import create_mesh
     from mxnet_tpu.parallel import transformer as T
+    from mxnet_tpu.runtime import use_compilation_cache
 
+    use_compilation_cache()
     n_needed = args.dp * args.tp * args.sp * args.pp * args.ep
     devs = jax.devices()
     assert len(devs) >= n_needed, \

@@ -151,13 +151,12 @@ def _assumptions():
     """The hardware model (lazy: ``benchmark/comm_model.py`` loaded by
     path through the fused step's cached loader; ``None`` in an
     installed wheel without the benchmark dir — rows then carry counts
-    and times but no memory-bandwidth utilization)."""
-    try:
-        from ..gluon.fused_step import _load_comm_model
-        cm = _load_comm_model()
-        return cm.ASSUMPTIONS if cm is not None else None
-    except Exception:
-        return None
+    and times but no memory-bandwidth utilization). Raises on a
+    non-CPU device the table does not describe
+    (``comm_model.modeled_assumptions``)."""
+    from ..gluon.fused_step import _load_comm_model
+    cm = _load_comm_model()
+    return cm.modeled_assumptions() if cm is not None else None
 
 
 # -- feeds -------------------------------------------------------------------

@@ -2,9 +2,10 @@
 
 Analog of the reference's libmxnet.so discovery + ctypes FFI
 (ref: python/mxnet/libinfo.py find_lib_path, python/mxnet/base.py _load_lib):
-locates ``libmxnet_tpu.so`` next to the package, builds it from ``src/``
-with g++ on first use if missing (the reference ships a prebuilt binary;
-here the toolchain is part of the environment), and exposes the C ABI with
+locates ``libmxnet_tpu.so`` next to the package, runs ``make -C src`` on
+first use so the binary follows the sources (the reference ships a
+prebuilt binary; here the toolchain is part of the environment), and
+exposes the C ABI with
 the reference's error convention — nonzero return → raise with
 ``MXTGetLastError()``.
 
@@ -34,9 +35,12 @@ def _src_dir():
 
 
 def _build():
+    """Bring ``libmxnet_tpu.so`` up to date with ``src/``: ``make``
+    decides, and is a no-op when the binary is current, so a stale
+    binary left in a working tree is never used as it is. False when
+    the build failed — which is said aloud, because from then on every
+    native path runs its pure-Python fallback."""
     src = _src_dir()
-    if not os.path.isdir(src):
-        return False
     try:
         import fcntl
         # serialize concurrent first-use builds (forked dataloader workers,
@@ -45,14 +49,16 @@ def _build():
         with open(os.path.join(src, ".build.lock"), "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
             try:
-                if not os.path.exists(_lib_path()):
-                    subprocess.run(["make", "-C", src], check=True,
-                                   capture_output=True, timeout=120)
+                subprocess.run(["make", "-C", src], check=True,
+                               capture_output=True, timeout=120)
             finally:
                 fcntl.flock(lock, fcntl.LOCK_UN)
-        return os.path.exists(_lib_path())
-    except Exception as e:  # compiler missing / build error → fallback
-        logging.debug("native build failed: %s", e)
+        return True
+    except (OSError, subprocess.SubprocessError) as e:
+        logging.warning(
+            "native library build failed (%s: %s); using the pure-Python "
+            "fallbacks", type(e).__name__,
+            (getattr(e, "stderr", None) or str(e))[-500:])
         return False
 
 
@@ -120,12 +126,17 @@ def get_lib():
         if _getenv("MXNET_TPU_NO_NATIVE", "0") == "1":
             return None
         path = _lib_path()
-        if not os.path.exists(path) and not _build():
+        # with the sources at hand the binary follows them; without
+        # (an installed wheel) the shipped binary is what there is
+        if os.path.isdir(_src_dir()) and not _build():
+            return None
+        if not os.path.exists(path):
             return None
         try:
             _LIB = _declare(ctypes.CDLL(path))
         except OSError as e:
-            logging.debug("native load failed: %s", e)
+            logging.warning("native library load failed (%s); using the "
+                            "pure-Python fallbacks", e)
             _LIB = None
     return _LIB
 

@@ -137,9 +137,9 @@ _profiler.register_stats_provider("fused_step", stats, reset_stats)
 
 # benchmark/comm_model.py is the ONE home of the wire-time formula and
 # the v5e model assumptions (deduped there by the PR 7 review); it
-# lives beside the package, not inside it, so load it by path — and
-# degrade to attribution-less operation when the tree layout differs
-# (an installed wheel without the benchmark/ dir).
+# lives beside the package, not inside it, so load it by path. Only a
+# tree without the file (an installed wheel without the benchmark/ dir)
+# runs attribution-less; a file that is there and fails to load raises.
 _COMM_MODEL_UNSET = object()
 _COMM_MODEL = _COMM_MODEL_UNSET
 
@@ -147,18 +147,18 @@ _COMM_MODEL = _COMM_MODEL_UNSET
 def _load_comm_model():
     global _COMM_MODEL
     if _COMM_MODEL is _COMM_MODEL_UNSET:
-        try:
-            import importlib.util
-            path = os.path.join(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))),
-                "benchmark", "comm_model.py")
-            spec = importlib.util.spec_from_file_location(
-                "_mxtpu_comm_model", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            _COMM_MODEL = mod
-        except Exception:
+        import importlib.util
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))),
+            "benchmark", "comm_model.py")
+        if not os.path.isfile(path):
             _COMM_MODEL = None
+            return None
+        spec = importlib.util.spec_from_file_location(
+            "_mxtpu_comm_model", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _COMM_MODEL = mod
     return _COMM_MODEL
 
 
@@ -272,6 +272,8 @@ class FusedTrainStep:
         self._key_counts = {}   # signature -> times seen (warming)
         self._partial_keys = set()  # configs compiled (retrace detection)
         self._failed_keys = set()   # signatures that failed to trace
+        self.last_trace_error = None  # the exception behind the most
+        #                               recent fallback:trace-failed
         self.last_mode = None   # how the previous call executed
         self._aot = None        # (compiled, cost, hlo) from the last AOT
         self._ckey = None       # full signature key of the in-flight
@@ -522,14 +524,18 @@ class FusedTrainStep:
             # a detected anomaly, not a trace failure: the batch must
             # NOT silently re-run on the eager path
             raise
-        except Exception:
+        except Exception as e:
             # trace-incompatible step (data-dependent control flow, host
             # callback, ...): remember the signature and run the genuine
-            # eager path — never a crash
+            # eager path — never a crash, and never a silent one: the
+            # exception stays on the step, is warned once and lands in
+            # the flight record, because a compiler refusal on the chip
+            # looks exactly like this
             if len(self._failed_keys) >= 4 * _CACHE_CAP:
                 self._failed_keys.clear()  # shape churn must not leak keys
             self._failed_keys.add(key)
             _STATS["fallbacks"] += 1
+            self._note_trace_failure(e)
             return self._eager_step(nd_args, batch_size,
                                     ignore_stale_grad), \
                 "fallback:trace-failed"
@@ -546,6 +552,24 @@ class FusedTrainStep:
             self._attr_models.pop(key, None)
             _STATS["attr_errors"] += 1
         return loss, "compile"
+
+    def _note_trace_failure(self, exc):
+        """Keep the evidence of a trace/compile/first-run failure that
+        demoted this signature to the eager path: the exception on the
+        step object, one warning per step object, and a flight-recorder
+        marker per failed signature."""
+        first = self.last_trace_error is None
+        self.last_trace_error = exc
+        msg = "%s: %s" % (type(exc).__name__, str(exc)[:2000])
+        if first:
+            warnings.warn(
+                "fused step: trace/compile failed, this signature runs "
+                "EAGERLY from now on (fallback:trace-failed) — %s "
+                "(warn-once; the exception is step.last_trace_error)"
+                % msg, RuntimeWarning, stacklevel=4)
+        # mxlint: disable=MX011 (demotion path, not steady-state dispatch; the black box must see it with the profiler off)
+        _flightrec.record_marker("fused_step.trace_failed",
+                                 args={"error": msg[:500]})
 
     def _fallback_reason(self):
         if not _ENABLED:
@@ -851,12 +875,8 @@ class FusedTrainStep:
                 pure_step, raw_mesh,
                 in_specs=in_specs, out_specs=out_specs,
                 check_vma=False)
-        donate = ()
-        try:
-            if jax.default_backend() != "cpu":
-                donate = (0, 1)  # weights + optimizer state
-        except Exception:
-            donate = ()
+        # weights + optimizer state; the host backend ignores donation
+        donate = (0, 1) if jax.default_backend() != "cpu" else ()
         in_shs = None
         if self._mesh is not None:
             in_shs = self._input_shardings(all_params, train_pos,
@@ -1260,59 +1280,59 @@ class FusedTrainStep:
                 # cost_analysis/HLO feed the attribution registry; the
                 # cache key pins every operand aval (and mesh mode
                 # pre-places operands above), so the executable stays
-                # valid for all later hits of this signature.
+                # valid for all later hits of this signature. A failure
+                # here is a trace failure like any other: it propagates
+                # to _dispatch, which records it and falls back.
+                #
+                # persistent cache first (ISSUE 19b): the key is the
+                # full signature _dispatch stashed in self._ckey —
+                # avals + signature-token snapshot + mesh
+                # fingerprint + optimizer static key — so a disk hit
+                # is exactly the executable this trace would have
+                # produced, and the trace+XLA compile is skipped
+                # entirely. Any load failure was counted by the
+                # cache and falls through to a fresh compile.
+                self._aot_from_cache = False
+                compiled = None
+                if _compile_cache.enabled() and self._ckey is not None:
+                    compiled = _compile_cache.load(self._ckey)
+                    self._aot_from_cache = compiled is not None
+                if compiled is None:
+                    compiled = jfn.lower(*operands).compile()
+                cost = compiled.cost_analysis()
+                cost = cost[0] if isinstance(cost, (list, tuple)) \
+                    else cost
                 try:
-                    # persistent cache first (ISSUE 19b): the key is the
-                    # full signature _dispatch stashed in self._ckey —
-                    # avals + signature-token snapshot + mesh
-                    # fingerprint + optimizer static key — so a disk hit
-                    # is exactly the executable this trace would have
-                    # produced, and the trace+XLA compile is skipped
-                    # entirely. Any load failure was counted by the
-                    # cache and falls through to a fresh compile.
-                    self._aot_from_cache = False
-                    compiled = None
-                    if _compile_cache.enabled() and self._ckey is not None:
-                        compiled = _compile_cache.load(self._ckey)
-                        self._aot_from_cache = compiled is not None
-                    if compiled is None:
-                        compiled = jfn.lower(*operands).compile()
-                    cost = compiled.cost_analysis()
-                    cost = cost[0] if isinstance(cost, (list, tuple)) \
-                        else cost
-                    try:
-                        hlo = compiled.as_text()
-                    except Exception:
-                        hlo = None
-                    mem = None
-                    try:
-                        # ISSUE 13b: the executable knows its own HBM
-                        # footprint — argument/output/temp/generated
-                        # bytes feed the compile registry's Memory
-                        # table and the headroom gauge
-                        ma = compiled.memory_analysis()
-                        mem = {
-                            "argument_bytes":
-                                int(ma.argument_size_in_bytes),
-                            "output_bytes":
-                                int(ma.output_size_in_bytes),
-                            "temp_bytes": int(ma.temp_size_in_bytes),
-                            "alias_bytes":
-                                int(ma.alias_size_in_bytes),
-                            "generated_code_bytes":
-                                int(ma.generated_code_size_in_bytes),
-                        }
-                    except Exception:
-                        mem = None  # backend without memory_analysis
-                    self._aot = (compiled, cost, hlo, mem)
-                    if self._mesh is not None:
-                        # the bench gspmd_step gate and the matched-
-                        # shardings check read the most recent program
-                        self._last_compiled = compiled
-                        self._last_hlo = hlo
-                    runner = compiled
+                    hlo = compiled.as_text()
                 except Exception:
-                    self._aot = None  # AOT API drift: plain path works
+                    hlo = None
+                mem = None
+                try:
+                    # ISSUE 13b: the executable knows its own HBM
+                    # footprint — argument/output/temp/generated
+                    # bytes feed the compile registry's Memory
+                    # table and the headroom gauge
+                    ma = compiled.memory_analysis()
+                    mem = {
+                        "argument_bytes":
+                            int(ma.argument_size_in_bytes),
+                        "output_bytes":
+                            int(ma.output_size_in_bytes),
+                        "temp_bytes": int(ma.temp_size_in_bytes),
+                        "alias_bytes":
+                            int(ma.alias_size_in_bytes),
+                        "generated_code_bytes":
+                            int(ma.generated_code_size_in_bytes),
+                    }
+                except Exception:
+                    mem = None  # backend without memory_analysis
+                self._aot = (compiled, cost, hlo, mem)
+                if self._mesh is not None:
+                    # the bench gspmd_step gate and the matched-
+                    # shardings check read the most recent program
+                    self._last_compiled = compiled
+                    self._last_hlo = hlo
+                runner = compiled
             if hmeta is not None:
                 loss_data, new_ws, new_sts, grads, aux_datas, health = \
                     runner(*operands)

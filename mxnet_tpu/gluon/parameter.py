@@ -9,6 +9,7 @@ by the active mesh (see mxnet_tpu/parallel), not per-GPU copies.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as _np
 
@@ -124,7 +125,11 @@ class Parameter:
                 "Parameter '%s' has not been initialized" % self.name)
         self.shape = tuple(shape)
         init, ctx, default_init = self._deferred_init
-        self._finish_init(init, ctx, default_init)
+        # a hybridized block finishes deferred init inside an abstract
+        # trace of its forward (HybridBlock._deferred_init_pass): the
+        # parameter's buffers are concrete all the same
+        with jax.ensure_compile_time_eval():
+            self._finish_init(init, ctx, default_init)
 
     def _init_grad(self):
         self._data.attach_grad(self._grad_req)
@@ -179,7 +184,16 @@ class Parameter:
             if self._grad_req != "null":
                 self._init_grad()
         else:
-            self._data._data = data._data.astype(self._data.dtype)
+            new = data._data.astype(self._data.dtype)
+            old = self._data._data
+            if isinstance(old, jax.Array) \
+                    and not isinstance(old, jax.core.Tracer) \
+                    and not isinstance(new, jax.core.Tracer):
+                # the parameter stays where it lives: values read on the
+                # host (a checkpoint) must not pull it off the chip while
+                # its context goes on saying tpu(0)
+                new = jax.device_put(new, old.sharding)
+            self._data._data = new
         _storage.ledger_register(self._data, "param", site=self.name)
 
     def _adopt_fused(self, weight_data, grad_data=None):

@@ -212,8 +212,8 @@ def _stats_kernel(x_ref, sum_ref, sq_ref):
     # FOLD_BLOCK, and fold_blocks of a tile == that tile's slice of
     # fold_blocks over the full array)
     xf = x_ref[:].astype(jnp.float32)
-    sum_ref[:] = fold_blocks(xf)
-    sq_ref[:] = fold_blocks(exact_sq(xf))
+    sum_ref[0] = fold_blocks(xf)
+    sq_ref[0] = fold_blocks(exact_sq(xf))
 
 
 def _apply_kernel(x_ref, g_ref, b_ref, mean_ref, var_ref, o_ref, *,
@@ -302,22 +302,26 @@ def _pallas_forward(x2, gamma, beta, eps, act, interpret):
     nr = pl.cdiv(R, TR)
     key = (R, C, str(x2.dtype), act)
     pt = -(-TR // FOLD_BLOCK)  # per-tile partial rows
+    # partials are (row tile, pt, C): a row tile under 512 gives pt < 8,
+    # and Mosaic takes a sublane block smaller than 8 only where it
+    # spans that whole array dim — which the tile's own axis makes true
+    part_spec = pl.BlockSpec((1, pt, TC), lambda c, r: (r, 0, c))
+    part_shape = jax.ShapeDtypeStruct((nr, pt, C), jnp.float32)
     sums, sqs = attributed("batchnorm_fused.stats", key, lambda:
         pl.pallas_call(
             _stats_kernel,
             grid=(C // TC, nr),
             in_specs=[pl.BlockSpec((TR, TC), lambda c, r: (r, c))],
-            out_specs=(pl.BlockSpec((pt, TC), lambda c, r: (r, c)),
-                       pl.BlockSpec((pt, TC), lambda c, r: (r, c))),
-            out_shape=(jax.ShapeDtypeStruct((nr * pt, C), jnp.float32),
-                       jax.ShapeDtypeStruct((nr * pt, C), jnp.float32)),
+            out_specs=(part_spec, part_spec),
+            out_shape=(part_shape, part_shape),
             interpret=interpret,
         )(x2))
     # finish the tree outside: fold_partials over the per-block sums is
     # bitwise the reference's tree_fold_rows (tile edges sit on
     # FOLD_BLOCK boundaries), so kernel stats == reference stats
-    mean = fold_partials(sums) / R
-    var = jnp.maximum(fold_partials(sqs) / R - exact_sq(mean), 0.0)
+    mean = fold_partials(sums.reshape(nr * pt, C)) / R
+    var = jnp.maximum(
+        fold_partials(sqs.reshape(nr * pt, C)) / R - exact_sq(mean), 0.0)
     g2 = gamma.astype(jnp.float32).reshape(1, C)
     b2 = beta.astype(jnp.float32).reshape(1, C)
     out = attributed("batchnorm_fused.apply", key, lambda:

@@ -90,10 +90,7 @@ def _dequant_kernel(words_ref, out_ref, *, threshold):
 
 
 def _pallas_ok():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def quantize_2bit(grad, residual, threshold=0.5, interpret=False):

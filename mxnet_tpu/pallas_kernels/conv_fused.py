@@ -391,15 +391,10 @@ def _use_pallas(x=None):
     # a CONCRETE array knows where it lives — eager ops on host-committed
     # arrays (default-ctx cpu NDArrays on a TPU-attached process) must
     # take the reference path even though the default platform is tpu
-    if x is not None and isinstance(x, jax.Array):
-        try:
-            return next(iter(x.devices())).platform == "tpu"
-        except Exception:
-            pass
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # uninitialized backend etc.
-        return False
+    if x is not None and isinstance(x, jax.Array) \
+            and not isinstance(x, jax.core.Tracer):
+        return next(iter(x.devices())).platform == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def _fwd_fits(x, w):

@@ -390,10 +390,7 @@ def _pallas_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
 
 
 def _use_pallas():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -470,46 +467,44 @@ def _autotune_blocks(q, k, v, causal, scale):
     for bq, bk in _TUNE_CANDIDATES:
         if bq > q.shape[2] or bk > k.shape[2]:
             continue
-        try:
-            def loss(q_, k_, v_, bq=bq, bk=bk):
-                o = _flash(q_, k_, v_, causal, float(scale), bq, bk, False)
-                return jnp.sum(o.astype(jnp.float32))
-            # grad over ALL inputs so the dk/dv backward kernel is part
-            # of what gets timed (grad on q alone would let XLA DCE it)
-            grad = jax.grad(loss, argnums=(0, 1, 2))
+        def loss(q_, k_, v_, bq=bq, bk=bk):
+            o = _flash(q_, k_, v_, causal, float(scale), bq, bk, False)
+            return jnp.sum(o.astype(jnp.float32))
+        # grad over ALL inputs so the dk/dv backward kernel is part
+        # of what gets timed (grad on q alone would let XLA DCE it)
+        grad = jax.grad(loss, argnums=(0, 1, 2))
 
-            @jax.jit  # mxlint: disable=MX005,MX022 (tuning micro-bench: compiled once per candidate block size inside the memoized autotune pass, timed by the autotuner itself)
-            def many(q_, k_, v_):
-                # chained fori so the device actually serializes the
-                # iterations (async dispatch would lie to the timer)
-                def body(i, qkv):
-                    qq, kk, vv = qkv
-                    dq, dk, dv = grad(qq, kk, vv)
-                    return (qq + 1e-12 * dq, kk + 1e-12 * dk,
-                            vv + 1e-12 * dv)
-                return lax.fori_loop(0, 5, body, (q_, k_, v_))[0]
+        @jax.jit  # mxlint: disable=MX005,MX022 (tuning micro-bench: compiled once per candidate block size inside the memoized autotune pass, timed by the autotuner itself)
+        def many(q_, k_, v_):
+            # chained fori so the device actually serializes the
+            # iterations (async dispatch would lie to the timer)
+            def body(i, qkv):
+                qq, kk, vv = qkv
+                dq, dk, dv = grad(qq, kk, vv)
+                return (qq + 1e-12 * dq, kk + 1e-12 * dk,
+                        vv + 1e-12 * dv)
+            return lax.fori_loop(0, 5, body, (q_, k_, v_))[0]
 
-            warm = many(q, k, v)  # compile
-            # allocation-ledger choke point (ISSUE 13a): the autotune
-            # trial buffers are the 'workspace' tag — the transient HBM
-            # spike a tuning pass costs shows up attributed, not as
-            # anonymous growth
-            from .. import storage as _storage
-            _storage.ledger_register(warm, "workspace",
-                                     site="flash.autotune")
-            float(jnp.sum(warm.astype(jnp.float32)))
-            # mxlint: disable=MX014 (host-side autotune timing: the measured winner is memoized per shape and MXTPU_FLASH_AUTOTUNE is a signature token, so timing noise never changes an already-cached executable)
-            t0 = time.perf_counter()
-            float(jnp.sum(many(q, k, v).astype(jnp.float32)))
-            # mxlint: disable=MX014 (host-side autotune timing, see t0 above)
-            dt = time.perf_counter() - t0
-        except Exception:  # noqa: BLE001 — candidate too big for VMEM etc.
-            continue
+        # a candidate the compiler refuses raises: the candidate list
+        # is ours, so a refusal is a wrong list, not a slow block size
+        warm = many(q, k, v)  # compile
+        # allocation-ledger choke point (ISSUE 13a): the autotune
+        # trial buffers are the 'workspace' tag — the transient HBM
+        # spike a tuning pass costs shows up attributed, not as
+        # anonymous growth
+        from .. import storage as _storage
+        _storage.ledger_register(warm, "workspace",
+                                 site="flash.autotune")
+        float(jnp.sum(warm.astype(jnp.float32)))
+        # mxlint: disable=MX014 (host-side autotune timing: the measured winner is memoized per shape and MXTPU_FLASH_AUTOTUNE is a signature token, so timing noise never changes an already-cached executable)
+        t0 = time.perf_counter()
+        float(jnp.sum(many(q, k, v).astype(jnp.float32)))
+        # mxlint: disable=MX014 (host-side autotune timing, see t0 above)
+        dt = time.perf_counter() - t0
         if dt < best_dt:
             best, best_dt = (bq, bk), dt
     if best is None:
-        # nothing ran (all candidates failed) — fall back WITHOUT
-        # caching, so a later healthy call can still tune this shape
+        # every candidate is larger than this sequence: nothing to time
         return _default_blocks(q.shape[2])
     _TUNE_CACHE[key] = best
     return best

@@ -1,17 +1,11 @@
-"""JAX version-compat shims for the parallel stack.
+"""The one import point for ``shard_map`` and the ``jax.sharding`` names.
 
-``shard_map`` moved twice across jax releases: it lives at
-``jax.experimental.shard_map.shard_map`` through 0.4.x/0.5.x (with a
-``check_rep`` kwarg) and at top-level ``jax.shard_map`` from 0.6 on
-(where the kwarg was renamed ``check_vma``). Every shard_map call in
-this package goes through this one shim so the rest of the code can
-use the modern spelling (``check_vma=``) on either jax.
-
-This module is also the ONE import point for the ``jax.sharding``
-names the package uses (``Mesh``/``NamedSharding``/``PartitionSpec``):
-hot modules import them from here instead of from jax directly, so a
-future relocation (as happened to shard_map twice) means editing one
-file. mxlint **MX020** enforces the routing statically.
+Every shard_map call in this package goes through this module, and hot
+modules import ``Mesh``/``NamedSharding``/``PartitionSpec`` from here
+instead of from jax directly, so a relocation in jax (``shard_map`` has
+moved before) means editing one file. mxlint **MX020** enforces the
+routing statically. The wrapper also accepts the package's
+``DeviceMesh`` in place of a raw ``jax.sharding.Mesh``.
 """
 from __future__ import annotations
 
@@ -20,21 +14,15 @@ import functools
 # the sharding type names, re-exported for the whole package (MX020)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: F401
 
-try:  # jax >= 0.6: top-level export taking check_vma
-    from jax import shard_map as _shard_map
-    _KWARG = "check_vma"
-except ImportError:  # jax <= 0.5: experimental export taking check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _KWARG = "check_rep"
+from jax import shard_map as _shard_map
 
 __all__ = ["shard_map", "Mesh", "NamedSharding", "PartitionSpec"]
 
 
 @functools.wraps(_shard_map)
 def shard_map(f, mesh, in_specs, out_specs, check_vma=True, **kwargs):
-    kwargs[_KWARG] = check_vma
     # accept the package's DeviceMesh wrapper transparently (every
     # caller otherwise repeats the getattr unwrap by hand)
     mesh = getattr(mesh, "mesh", mesh)
     return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+                      out_specs=out_specs, check_vma=check_vma, **kwargs)

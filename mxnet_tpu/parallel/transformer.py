@@ -230,11 +230,36 @@ def _attention(cfg, mesh, q, k, v, positions):
         from ..pallas_kernels import flash_attention
         S = qt.shape[2]
         if S % 128 == 0:
-            ot = flash_attention(qt, kt, vt, causal=cfg.causal)
+            attend = functools.partial(flash_attention, causal=cfg.causal)
+            sizes = _mesh_sizes(mesh)
+            if any(n > 1 for n in sizes.values()):
+                # GSPMD cannot partition a Mosaic kernel ("wrap the call
+                # in a shard_map", says the TPU lowering — the CPU mesh,
+                # where the jnp reference runs, never showed it).
+                # Attention is independent per sequence and per head:
+                # each device runs the kernel on its batch ('dp') and
+                # head ('tp') shard; an axis that does not divide stays
+                # replicated, as does the sequence.
+                from .compat import shard_map
+                spec = P(
+                    "dp" if qt.shape[0] % sizes.get("dp", 1) == 0 else None,
+                    "tp" if qt.shape[1] % sizes.get("tp", 1) == 0 else None,
+                    None, None)
+                attend = shard_map(attend, mesh, in_specs=(spec,) * 3,
+                                   out_specs=spec, check_vma=False)
+            ot = attend(qt, kt, vt)
         else:
             from ..pallas_kernels.flash_attention import attention_reference
             ot = attention_reference(qt, kt, vt, causal=cfg.causal)
     return jnp.transpose(ot, (0, 2, 1, 3))
+
+
+def _mesh_sizes(mesh):
+    """{axis: size} of a DeviceMesh / jax Mesh; {} for no mesh."""
+    if mesh is None:
+        return {}
+    return {a: int(n) for a, n in
+            dict(getattr(mesh, "mesh", mesh).shape).items()}
 
 
 def _layer_body(cfg, mesh, positions, x, lp):
@@ -334,7 +359,7 @@ def _chunked_ce_local(x, w_out, targets, n_chunks, mesh):
     gather run distributed (pmax/psum over 'tp')."""
     from .compat import shard_map
     raw = getattr(mesh, "mesh", mesh)
-    sizes = {a: int(s) for a, s in dict(raw.shape).items()}
+    sizes = _mesh_sizes(mesh)
     sp, tp = sizes.get("sp", 1), sizes.get("tp", 1)
     B, S, _ = x.shape
     if (S // sp) % n_chunks != 0:
@@ -418,8 +443,7 @@ def ce_local_accum_active(cfg, mesh, batch, seq):
     if env in ("0", "off", "false") or cfg.ce_local_accum is False:
         return False
     forced = cfg.ce_local_accum is True or env in ("1", "on", "true")
-    sizes = {a: int(s)
-             for a, s in dict(getattr(mesh, "mesh", mesh).shape).items()}
+    sizes = _mesh_sizes(mesh)
     dp, sp = sizes.get("dp", 1), sizes.get("sp", 1)
     if not forced and dp * sp <= 1:
         return False  # no batch-sharded partial sums -> nothing to save

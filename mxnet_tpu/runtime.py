@@ -9,8 +9,32 @@ devices, whether pallas / distributed / native extensions are usable.
 from __future__ import annotations
 
 import collections
+import os
 
-__all__ = ["Feature", "Features", "feature_list"]
+from .base import getenv as _getenv
+
+__all__ = ["Feature", "Features", "feature_list", "use_compilation_cache"]
+
+
+def use_compilation_cache():
+    """Place JAX's persistent compilation cache and return its directory.
+
+    Called by the entry points that compile for the chip (chip_smoke.py,
+    bench.py, benchmark/longcontext.py, example/transformer/train_lm.py)
+    before their first compile — never at package import. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its cache
+    there, and nothing is set in code. Otherwise the cache goes to one
+    fixed directory inside the checkout, ``<repo>/.jax_cache``: the
+    path is part of the cache key, so a directory that moves (a
+    tempdir, a pid, a timestamp) never hits."""
+    import jax
+    path = _getenv("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class Feature:

@@ -127,19 +127,20 @@ class TensorInspector:
         """
         import jax
         import jax.numpy as jnp
-        hdr = ("TensorInspector[%s] <%s %s>" % (
+        # the header rides as a (static) format argument, never inside
+        # the format string: a tag may carry braces
+        hdr = "TensorInspector[%s] <%s %s>" % (
             tag or "Tensor", "x".join(map(str, x.shape)), x.dtype)
-        ).replace("{", "{{").replace("}", "}}")  # tag-safe fmt string
         if jnp.issubdtype(x.dtype, jnp.floating) or \
                 jnp.issubdtype(x.dtype, jnp.complexfloating):
             x32 = jnp.abs(x).astype(jnp.float32)
             jax.debug.print(
-                hdr + " nonfinite={bad} absmax={amax} l2={l2}",
+                "{hdr} nonfinite={bad} absmax={amax} l2={l2}", hdr=hdr,
                 bad=jnp.sum((~jnp.isfinite(x)).astype(jnp.int32)),
                 amax=jnp.max(x32) if x.size else jnp.float32(0),
                 l2=jnp.sqrt(jnp.sum(x32 * x32)))
         else:
-            jax.debug.print(hdr + " min={mn} max={mx}",
+            jax.debug.print("{hdr} min={mn} max={mx}", hdr=hdr,
                             mn=jnp.min(x) if x.size else 0,
                             mx=jnp.max(x) if x.size else 0)
         return x
@@ -154,7 +155,6 @@ class TensorInspector:
         import jax.numpy as jnp
         bad = jnp.sum((~jnp.isfinite(x)).astype(jnp.int32)) \
             if jnp.issubdtype(x.dtype, jnp.inexact) else jnp.int32(0)
-        hdr = ("TensorInspector[%s] check:" % (tag or "Tensor")) \
-            .replace("{", "{{").replace("}", "}}")
-        jax.debug.print(hdr + " nonfinite={bad}", bad=bad)
+        jax.debug.print("{hdr} nonfinite={bad}", bad=bad,
+                        hdr="TensorInspector[%s] check:" % (tag or "Tensor"))
         return x

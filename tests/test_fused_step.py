@@ -369,6 +369,45 @@ def test_shape_change_is_a_retrace_not_a_failure():
     assert FS.stats()["retraces"] == 1
 
 
+def test_trace_failure_falls_back_and_keeps_the_evidence():
+    """A step whose trace raises still trains eagerly — and says so:
+    the exception stays on the step object, one warning names it, and a
+    marker lands in the flight record. On the chip a compiler refusal
+    looks exactly like this; it once vanished into a bare ``except``."""
+    from mxnet_tpu._debug import flightrec
+    loss_fn = gluon.loss.L2Loss()
+    net = _make_net()
+    ref = _make_net(seed_from=net)
+    tr = gluon.Trainer(net.collect_params(), "sgd", {"learning_rate": 0.1})
+    tr_ref = gluon.Trainer(ref.collect_params(), "sgd",
+                           {"learning_rate": 0.1})
+
+    def bad_loss(x, y):
+        out = net(x)
+        if not autograd.is_recording():  # eager records; the trace does not
+            raise ValueError("Mosaic refused the kernel")
+        return loss_fn(out, y)
+
+    step = tr.fuse_step(bad_loss)
+    assert step.last_trace_error is None
+    FS.reset_stats()
+    x, y = _batch()
+    with pytest.warns(RuntimeWarning,
+                      match="ValueError: Mosaic refused") as caught:
+        for _ in range(4):
+            step(x, y, batch_size=4)
+            _eager_step(ref, loss_fn, tr_ref, x, y, 4)
+    assert step.last_mode == "fallback:trace-failed"
+    assert isinstance(step.last_trace_error, ValueError)
+    assert FS.stats()["fallbacks"] == 3       # eager-warming, then 3 failed
+    assert len([w for w in caught
+                if "trace/compile failed" in str(w.message)]) == 1
+    assert any(ev[1] == "fused_step.trace_failed"
+               and "Mosaic refused" in ev[6]["error"]
+               for ev in flightrec.snapshot())
+    assert _params_bitwise(net, ref)          # the fallback still trained
+
+
 # -- observability -----------------------------------------------------------
 
 def test_counters_surface_in_profiler_metrics():

@@ -222,3 +222,75 @@ def test_symbolblock_batchnorm_aux_updates():
     assert np.abs(mm).max() > 0.1, mm
     y2 = blk(x)  # inference path with updated stats
     assert np.isfinite(y2.asnumpy()).all()
+
+
+def test_lean_vjp_when_residuals_would_crowd_the_device(monkeypatch):
+    """A hybridized block whose recorded forward would hold more than
+    half the device's memory in vjp residuals holds nothing instead: its
+    backward is one program that re-runs the forward. Same loss, same
+    gradients up to fusion order, moving stats updated once. The CPU
+    reports no memory limit and keeps the plain vjp; the limit is faked
+    here to drive the decision both ways."""
+    from mxnet_tpu.gluon import block as B
+
+    rs = np.random.RandomState(0)
+    x = nd.array(rs.rand(4, 6).astype("float32"))
+    assert B._device_bytes_limit(x._data) is None      # CPU: no limit
+    decisions = []
+    real = B._vjp_crowds_device
+    monkeypatch.setattr(
+        B, "_vjp_crowds_device",
+        lambda *a: decisions.append(real(*a)) or decisions[-1])
+
+    def loss_and_grads(limit):
+        monkeypatch.setattr(B, "_device_bytes_limit", lambda a: limit)
+        mx.random.seed(0)
+        net = nn.HybridSequential()
+        net.add(nn.Dense(8, in_units=6), nn.BatchNorm(),
+                nn.Activation("relu"), nn.Dense(4))
+        net.initialize(mx.init.Xavier())
+        net.hybridize()
+        net(x)
+        with autograd.record():
+            loss = (net(x) ** 2).sum()
+        loss.backward()
+        return float(loss.asscalar()), [
+            (p.grad() if p.grad_req != "null" else p.data()).asnumpy()
+            for _, p in sorted(net.collect_params().items())]
+
+    roomy = loss_and_grads(1 << 40)
+    lean = loss_and_grads(1 << 10)
+    assert decisions == [False, True]
+    assert roomy[0] == lean[0]
+    for a, b in zip(roomy[1], lean[1]):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_loaded_values_land_where_the_parameter_lives(tmp_path):
+    """nd.load reads onto the host's first device. set_data on a live
+    parameter keeps the parameter's own placement, and load_parameters
+    honours ``ctx`` for parameters that have no data yet — on the chip a
+    checkpoint load used to move every weight to the CPU while its
+    context still said tpu(0)."""
+    import jax
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    dev1 = jax.devices()[1]
+    fname = str(tmp_path / "net.params")
+
+    src = nn.Dense(3, in_units=2)
+    src.initialize()
+    src.save_parameters(fname)
+
+    live = nn.Dense(3, in_units=2)
+    live.initialize(ctx=mx.cpu(1))
+    assert live.weight.data()._data.devices() == {dev1}
+    live.load_parameters(fname)
+    assert live.weight.data()._data.devices() == {dev1}
+    np.testing.assert_array_equal(live.weight.data().asnumpy(),
+                                  src.weight.data().asnumpy())
+
+    fresh = nn.Dense(3)                      # deferred: no data yet
+    fresh.initialize(ctx=mx.cpu(1))
+    fresh.load_parameters(fname, ctx=mx.cpu(1))
+    assert fresh.weight.data()._data.devices() == {dev1}

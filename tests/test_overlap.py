@@ -130,9 +130,13 @@ class TestBucketedParity:
             np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
 
     def test_bucketing_collapses_collective_count(self, dp_mesh):
-        """The compiled HLO carries ONE all-reduce per bucket, not one
-        per leaf — the wire-batching half of the overlap story — and
-        the payload bytes match the unbucketed lowering exactly."""
+        """The lowering carries ONE all-reduce per bucket, not one per
+        leaf — the wire-batching half of the overlap story — and the
+        compiled payload bytes match the unbucketed program exactly.
+        The per-bucket count is read off the UN-optimised lowering:
+        what the program asks for, whatever a backend's own all-reduce
+        combiner merges afterwards (this XLA's CPU combiner folds the
+        six per-leaf reductions into one by itself)."""
         shapes = [(64,)] * 6
         ws = _rand_leaves(jr.PRNGKey(3), shapes)
         xs = _rand_leaves(jr.PRNGKey(4), shapes)
@@ -141,11 +145,13 @@ class TestBucketedParity:
         def loss(ws_, xs_):
             return sum(jnp.sum(w * x) ** 2 for w, x in zip(ws_, xs_))
 
-        def grads_of(fn):
+        def lowered(fn):
             body = shard_map(fn, dp_mesh, in_specs=(specs, specs),
                              out_specs=specs, check_vma=False)
-            return jax.jit(body).lower(tuple(ws),
-                                       tuple(xs)).compile().as_text()
+            return jax.jit(body).lower(tuple(ws), tuple(xs))
+
+        def asked(fn):
+            return lowered(fn).as_text(dialect="hlo").count(" all-reduce(")
 
         def ref(ws_, xs_):
             g = jax.grad(loss)(list(ws_), list(xs_))
@@ -158,10 +164,13 @@ class TestBucketedParity:
                                                  bucket_bytes=768), xs_)
             return tuple(jax.grad(loss_tagged)(list(ws_)))
 
-        b_ref, c_ref, _ = hlo_collective_bytes(grads_of(ref))
-        b_tag, c_tag, _ = hlo_collective_bytes(grads_of(tagged))
-        assert c_ref.get("all-reduce", 0) >= 6
-        assert c_tag.get("all-reduce", 0) == 2
+        assert asked(ref) == 6
+        assert asked(tagged) == 2
+        b_ref, c_ref, _ = hlo_collective_bytes(
+            lowered(ref).compile().as_text())
+        b_tag, c_tag, _ = hlo_collective_bytes(
+            lowered(tagged).compile().as_text())
+        assert 1 <= c_tag["all-reduce"] <= c_ref["all-reduce"]
         assert b_tag["all-reduce"] == b_ref["all-reduce"]
 
 

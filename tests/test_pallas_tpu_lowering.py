@@ -1,0 +1,95 @@
+"""Every default-on Pallas kernel cross-lowers for the TPU from the CPU.
+
+``jax.export`` with ``platforms=["tpu"]`` runs the Pallas-to-Mosaic
+lowering — block-shape rules, memory spaces, the ops Mosaic accepts —
+with no chip attached. It takes seconds and keeps a kernel from being
+valid in interpret mode only, which is how the fused-BatchNorm stats
+kernel shipped: at ResNet-50's last stage its output block was (2, 256).
+What the Mosaic compiler itself says (VMEM, layouts) only the chip can
+tell; that is chip_smoke.py phase B, over this same table.
+"""
+import importlib
+import os
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+BN = importlib.import_module("mxnet_tpu.pallas_kernels.batchnorm_fused")
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The kernels' own dispatch as it runs on a TPU: every backend
+    predicate says yes, so the public entry points take the Pallas
+    branch (their fit predicates still decide) instead of the jnp one."""
+    for mod, name in (("batchnorm_fused", "_use_pallas"),
+                      ("quantized_matmul", "_use_pallas"),
+                      ("flash_attention", "_use_pallas"),
+                      ("compression", "_pallas_ok")):
+        monkeypatch.setattr(
+            importlib.import_module("mxnet_tpu.pallas_kernels." + mod),
+            name, lambda *a: True)
+
+
+CASES = chip_smoke.kernel_cases()
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_kernel_lowers_to_mosaic(as_on_tpu, case):
+    name, fn, specs, n_kernels = case[:4]
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*specs)
+    assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) \
+        == n_kernels, "%s: a jnp path was taken" % name
+
+
+@pytest.mark.parametrize("R,C,tr", [
+    (6272, 2048, 128),      # ResNet-50 last stage, batch 128: the refusal
+    (3136, 2048, 64),       # one partial row per tile
+    (25088, 1024, 512),     # pt == 8: lowered before, too
+])
+def test_bn_row_tiles_under_512_fit_and_lower(as_on_tpu, R, C, tr):
+    """A row tile under 512 yields fewer than 8 partial rows per tile;
+    with several tiles along the rows that used to break Mosaic's
+    (8, 128) block rule for the stats kernel's output."""
+    import jax.numpy as jnp
+    assert BN._tiles(R, C, 2, 2) == (tr, 256, True)
+    x = jax.ShapeDtypeStruct((R, C), jnp.bfloat16)
+    g = jax.ShapeDtypeStruct((C,), jnp.float32)
+    assert BN.engaged(x, axis=1)
+    exported = jax.export.export(
+        jax.jit(lambda x_, g_, b_: BN.fused_batch_norm(x_, g_, b_)),
+        platforms=["tpu"])(x, g, g)
+    assert chip_smoke.MOSAIC_CALL in exported.mlir_module()
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4,
+                    reason="needs four virtual devices")
+def test_flash_inside_the_sharded_transformer_step_lowers(as_on_tpu):
+    """GSPMD cannot partition a Mosaic kernel: on a dp x tp mesh the
+    TPU lowering of the transformer step refused ("wrap the call in a
+    shard_map") — seen first on four real chips, because on the CPU mesh
+    attention takes the jnp reference. The step now runs the kernel per
+    (batch, head) shard; forward, its remat re-run, dq and dk/dv lower."""
+    import jax.numpy as jnp
+    import jax.random as jr
+    from mxnet_tpu.parallel import create_mesh
+    from mxnet_tpu.parallel import transformer as T
+    mesh = create_mesh(devices=jax.devices()[:4], dp=2, tp=2)
+    cfg = T.TransformerConfig(
+        vocab_size=256, dim=256, n_layers=1, n_heads=2, ffn_hidden=512,
+        max_seq_len=128, dtype="bfloat16", attn_mode="local", loss_chunks=2)
+    _, step_fn = T.make_train_step(cfg, mesh)
+    with mesh.mesh:
+        params = jax.eval_shape(lambda k: T.init_params(k, cfg),
+                                jr.PRNGKey(0))
+        toks = jax.ShapeDtypeStruct((4, 128), jnp.int32)
+        exported = jax.export.export(step_fn, platforms=["tpu"])(
+            (params, params), toks, toks)
+    assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == 4

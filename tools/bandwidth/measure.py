@@ -92,8 +92,8 @@ def measure_mesh(args):
     kvstore path cannot beat."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     import numpy as np
 
     devs = jax.devices()

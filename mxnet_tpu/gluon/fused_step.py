@@ -373,7 +373,11 @@ class FusedTrainStep:
         # detector watches; non-"fused" completions are warm-up/compile/
         # fallback shapes and stay out of the rolling median
         _watchdog.step_begin()
-        t0 = _time.perf_counter() if _profiler._LIVE else None
+        # the program's step span: on the device trace's host plane
+        # when xprof runs, and through record_op under _LIVE on exit
+        sp = _profiler.step_span("gluon.train_step", lane="gluon",
+                                 category="gluon")
+        sp.__enter__()
         mode = "error"
         try:
             loss, mode = self._dispatch(nd_args, batch_size,
@@ -388,13 +392,12 @@ class FusedTrainStep:
             attr = self._step_attr if mode == "fused" else None
             _watchdog.step_end(warmup=mode != "fused", mode=mode,
                                sig=attr.get("sig") if attr else None)
-            if t0 is not None:
-                dur_us = (_time.perf_counter() - t0) * 1e6
-                _profiler.record_op(
-                    "gluon.train_step", dur_us,
-                    category="gluon", lane="gluon",
-                    args={"mode": mode, "batch_size": batch_size,
-                          "params": len(self._trainer._params)})
+            if _profiler._LIVE:
+                sp.args = {"mode": mode, "batch_size": batch_size,
+                           "params": len(self._trainer._params)}
+            sp.__exit__(None, None, None)
+            if _profiler._LIVE:
+                dur_us = sp.dur_us
                 # the latency histogram ROADMAP item 1's serve gate
                 # reports p50/p99 from (metrics()['latency'])
                 _profiler.record_latency("fused_step.step", dur_us)

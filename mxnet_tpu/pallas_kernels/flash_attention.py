@@ -210,6 +210,7 @@ def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),    # unnormalized output
         ],
         interpret=interpret,
+        name="mx_flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d), lse
 
@@ -356,6 +357,7 @@ def _pallas_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="mx_flash_dq",
     )(qf, kf, vf, dof, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -383,6 +385,7 @@ def _pallas_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_flash_dkv",
     )(qf, kf, vf, dof, lse, delta)
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),

@@ -37,6 +37,7 @@ from jax import lax
 
 from jax.ad_checkpoint import checkpoint_name as _ckpt_name
 
+from .. import profiler as _profiler
 from ..base import getenv as _getenv
 from .compat import NamedSharding, PartitionSpec as P
 
@@ -264,25 +265,29 @@ def _mesh_sizes(mesh):
 
 def _layer_body(cfg, mesh, positions, x, lp):
     """One transformer layer. x: [B, S, D]; lp: this layer's params."""
-    h = _rms_norm(x, lp["ln1"])
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-    q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)), positions),
-                      (0, 2, 1, 3))
-    k = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)), positions),
-                      (0, 2, 1, 3))
-    o = _ckpt_name(_attention(cfg, mesh, q, k, v, positions), "attn_o")
-    x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
-    h = _rms_norm(x, lp["ln2"])
-    if cfg.num_experts > 0:
-        y, aux = moe_ffn(h, lp["moe_router"], lp["moe_w1"], lp["moe_w2"],
-                         k=cfg.moe_k)
-        return x + y, aux
-    g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
-    u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
-    prod = _ckpt_name(g * u, "ffn_prod")
-    return x + jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), 0.0
+    with jax.named_scope("mx.attn_proj"):
+        h = _rms_norm(x, lp["ln1"])
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)), positions),
+                          (0, 2, 1, 3))
+        k = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)), positions),
+                          (0, 2, 1, 3))
+    with jax.named_scope("mx.flash"):
+        o = _ckpt_name(_attention(cfg, mesh, q, k, v, positions), "attn_o")
+    with jax.named_scope("mx.attn_out"):
+        x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+    with jax.named_scope("mx.ffn"):
+        h = _rms_norm(x, lp["ln2"])
+        if cfg.num_experts > 0:
+            y, aux = moe_ffn(h, lp["moe_router"], lp["moe_w1"],
+                             lp["moe_w2"], k=cfg.moe_k)
+            return x + y, aux
+        g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
+        u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
+        prod = _ckpt_name(g * u, "ffn_prod")
+        return x + jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), 0.0
 
 
 def apply(params, tokens, cfg: TransformerConfig, mesh=None,
@@ -290,7 +295,8 @@ def apply(params, tokens, cfg: TransformerConfig, mesh=None,
     """Forward: tokens [B, S] int32 -> logits [B, S, V]. GSPMD mode.
     With return_aux, also returns the summed MoE load-balance loss."""
     x, aux = _hidden(params, tokens, cfg, mesh)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
+    with jax.named_scope("mx.head_ce"):
+        logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
     if return_aux:
         return logits, aux
     return logits
@@ -308,7 +314,8 @@ def _remat_policy(cfg):
 def _hidden(params, tokens, cfg, mesh):
     """Trunk forward up to (but excluding) the output projection;
     returns (x [B,S,D], summed aux)."""
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("mx.embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     positions = jnp.arange(tokens.shape[1])
 
     def body(x, lp):
@@ -317,8 +324,11 @@ def _hidden(params, tokens, cfg, mesh):
 
     if cfg.remat:
         body = jax.checkpoint(body, policy=_remat_policy(cfg))
-    x, auxs = lax.scan(body, x, params["layers"])
-    return _rms_norm(x, params["ln_f"]), jnp.sum(auxs)
+    with jax.named_scope("mx.layer"):
+        x, auxs = lax.scan(body, x, params["layers"])
+    with jax.named_scope("mx.head_ce"):
+        x = _rms_norm(x, params["ln_f"])
+    return x, jnp.sum(auxs)
 
 
 def _chunked_ce(x, w_out, targets, n_chunks):
@@ -474,18 +484,22 @@ def loss_fn(params, tokens, targets, cfg, mesh=None, aux_weight=0.01):
                 "divisor or set loss_chunks=1"
                 % (cfg.loss_chunks, tokens.shape[1]))
         x, aux = _hidden(params, tokens, cfg, mesh)
-        if ce_local_accum_active(cfg, mesh, tokens.shape[0],
-                                 tokens.shape[1]):
-            loss = _chunked_ce_local(x, params["w_out"], targets,
-                                     cfg.loss_chunks, mesh)
-        else:
-            loss = _chunked_ce(x, params["w_out"], targets,
-                               cfg.loss_chunks)
+        local = ce_local_accum_active(cfg, mesh, tokens.shape[0],
+                                      tokens.shape[1])
+        with jax.named_scope("mx.head_ce"):
+            if local:
+                loss = _chunked_ce_local(x, params["w_out"], targets,
+                                         cfg.loss_chunks, mesh)
+            else:
+                loss = _chunked_ce(x, params["w_out"], targets,
+                                   cfg.loss_chunks)
     else:
         logits, aux = apply(params, tokens, cfg, mesh, return_aux=True)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        loss = -jnp.mean(ll)
+        with jax.named_scope("mx.head_ce"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ll = jnp.take_along_axis(logp, targets[..., None],
+                                     axis=-1)[..., 0]
+            loss = -jnp.mean(ll)
     if cfg.num_experts > 0:
         loss = loss + aux_weight * aux  # GShard load-balance pressure
     return loss
@@ -499,27 +513,32 @@ def _layer_body_local(cfg, positions, x, lp):
     """Per-device layer body used inside shard_map: tp dims of lp are LOCAL
     shards; row-parallel outputs need psum over 'tp'. Sequence dim of x is
     the local 'sp' shard; attention uses the ppermute ring."""
-    h = _rms_norm(x, lp["ln1"])
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-    q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)), positions),
-                      (0, 2, 1, 3))
-    kq = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)), positions),
-                       (0, 2, 1, 3))
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(kq, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    ot = ring_attention(qt, kt, vt, "sp", causal=cfg.causal,
-                        q_offset=positions[0])
-    o = jnp.transpose(ot, (0, 2, 1, 3))
-    attn_out = lax.psum(jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), "tp")
-    x = x + attn_out
-    h = _rms_norm(x, lp["ln2"])
-    g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
-    u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
-    ffn_out = lax.psum(jnp.einsum("bsf,fd->bsd", g * u, lp["w_down"]), "tp")
-    return x + ffn_out
+    with jax.named_scope("mx.attn_proj"):
+        h = _rms_norm(x, lp["ln1"])
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)), positions),
+                          (0, 2, 1, 3))
+        kq = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)), positions),
+                           (0, 2, 1, 3))
+    with jax.named_scope("mx.flash"):
+        qt = jnp.transpose(q, (0, 2, 1, 3))
+        kt = jnp.transpose(kq, (0, 2, 1, 3))
+        vt = jnp.transpose(v, (0, 2, 1, 3))
+        ot = ring_attention(qt, kt, vt, "sp", causal=cfg.causal,
+                            q_offset=positions[0])
+        o = jnp.transpose(ot, (0, 2, 1, 3))
+    with jax.named_scope("mx.attn_out"):
+        attn_out = lax.psum(jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), "tp")
+        x = x + attn_out
+    with jax.named_scope("mx.ffn"):
+        h = _rms_norm(x, lp["ln2"])
+        g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
+        u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
+        ffn_out = lax.psum(
+            jnp.einsum("bsf,fd->bsd", g * u, lp["w_down"]), "tp")
+        return x + ffn_out
 
 
 def _pipeline_forward_local(cfg, params, tokens):
@@ -534,7 +553,8 @@ def _pipeline_forward_local(cfg, params, tokens):
     mb = B // M
     positions = sp_idx * S_local + jnp.arange(S_local)
 
-    x_all = jnp.take(params["embed"], tokens, axis=0)       # [B, S_l, D]
+    with jax.named_scope("mx.embed"):
+        x_all = jnp.take(params["embed"], tokens, axis=0)   # [B, S_l, D]
     x_mb = x_all.reshape(M, mb, S_local, cfg.dim)
 
     stage_params = jax.tree_util.tree_map(lambda p: p[0], params["layers"])
@@ -542,22 +562,25 @@ def _pipeline_forward_local(cfg, params, tokens):
     def stage_fn(x):
         def body(x, lp):
             return _layer_body_local(cfg, positions, x, lp), None
-        x, _ = lax.scan(body, x, stage_params)
+        with jax.named_scope("mx.layer"):
+            x, _ = lax.scan(body, x, stage_params)
         return x
 
     outs = gpipe_loop(stage_fn, x_mb, "pp")
     x = outs.reshape(B, S_local, cfg.dim)
-    x = _rms_norm(x, params["ln_f"])
-    logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
+    with jax.named_scope("mx.head_ce"):
+        x = _rms_norm(x, params["ln_f"])
+        logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
     return logits
 
 
 def _pipeline_loss_local(cfg, params, tokens, targets):
     logits = _pipeline_forward_local(cfg, params, tokens)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    # mean over local tokens, then over dp & sp shards
-    return lax.pmean(lax.pmean(jnp.mean(ll), "dp"), "sp") * -1.0
+    with jax.named_scope("mx.head_ce"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        # mean over local tokens, then over dp & sp shards
+        return lax.pmean(lax.pmean(jnp.mean(ll), "dp"), "sp") * -1.0
 
 
 # --------------------------------------------------------------------------
@@ -603,10 +626,11 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
             params, mom = state
             loss, grads = jax.value_and_grad(loss_of)(params, tokens,
                                                       targets)
-            new_mom = jax.tree_util.tree_map(
-                lambda m, g: 0.9 * m + g, mom, grads)
-            new_params = jax.tree_util.tree_map(
-                lambda p, m: p - learning_rate * m, params, new_mom)
+            with jax.named_scope("mx.optimizer"):
+                new_mom = jax.tree_util.tree_map(
+                    lambda m, g: 0.9 * m + g, mom, grads)
+                new_params = jax.tree_util.tree_map(
+                    lambda p, m: p - learning_rate * m, params, new_mom)
             return (new_params, new_mom), loss
     else:
         from .compat import shard_map
@@ -633,10 +657,11 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
             grads = jax.tree_util.tree_map(
                 reduce_grad, grads, specs,
                 is_leaf=lambda l: hasattr(l, "shape"))
-            new_mom = jax.tree_util.tree_map(
-                lambda m, g: 0.9 * m + g, mom, grads)
-            new_params = jax.tree_util.tree_map(
-                lambda p, m: p - learning_rate * m, params, new_mom)
+            with jax.named_scope("mx.optimizer"):
+                new_mom = jax.tree_util.tree_map(
+                    lambda m, g: 0.9 * m + g, mom, grads)
+                new_params = jax.tree_util.tree_map(
+                    lambda p, m: p - learning_rate * m, params, new_mom)
             loss = lax.pmean(lax.pmean(loss, "dp"), "sp")
             return new_params, new_mom, loss
 
@@ -651,7 +676,9 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
             new_params, new_mom, loss = smapped(params, mom, tokens, targets)
             return (new_params, new_mom), loss
 
-    return init_fn, step_fn
+    # the jitted step behind the program's own span and counters
+    # (profiler.metrics()['train_step']); .lower/.trace reach the jit
+    return init_fn, _profiler.instrument_step(step_fn, "mx.train_step")
 
 
 def _spec_mentions(spec, axis):

@@ -43,11 +43,14 @@ HTTP endpoint (``MXNET_PROFILER_HTTP_PORT``).
 """
 from __future__ import annotations
 
+import collections
 import json
 import math
 import os
 import threading
 import time
+
+import jax
 
 from ._debug import flightrec as _flightrec
 from ._debug import locktrace as _locktrace
@@ -65,6 +68,8 @@ __all__ = [
     "marker", "bump_elastic", "elastic_stats", "reset_elastic_stats",
     "record_compile", "compile_stats", "ensure_lane",
     "record_program", "program_records",
+    "span", "step_span", "instrument_step", "train_step_stats",
+    "jax_compile_stats", "device_table",
 ]
 
 # chrome-trace pid of every event this process emits: the worker rank.
@@ -84,6 +89,7 @@ LANES = {
     "user": 7,
     "compile": 8,
     "health": 9,
+    "train_step": 10,
 }
 
 # dynamic lanes (ensure_lane) are allocated from here up, so the fixed
@@ -118,6 +124,7 @@ _state = {
     "xprof": True,
     "xprof_dir": None,
     "xprof_active": False,
+    "xprof_last": None,   # directory of the last finished device trace
 }
 # Fast-path guard mirrored from (running and not paused). Subsystem hooks
 # read this module attribute before building any event dict — the
@@ -258,7 +265,6 @@ def set_state(state="stop", profile_process="worker"):
                             os.path.abspath(_state["filename"])),
                         "xprof_trace")
                 try:
-                    import jax
                     jax.profiler.start_trace(xdir)
                     _state["xprof_active"] = True
                     _state["xprof_dir"] = xdir
@@ -290,8 +296,8 @@ def set_state(state="stop", profile_process="worker"):
             if _state["xprof_active"]:
                 _state["xprof_active"] = False
                 try:
-                    import jax
                     jax.profiler.stop_trace()
+                    _state["xprof_last"] = _state["xprof_dir"]
                 except Exception:
                     pass
         # shutdown ordering (ISSUE 8 satellite): the /metrics endpoint
@@ -949,6 +955,286 @@ def register_stats_provider(name, snapshot, reset=None):
 register_stats_provider("elastic", elastic_stats, reset_elastic_stats)
 
 
+# -- spans on the profiler's clock, step and compile counters (ISSUE 26) -----
+# One helper for every host span that should be readable beside device
+# time. ``jax.profiler.TraceAnnotation`` is a TraceMe: a flag test while
+# no device trace runs, an event on the ``.xplane.pb`` host plane (the
+# device trace's clock) while one does. On exit the span feeds
+# ``record_op`` under the shared ``_LIVE`` guard, so the host lanes, the
+# aggregate table and the flight-recorder ring see what they always saw.
+
+class span:
+    """``with profiler.span(name, lane=..., args=None) as sp:`` — one host
+    span on the profiler's clock. ``sp.args`` may be set inside the block
+    (a step knows its mode only at the end); ``sp.dur_us`` holds the host
+    time after it."""
+    __slots__ = ("name", "lane", "category", "args", "dur_us", "_t0",
+                 "_note")
+
+    def __init__(self, name, lane="user", args=None, category="span"):
+        self.name, self.lane, self.args = name, lane, args
+        self.category = category
+        self.dur_us = 0.0
+
+    def _annotation(self):
+        return jax.profiler.TraceAnnotation(self.name)
+
+    def __enter__(self):
+        self._note = self._annotation()
+        self._note.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur_us = (time.perf_counter() - self._t0) * 1e6
+        self._note.__exit__(*exc)
+        if _LIVE:
+            record_op(self.name, self.dur_us, category=self.category,
+                      args=self.args, lane=self.lane)
+        return False
+
+
+# mxlint: disable=MX003 (GIL-atomic best-effort counters, same contract as gluon/fused_step._STATS: no lock on the step's hot path)
+_STEPS = {"started": 0,   # step spans opened in this process, any kind
+          "open": 0}      # ... and not yet closed: > 0 means "in a step"
+
+
+class step_span(span):
+    """A span that is one training step: a ``StepTraceAnnotation``, so
+    the spans of one step share its number in the device trace. While
+    one is open, what JAX compiles is booked to the step (``in_step`` in
+    ``metrics()['jax_compile']``)."""
+    __slots__ = ("step_num",)
+
+    def __init__(self, name, step_num=None, lane="train_step", args=None,
+                 category="train_step"):
+        span.__init__(self, name, lane, args, category)
+        self.step_num = step_num
+
+    def _annotation(self):
+        return jax.profiler.StepTraceAnnotation(self.name,
+                                                step_num=self.step_num)
+
+    def __enter__(self):
+        _STEPS["started"] += 1
+        _STEPS["open"] += 1
+        if self.step_num is None:
+            self.step_num = _STEPS["started"]
+        return span.__enter__(self)
+
+    def __exit__(self, *exc):
+        _STEPS["open"] -= 1
+        return span.__exit__(self, *exc)
+
+
+_RING_CAP = 4096
+# mxlint: disable=MX003 (GIL-atomic best-effort counters; deque.append is atomic)
+_TRAIN = {"steps": 0,      # calls started
+          "compiles": 0,   # calls during which JAX traced, lowered,
+                           # compiled or loaded a program
+          "retraces": 0}   # compiles beyond each step object's first
+                           # call: 0 in a sound run
+# mxlint: disable=MX003 (deque.append/clear are atomic under the GIL; one writer, the calling thread of the step)
+_TRAIN_RING = collections.deque(maxlen=_RING_CAP)  # (host us, compiled?)
+
+
+class instrument_step:
+    """A jitted train step behind the program's own span and counters:
+    each call runs inside ``step_span(name, n)`` and is counted in
+    ``metrics()['train_step']``, at two clock reads, one TraceMe and one
+    ring append a call, no lock. Everything else a caller does with the
+    ``jax.jit`` object (``lower``, ``trace``, ``jax.export``) reaches it
+    unchanged."""
+
+    def __init__(self, jitted, name="mx.train_step"):
+        self._jitted, self._name, self._calls = jitted, name, 0
+
+    def __call__(self, *args, **kwargs):
+        self._calls += 1
+        _TRAIN["steps"] += 1
+        seen = _JAXC["in_step"]
+        with step_span(self._name, _TRAIN["steps"]) as sp:
+            out = self._jitted(*args, **kwargs)
+            compiled = _JAXC["in_step"] != seen
+            if compiled:
+                sp.args = {"compiled": True}
+        if compiled:
+            _TRAIN["compiles"] += 1
+            if self._calls > 1:
+                _TRAIN["retraces"] += 1
+        _TRAIN_RING.append((sp.dur_us, compiled))
+        return out
+
+    def lower(self, *args, **kwargs):
+        return self._jitted.lower(*args, **kwargs)
+
+    def trace(self, *args, **kwargs):
+        return self._jitted.trace(*args, **kwargs)
+
+    def record_program(self, *args, **kwargs):
+        """Compile (or load) the step for these arguments and keep its
+        HLO text in ``record_program``: ``device_table`` maps a trace's
+        instruction names to their scopes through it."""
+        hlo = self._jitted.lower(*args, **kwargs).compile().as_text()
+        record_program("train_step", self._name, hlo)
+        return hlo
+
+    def __getattr__(self, attr):
+        if attr == "_jitted":      # not set yet (copy, unpickle): no loop
+            raise AttributeError(attr)
+        return getattr(self._jitted, attr)
+
+
+def train_step_stats():
+    """``metrics()['train_step']``: ``steps``, ``compiles``, ``retraces``
+    and ``calls``, the last 4096 calls as [host us inside the call,
+    compiled?], oldest first."""
+    out = dict(_TRAIN)
+    out["calls"] = [[us, bool(c)] for us, c in list(_TRAIN_RING)]
+    return out
+
+
+def reset_train_step_stats():
+    for k in _TRAIN:
+        _TRAIN[k] = 0
+    _TRAIN_RING.clear()
+    _STEPS["started"] = 0   # the ledger's at_step counts from here too
+
+
+register_stats_provider("train_step", train_step_stats,
+                        reset_train_step_stats)
+
+# The compile ledger: JAX reports every trace, lowering, backend compile
+# and persistent-cache hit through jax.monitoring, with the function's
+# name. Listeners fire only when something compiles, so a steady step
+# pays nothing. In JAX 0.9.0 the cache read lies INSIDE
+# backend_compile_duration (pxla._cached_compilation times
+# compiler.compile_or_get_cached), so ``compile_s`` adds the three phases
+# and never cache_retrieval_time_sec on top.
+_JAX_EVENT_ROOTS = ("/jax/core/compile/", "/jax/compilation_cache/")
+_JAX_PHASES = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+               "backend_compile_duration")
+_JAX_FUN_CAP = 512
+# mxlint: disable=MX003 (written by JAX's listeners on the compiling thread, read as a snapshot; best-effort like _TRAIN)
+_JAXC = {"in_step": 0,     # phase events that fired inside a step's call
+         "open": 0,        # phases open right now (nesting depth)
+         "nested": 0,      # phase events inside another phase: counted
+                           # here, kept out of entries and totals
+         "dropped": 0}     # entries the bounded list let go
+# mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; deque.append is atomic)
+_JAXC_ENTRIES = collections.deque(maxlen=_RING_CAP)
+# mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; best-effort totals like _TRAIN)
+_JAXC_SECONDS = {}   # event -> seconds
+# mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; best-effort totals like _TRAIN)
+_JAXC_COUNTS = {}    # event -> count
+# mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; best-effort totals like _TRAIN)
+_JAXC_BY_FUN = {}    # fun_name -> {event: seconds}
+
+
+def _jax_event(event, seconds=0.0, fun_name=None, **_):
+    """jax.monitoring's duration listener and its plain event listener."""
+    for root in _JAX_EVENT_ROOTS:
+        if event.startswith(root):
+            event = event[len(root):]
+            break
+    else:
+        return
+    in_step = _STEPS["open"] > 0
+    if event in _JAX_PHASES:
+        if in_step:
+            _JAXC["in_step"] += 1
+        _JAXC["open"] = max(0, _JAXC["open"] - 1)
+        if _JAXC["open"]:
+            # a jit traced while another is traced or lowered (every
+            # jnp op of the step's body): its time lies inside the
+            # outer phase's
+            _JAXC["nested"] += 1
+            return
+    if len(_JAXC_ENTRIES) == _RING_CAP:
+        _JAXC["dropped"] += 1
+    _JAXC_ENTRIES.append((event, fun_name, float(seconds),
+                          _STEPS["started"], in_step))
+    _JAXC_COUNTS[event] = _JAXC_COUNTS.get(event, 0) + 1
+    _JAXC_SECONDS[event] = _JAXC_SECONDS.get(event, 0.0) + seconds
+    if fun_name is not None:
+        if fun_name not in _JAXC_BY_FUN and \
+                len(_JAXC_BY_FUN) >= _JAX_FUN_CAP:
+            fun_name = "(other)"
+        by = _JAXC_BY_FUN.setdefault(fun_name, {})
+        by[event] = by.get(event, 0.0) + seconds
+
+
+def _jax_phase_start(event, value, **_):
+    """jax.monitoring's scalar listener: log_elapsed_time reports each
+    phase's start as a scalar, the only way to know that a phase began
+    inside another."""
+    if event.startswith(_JAX_EVENT_ROOTS[0]):
+        _JAXC["open"] += 1
+
+
+def jax_compile_stats():
+    """``metrics()['jax_compile']``: what JAX itself reported. ``entries``
+    (the last 4096, oldest first) are {event, fun_name, seconds, at_step,
+    in_step}: ``at_step`` is the number of step spans started when the
+    event fired, ``in_step`` whether one was open. ``seconds`` and
+    ``counts`` are totals by event, ``by_fun`` by function name, and
+    ``compile_s`` the three compile phases together (cache loads are
+    inside backend_compile_duration and counted once)."""
+    keys = ("event", "fun_name", "seconds", "at_step", "in_step")
+    seconds = dict(_JAXC_SECONDS)
+    return {
+        "entries": [dict(zip(keys, e)) for e in list(_JAXC_ENTRIES)],
+        "seconds": seconds,
+        "counts": dict(_JAXC_COUNTS),
+        "by_fun": {f: dict(v) for f, v in list(_JAXC_BY_FUN.items())},
+        "compile_s": sum(seconds.get(p, 0.0) for p in _JAX_PHASES),
+        "nested": _JAXC["nested"],
+        "dropped": _JAXC["dropped"],
+    }
+
+
+def reset_jax_compile_stats():
+    _JAXC_ENTRIES.clear()
+    _JAXC_SECONDS.clear()
+    _JAXC_COUNTS.clear()
+    _JAXC_BY_FUN.clear()
+    _JAXC["dropped"] = _JAXC["nested"] = 0
+
+
+register_stats_provider("jax_compile", jax_compile_stats,
+                        reset_jax_compile_stats)
+# once a process, at import: the listeners cost nothing until JAX compiles
+jax.monitoring.register_event_duration_secs_listener(_jax_event)
+jax.monitoring.register_event_listener(_jax_event)
+jax.monitoring.register_scalar_listener(_jax_phase_start)
+
+
+def device_table(trace=None, hlo=None):
+    """Device time by ``mx.*`` scope (forward / backward / recompute),
+    the Pallas kernels by name and the program's host spans, read back
+    from an xprof trace: ``trace`` is an ``.xplane.pb``, a trace
+    directory, or None for the last trace ``set_state('run')`` wrote;
+    ``hlo`` the compiled step's text, by default what the step's
+    ``record_program`` kept. None where the trace holds no device plane
+    (``_debug/devicetable.py`` has the reduction)."""
+    import glob
+    from ._debug import devicetable
+    if trace is None:
+        trace = _state["xprof_last"]
+        if trace is None:
+            return None
+    if isinstance(trace, str) and os.path.isdir(trace):
+        found = sorted(glob.glob(os.path.join(
+            trace, "plugins", "profile", "*", "*.xplane.pb")))
+        if not found:
+            return None
+        trace = found[-1]
+    if hlo is None:
+        kept = program_records("train_step")
+        hlo = kept[-1]["hlo"] if kept else None
+    return devicetable.device_table(trace, hlo)
+
+
 def _provider_sections(reset):
     """[(name, stats dict)] from the registered providers; a raising
     provider reports its error instead of killing the snapshot."""
@@ -1078,7 +1364,8 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
                     st["fallbacks"], st["bulk_flushes"], st["bulk_ops"]))
     for name, stats in _provider_sections(reset):
         lines.append("%s: %s" % (name, " ".join(
-            "%s=%s" % (k, stats[k]) for k in sorted(stats))))
+            "%s=%s" % (k, stats[k]) for k in sorted(stats)
+            if not isinstance(stats[k], (list, dict)))))
     if latency:
         lines.append("")
         lines.append("%-40s %8s %10s %10s %10s %10s" % (
@@ -1236,6 +1523,16 @@ def dumps(reset=False, format="table", sort_by="total", ascending=False):
                     "/".join("%.1f" % (t.get(b, 0.0) * 1e6)
                              for b in _perfmodel_mod.BOUNDS)
                     if t else "-"))
+    # Device time by scope: the last finished xprof trace, read back
+    if _state["xprof_last"] and not _state["xprof_active"]:
+        try:
+            from ._debug import devicetable as _devicetable_mod
+            text = _devicetable_mod.format_table(device_table())
+        except Exception as e:
+            text = "device table: %s: %s" % (type(e).__name__, e)
+        if text:
+            lines.append("")
+            lines.append(text)
     if reset:
         reset_imperative_stats()
     return "\n".join(lines)
@@ -1620,7 +1917,10 @@ def _reset():
         _elastic.clear()
         _compiles.clear()
         del _programs[:]
+        _state["xprof_last"] = None
     reset_imperative_stats()
+    reset_train_step_stats()
+    reset_jax_compile_stats()
     try:
         from . import storage as _storage_mod
         _storage_mod.ledger_reset()

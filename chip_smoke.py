@@ -49,6 +49,9 @@ MOSAIC_CALL = "tpu_custom_call"
 # test_pallas_tpu_lowering.py cross-lowers exactly this table on the CPU.
 FLASH_SHAPE = (12, 32, 2048, 128)          # bench transformer, bf16 causal
 FLASH_LONG_SHAPE = (1, 16, 65536, 128)     # README long-context claim
+# the attention of BENCHMARK.json's two cells (baichuan_7b.l5-seq2048,
+# -seq16384): each kernel's own tile shape has to pass Mosaic there
+FLASH_CELL_SHAPES = ((8, 32, 2048, 128), (1, 32, 16384, 128))
 # ResNet-50's channels-last stage shapes at batch 128, and one Dense case
 BN_SHAPES = (((401408, 256), "bfloat16"), ((100352, 512), "bfloat16"),
              ((25088, 1024), "bfloat16"), ((6272, 2048), "bfloat16"),
@@ -278,6 +281,7 @@ def phase_a(platform, make_net=resnet50, classes=1000, image=224,
 # ---------------------------------------------------------------------------
 
 def kernel_cases(flash_shape=FLASH_SHAPE, flash_long_shape=FLASH_LONG_SHAPE,
+                 flash_cell_shapes=FLASH_CELL_SHAPES,
                  bn_shapes=BN_SHAPES, qmm_shape=QMM_SHAPE,
                  twobit_n=TWOBIT_N, flash_dtype="bfloat16",
                  interpret=False):
@@ -310,18 +314,17 @@ def kernel_cases(flash_shape=FLASH_SHAPE, flash_long_shape=FLASH_LONG_SHAPE,
                                       interpret=interpret))
     flash_ref = fwd_bwd(functools.partial(attention_reference, causal=True))
     low = jnp.dtype(flash_dtype).itemsize < 4
-    if flash_shape is not None:
+    # a reference only where one batch element's score matrix fits
+    for shape, with_ref in ([(flash_shape, True), (flash_long_shape, False)]
+                            + [(s, s[2] <= 2048) for s in flash_cell_shapes]):
+        if shape is None:
+            continue
         cases.append(("flash_attention fwd+bwd %s %s causal"
-                      % (list(flash_shape), flash_dtype),
-                      flash, (S(flash_shape, jnp.dtype(flash_dtype)),) * 4,
-                      3, flash_ref,
-                      (3e-2,) + (5e-2,) * 3 if low else (1e-3,) * 4))
-    if flash_long_shape is not None:
-        cases.append(("flash_attention fwd+bwd %s %s causal"
-                      % (list(flash_long_shape), flash_dtype),
-                      flash, (S(flash_long_shape,
-                                jnp.dtype(flash_dtype)),) * 4, 3, None,
-                      None))
+                      % (list(shape), flash_dtype),
+                      flash, (S(shape, jnp.dtype(flash_dtype)),) * 4, 3,
+                      flash_ref if with_ref else None,
+                      ((3e-2,) + (5e-2,) * 3 if low else (1e-3,) * 4)
+                      if with_ref else None))
 
     # fused BatchNorm(+relu): stats + apply forward, reduce + dx backward
     def bn_fn(kernel):

@@ -607,14 +607,13 @@ def signature_tokens():
 
 
 # The kernel-routing switches (ops/nn.py batch_norm, ops/quantized.py,
-# the global kill switch) and the packed-apply/autotune toggles that
-# change traced update/kernel graphs. New env-routed kernels register
+# the global kill switch) and the packed-apply toggle that changes
+# traced update/kernel graphs. New env-routed kernels register
 # theirs alongside these.
 register_signature_token("MXTPU_NO_PALLAS", "0")
 register_signature_token("MXTPU_FUSED_BN", "1")
 register_signature_token("MXTPU_QUANT_MATMUL", "1")
 register_signature_token("MXTPU_FUSED_APPLY", "0")
-register_signature_token("MXTPU_FLASH_AUTOTUNE", "0")
 # the packed-apply bucket plan (parallel/overlap.bucket_plan) reads the
 # bucket-size cap at trace time, so it shapes the traced update graph —
 # found by mxlint MX014 on its first whole-tree run (exactly the PR 9

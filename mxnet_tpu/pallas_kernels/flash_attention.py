@@ -1,11 +1,17 @@
-"""Flash attention: tiled online-softmax attention as a Pallas TPU kernel.
+"""Flash attention: tiled online-softmax attention as Pallas TPU kernels.
 
 The reference has no attention kernel at all (2019-era; its closest analog
 is the fused cuDNN RNN, src/operator/rnn-inl.h). Long-context attention is
 where a modern framework's FLOPs go, so this is the flagship custom
-kernel: per (batch*head, q-block) grid cell, K/V stream through VMEM in
-``block_k`` tiles while the m/l/o running softmax accumulates in
-registers — HBM traffic is O(S·D) instead of the O(S^2) score matrix.
+kernel. The score matrix is cut into (block_q, block_k) tiles; a grid step
+is one tile, K/V stream through VMEM one ``block_k`` tile at a time, and
+the m/l/o running softmax lives in VMEM scratch across a q-block's steps
+(m and l lane-replicated, so no 1-D vector and no relayout appears in the
+loop) — HBM traffic is O(S·D) instead of the O(S^2) score matrix. Only
+tiles the causal mask leaves something of are grid steps (``_steps``), and
+inside a tile the work goes in chunks of rows, each stopping at the last
+column its rows can see, so a diagonal tile costs about 5/8 of a full one
+and only its last sub-block pays for the mask.
 
 Composition with the parallelism layer: ring attention
 (parallel/ring_attention.py) shards the sequence over the mesh and
@@ -15,11 +21,13 @@ match Liu et al.'s blockwise formulation.
 
 Backward is a pair of Pallas kernels in the flash-2 formulation: the
 forward saves only the per-row logsumexp L = m + log(l) (O(S) extra);
-the backward recomputes each (block_q, block_k) score tile inside the
-kernel from Q/K/L, so dQ/dK/dV are produced with O(S*D) HBM traffic and
-O(block^2) VMEM — the O(S^2) score matrix is never materialized in
-either direction. On non-TPU backends (and when the kernel is bypassed)
-the jnp reference's XLA vjp is used instead.
+the backward recomputes each score tile inside the kernel from Q/K/L, so
+dQ/dK/dV are produced with O(S*D) HBM traffic and O(block^2) VMEM — the
+O(S^2) score matrix is never materialized in either direction. dQ works
+q-major like the forward; dK/dV kv-major (S^T = K @ Q^T), so neither
+contracts an operand over its major dim. Each of the three kernels has a
+tile shape of its own (``_default_blocks``). On non-TPU backends (and when
+the kernel is bypassed) the jnp reference's XLA vjp is used instead.
 """
 from __future__ import annotations
 
@@ -27,13 +35,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
-from ..base import getenv as _getenv
+
+from .. import profiler as _profiler
 
 __all__ = ["flash_attention", "attention_reference"]
 
-# Mosaic requires the minor block dim to be a multiple of 128 lanes, so
-# per-row scalars (logsumexp, delta) are stored broadcast over 128 lanes.
+# Mosaic requires the minor block dim to be a multiple of 128 lanes:
+# per-row statistics are lane-replicated (rows, 128) in VMEM and
+# (1, rows) rows in HBM.
 _LANES = 128
 
 
@@ -81,38 +92,202 @@ def attention_reference(q, k, v, causal=False, scale=None):
                       v).astype(q.dtype)
 
 
-def _causal_dispatch(qi, ki, block_q, block_k, compute):
-    """Run ``compute(masked)`` for one (q-block, k-block) causal cell:
-    blocks strictly above the diagonal are skipped, diagonal-straddling
-    blocks run masked, strictly-below blocks run unmasked. Shared by the
-    forward and both backward kernels so the classification cannot
+# Scores and statistics live in the log2 domain inside the kernels:
+# s2 = (q . k) * scale * log2(e), p = 2^(s2 - m2). The scale and the
+# exp's own log2(e) are one f32 multiply per score instead of two, and
+# exp2 is the EUP's native op. lse leaves the kernel in natural log.
+_LOG2E = 1.4426950408889634
+_LN2 = 0.6931471805599453
+_NT = (((1,), (1,)), ((), ()))      # a @ b^T: both contract their minor dim
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+# A causal tile's place relative to the diagonal is the static integer
+# rel = (its first q position) - (its first k position); the kernels
+# emit one specialised body per value a straddling tile can take. More
+# values than this (blocks with a small common divisor, only tiny test
+# shapes) take one body with a dynamic rel instead.
+_MAX_DIAG_BODIES = 8
+# Rows of the tile one softmax update works on (q rows in forward and
+# dq, k rows in dk/dv). A (1024, 1024) f32 score tile is 1024 vector
+# registers of a 64-register file; in chunks the scheduler keeps each
+# chunk's statistics and accumulator rows in registers, and a chunk of
+# a diagonal tile stops at the last column (row) its rows can see.
+_CHUNK = 256
+# Chunks of a tile off the diagonal run in a loop, this many to a loop
+# body: within a body the scheduler overlaps one chunk's matmuls with the
+# next one's softmax. All of them unrolled is 1.8% (forward), 2.0% (dq)
+# and 1.8% (dk/dv) faster at 16k than these and 44 more bodies to trace
+# and lower in every process (PERF.md, PR 27).
+_GROUP = {"fwd": 4, "dq": 2, "dkv": 2}
+
+
+def _chunk(block):
+    """Largest multiple of 8 that divides ``block`` and is at most
+    ``_CHUNK``; the block itself where none exists."""
+    c = min(block, _CHUNK)
+    c -= c % 8
+    while c >= 8 and block % c:
+        c -= 8
+    return c if c >= 8 else block
+
+
+# The chunk bodies below are written with lax primitives, not jnp
+# operators: a kernel is traced and lowered again in every process (the
+# persistent cache keys on the lowered module), a diagonal tile unrolls
+# into a dozen bodies, and a jnp operator costs four times a lax bind to
+# trace. That time is set-up.
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _lanes(x, n):
+    """Per-row statistics kept lane-replicated as (rows, 128) -> (rows, n)
+    without ever forming a 1-D vector (which Mosaic lays out along lanes:
+    a sublane-to-lane relayout per use)."""
+    if n == _LANES:
+        return x
+    if n % _LANES == 0:
+        return lax.concatenate([x] * (n // _LANES), 1)
+    if n < _LANES:
+        return lax.slice_in_dim(x, 0, n, axis=1)
+    return lax.broadcast_in_dim(lax.slice_in_dim(x, 0, 1, axis=1),
+                                (x.shape[0], n), (0, 1))
+
+
+def _row_stat(reduce, x):
+    """Row maximum or sum of a score chunk, lane-replicated (rows, 128)."""
+    return lax.broadcast_in_dim(reduce(x, (1,)), (x.shape[0], _LANES), (0,))
+
+
+def _row(x):
+    """(n, 128) lane-replicated -> (1, n): how per-row statistics cross
+    HBM (n floats a block instead of 128 n). One XLU transpose a q-block,
+    outside the k loop."""
+    return jnp.transpose(x)[:1]
+
+
+def _replicated(row):
+    """(1, n) -> (n, 128) lane-replicated; the inverse of ``_row``."""
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, row.shape[1])))
+
+
+def _for_chunks(rel, block, chunk, group, body):
+    """``body(lo)`` for each chunk of rows [lo, lo + chunk) of a tile. A
+    tile on the diagonal (static ``rel``) is unrolled, so that each chunk
+    gets its own visible range; every other tile is a loop over groups of
+    ``group`` chunks, whose bodies the scheduler may overlap: the kernels
+    are traced and lowered in every process, and that time is set-up."""
+    if isinstance(rel, int):
+        for lo in range(0, block, chunk):
+            body(lo)
+        return
+    import jax.experimental.pallas as pl
+    group = min(group, block // chunk)
+    while (block // chunk) % group:
+        group -= 1
+
+    def step(i, carry):
+        lo = pl.multiple_of(i * (group * chunk), group * chunk)
+        for g in range(group):
+            body(lo + g * chunk)
+        return carry
+
+    lax.fori_loop(0, block // (group * chunk), step, 0)
+
+
+def _floor_to(x, q):
+    return (x // q) * q
+
+
+def _visible(rel, lo, n, width, kv_major):
+    """Which part of the other axis one chunk of a causal tile computes.
+
+    q-major (forward, dq): the chunk is q rows [lo, lo+n) of the tile and
+    the answer (full_end, end) says k columns [0, full_end) are visible
+    to every row and [full_end, end) to some (masked); columns from
+    ``end`` on are not computed. kv-major (dk/dv): the chunk is k rows
+    and the answer (start, full_start) says q columns [start, full_start)
+    are masked and [full_start, width) fully visible. ``rel`` None means
+    no mask at all; a traced ``rel`` masks the whole width. Ranges end
+    on lane-tile boundaries (the whole width where it has none)."""
+    quantum = _LANES if width % _LANES == 0 else width
+    if rel is None:
+        return (width, width) if not kv_major else (0, 0)
+    if not isinstance(rel, int):
+        return (0, width)
+    if not kv_major:
+        # column j is visible to row i iff j - i <= rel
+        full_end = _floor_to(rel + lo + 1, quantum)
+        end = -_floor_to(-(rel + lo + n), quantum)
+        return (min(max(full_end, 0), width), min(max(end, 0), width))
+    # q column i is visible to k row j iff i >= j - rel
+    start = _floor_to(lo - rel, quantum)
+    full_start = -_floor_to(-(lo + n - 1 - rel), quantum)
+    return (min(max(start, 0), width), min(max(full_start, 0), width))
+
+
+def _mask(s, lo, hi, d, k_axis):
+    """``s`` with -inf where (k index) - (q index) > d, for columns
+    [lo, hi) only: the rest of a chunk's columns its rows see whole. The
+    k index runs along ``k_axis`` of ``s`` and d counts from column lo."""
+    if lo == hi:
+        return s
+    part = lax.slice_in_dim(s, lo, hi, axis=1)
+    kk = lax.broadcasted_iota(jnp.int32, part.shape, k_axis)
+    qq = lax.broadcasted_iota(jnp.int32, part.shape, 1 - k_axis)
+    part = lax.select(lax.gt(lax.sub(kk, qq), lax.full_like(kk, d)),
+                      lax.full_like(part, -jnp.inf), part)
+    pieces = [lax.slice_in_dim(s, 0, lo, axis=1), part,
+              lax.slice_in_dim(s, hi, s.shape[1], axis=1)]
+    pieces = [x for x in pieces if x.shape[1]]
+    return pieces[0] if len(pieces) == 1 else lax.concatenate(pieces, 1)
+
+
+def _diag_rels(block_q, block_k):
+    """The values rel takes on tiles the diagonal crosses: tiles wholly
+    above it have rel <= -block_q, tiles wholly below rel >= block_k - 1."""
+    import math
+    g = math.gcd(block_q, block_k)
+    return list(range(-block_q + g, block_k - 1, g))
+
+
+def _causal_bodies(rel, block_q, block_k, body):
+    """Run ``body`` for one causal tile of ``_steps`` (none lies above
+    the diagonal): ``body(None)``, no mask, wholly below it, and on it
+    ``body`` with the tile's static rel, so each chunk's visible range is
+    a constant. Shared by the three kernels so the classification cannot
     drift."""
     import jax.experimental.pallas as pl
 
-    below = ki * block_k + block_k - 1 <= qi * block_q
-
-    @pl.when(jnp.logical_and(
-        ki * block_k <= qi * block_q + block_q - 1,
-        jnp.logical_not(below)))
+    @pl.when(rel >= block_k - 1)
     def _():
-        compute(True)
+        body(None)
 
-    @pl.when(below)
-    def _():
-        compute(False)
+    rels = _diag_rels(block_q, block_k)
+    if len(rels) > _MAX_DIAG_BODIES:
+        @pl.when(rel < block_k - 1)
+        def _():
+            body(rel)
+        return
+    for r in rels:
+        @pl.when(rel == r)
+        def _(r=r):
+            body(r)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, o_scr, *,
-                block_q, block_k, causal, scale, n_kblocks):
-    """One (batch*head, q-block, k-block) grid cell. The TPU grid runs
-    sequentially with the k axis innermost, so VMEM scratch carries the
-    m/l/o online-softmax state across k steps — only one (block_k, D)
-    K/V tile is resident at a time, keeping VMEM O(block) instead of
-    O(seq)."""
+def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
+                l_scr, o_scr, *, block_q, block_k, chunk, causal, scale2,
+                n_kblocks):
+    """One (batch*head, step) grid cell; a step is one (q-block, k-block)
+    tile of ``_steps``. The TPU grid runs sequentially with a q-block's
+    k-blocks in a row, so VMEM scratch carries the m/l/o online-softmax
+    state across them — only one (block_k, D) K/V tile is resident at a
+    time, keeping VMEM O(block) instead of O(seq). m and l are
+    (block_q, 128), every lane of a row the same."""
     import jax.experimental.pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi = qi_ref[pl.program_id(1)]
+    ki = ki_ref[pl.program_id(1)]
+    d = o_scr.shape[-1]
 
     @pl.when(ki == 0)
     def _init():
@@ -120,57 +295,109 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, o_scr, *,
         l_scr[:] = jnp.zeros_like(l_scr)
         o_scr[:] = jnp.zeros_like(o_scr)
 
-    def compute(masked):
+    def tile(rel):
         # dots run on the input dtype (bf16 hits the MXU at full rate;
         # f32 would be 8x slower) and accumulate in f32.
         # No isneginf guards: every q row's FIRST processed block (ki=0)
-        # contains its valid col 0, so m stays finite from the first
-        # step on, exp(-inf - finite) underflows to exactly 0 for both
-        # masked scores and the m_prev=-inf init, and no exp(-inf+inf)
-        # NaN can form. (Fully-masked rows cannot occur: causal row r
-        # always sees cols 0..r.)
-        q = q_ref[0]                                  # (block_q, D)
-        k = k_ref[0]                                  # (block_k, D)
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (block_q, block_k)
-        if masked:
-            row = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col > row, -jnp.inf, s)
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        m_scr[:, 0] = m_new
-        l_scr[:, 0] = corr * l_scr[:, 0] + jnp.sum(p, axis=-1)
-        o_scr[:] = corr[:, None] * o_scr[:] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        # starts at its valid col 0, so m is finite from the first step
+        # on, exp2(-inf - finite) is exactly 0 for masked scores and for
+        # the m_prev=-inf init, and no exp2(-inf+inf) NaN can form.
+        # (Fully-masked rows cannot occur: causal row r sees cols 0..r.)
+        def rows_at(lo):
+            full_end, end = _visible(rel, lo, chunk, block_k, False)
+            if end == 0:
+                return
+            rows = pl.ds(lo, chunk)
+            s = lax.mul(_dot(q_ref[0, rows], k_ref[0, :end], _NT), scale2)
+            if full_end < end:
+                s = _mask(s, full_end, end, rel + lo - full_end, 1)
+            m_prev = m_scr[rows]
+            m_new = lax.max(m_prev, _row_stat(lax.reduce_max, s))
+            corr = lax.exp2(lax.sub(m_prev, m_new))
+            p = lax.exp2(lax.sub(s, _lanes(m_new, end)))
+            v = v_ref[0, :end]
+            m_scr[rows] = m_new
+            l_scr[rows] = lax.add(lax.mul(corr, l_scr[rows]),
+                                  _row_stat(lax.reduce_sum, p))
+            o_scr[rows] = lax.add(
+                lax.mul(_lanes(corr, d), o_scr[rows]),
+                _dot(lax.convert_element_type(p, v.dtype), v, _NN))
+
+        _for_chunks(rel, block_q, chunk, _GROUP["fwd"], rows_at)
 
     if causal:
-        # most active blocks at long seq are strictly below the diagonal
-        # and skip the per-element iota/compare/select VPU work
-        _causal_dispatch(qi, ki, block_q, block_k, compute)
+        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile)
     else:
-        compute(False)
+        tile(None)
 
-    @pl.when(ki == n_kblocks - 1)
+    @pl.when(ki == _last_kblock(qi, block_q, block_k, n_kblocks, causal))
     def _finalize():
         # INVARIANT: no row is ever fully masked (causal row r sees cols
         # 0..r; non-causal sees everything; ring x flash skips
         # fully-masked hops before calling the kernel), so l > 0 and
         # lse is finite — the backward recompute relies on this.
-        # Broadcast across a 128-lane minor dim — Mosaic requires the
-        # last block dim to be a multiple of 128, so scalars-per-row
-        # ride a full lane register.
-        l = l_scr[:, 0]
-        lse = m_scr[:, 0] + jnp.log(l)
-        lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
-        o_ref[0] = (o_scr[:] / l[:, None]).astype(o_ref.dtype)
+        l = l_scr[:]
+        lse_ref[0] = _row((m_scr[:] + jnp.log2(l)) * _LN2)
+        o_ref[0] = (o_scr[:] / _lanes(l, d)).astype(o_ref.dtype)
+
+
+def _steps(n_qblocks, n_kblocks, block_q, block_k, causal, kv_major):
+    """The (q-block, k-block) pairs that compute, in grid order (k
+    innermost; q innermost for dk/dv), as two int32 tables the kernels
+    and their index maps read from SMEM. Tiles wholly above the diagonal
+    are not grid steps at all: no step overhead, no DMA."""
+    pairs = [(i, j) for i in range(n_qblocks) for j in range(n_kblocks)
+             if not causal or j * block_k <= i * block_q + block_q - 1]
+    if kv_major:
+        pairs.sort(key=lambda p: (p[1], p[0]))
+    # numpy, not jnp: a jnp array made while tracing is an eager device
+    # computation, a program of its own to compile or fetch from the cache
+    qi, ki = np.asarray(pairs, np.int32).T
+    return qi, ki
+
+
+# Index maps of a (batch*head, step) grid: the step's q-block or k-block
+# from the prefetched tables, as rows of a (bh, S, D) operand or as lanes
+# of a (bh, 1, S) statistics row.
+def _q_rows(bh, t, qi, ki):
+    return bh, qi[t], 0
+
+
+def _k_rows(bh, t, qi, ki):
+    return bh, ki[t], 0
+
+
+def _q_lanes(bh, t, qi, ki):
+    return bh, 0, qi[t]
+
+
+def _last_kblock(qi, block_q, block_k, n_kblocks, causal):
+    """The last k-block a q-block's steps visit."""
+    if not causal:
+        return n_kblocks - 1
+    return jnp.minimum(n_kblocks - 1, ((qi + 1) * block_q - 1) // block_k)
+
+
+def _compiler_params(interpret, vmem_bytes):
+    """The step axis carries the accumulators; heads are independent.
+    ``vmem_bytes`` is the launcher's count of double-buffered blocks,
+    scratch and one chunk's score-sized temporaries; the scoped-VMEM
+    limit (16 MiB by default) is set half as much again above it, for
+    what the compiler spills."""
+    if interpret:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=int(min(max(1.5 * vmem_bytes + (4 << 20), 16 << 20),
+                                 100 << 20)))
+
+
+def _fit(block_q, block_k, sq, sk):
+    """Both axes ride lanes somewhere (k in the score tile, q in the
+    statistics rows and in dk/dv's tile): whole lane tiles or the whole
+    axis."""
+    return _fit_block(block_q, sq, _LANES), _fit_block(block_k, sk, _LANES)
 
 
 def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -179,234 +406,290 @@ def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret):
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q = _fit_block(block_q, sq, 8)
-    block_k = _fit_block(block_k, sk, 128)
+    block_q, block_k = _fit(block_q, block_k, sq, sk)
+    chunk = _chunk(block_q)
     qf = q.reshape(b * h, sq, d)
     kf = k.reshape(b * h, sk, d)
     vf = v.reshape(b * h, sk, d)
     n_kblocks = sk // block_k
-    kernel = functools.partial(_fwd_kernel, block_q=block_q,
-                               block_k=block_k, causal=causal, scale=scale,
-                               n_kblocks=n_kblocks)
+    kernel = functools.partial(
+        _fwd_kernel, block_q=block_q, block_k=block_k, chunk=chunk,
+        causal=causal, scale2=scale * _LOG2E, n_kblocks=n_kblocks)
+    steps = _steps(sq // block_q, n_kblocks, block_q, block_k, causal, False)
+    item = q.dtype.itemsize
+    vmem = (4 * (block_q + block_k) * d * item
+            + block_q * (2 * _LANES + d) * 4 + 5 * chunk * block_k * 4)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, sq // block_q, n_kblocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, i, j: (bh, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b * h, steps[0].size),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), _q_rows),
+                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_k, d), _k_rows),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), _q_rows),
+                pl.BlockSpec((1, 1, block_q), _q_lanes),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max m
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # denominator l
+                pltpu.VMEM((block_q, d), jnp.float32),   # unnormalized out
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),    # running max m
-            pltpu.VMEM((block_q, 1), jnp.float32),    # running denom l
-            pltpu.VMEM((block_q, d), jnp.float32),    # unnormalized output
-        ],
+        compiler_params=_compiler_params(interpret, vmem),
         interpret=interpret,
         name="mx_flash_fwd",
-    )(qf, kf, vf)
-    return out.reshape(b, h, sq, d), lse
+    )(*steps, qf, kf, vf)
+    # callers (ring_flash) take lse as (bh, sq, 128) and keep one lane:
+    # a broadcast XLA folds into that slice, never 128x in HBM
+    return out.reshape(b, h, sq, d), jnp.broadcast_to(
+        lse.reshape(b * h, sq, 1), (b * h, sq, _LANES))
 
 
-def _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    qi, ki, block_q, block_k, masked, scale):
-    """Shared flash-2 backward recompute: rebuild the (block_q, block_k)
-    probability tile from Q/K and the saved row logsumexp, then
-    dS = P * (dP - delta) * scale. Used by both _dq_kernel and
-    _dkv_kernel so the masking/lse-safety logic cannot drift.
-    ``masked`` is static: only diagonal-straddling blocks pay the iota
-    mask; masked scores give p = exp(-inf - lse) = 0 exactly (causal
-    rows always have a finite lse — see _fwd_kernel)."""
-    q = q_ref[0]                                  # (block_q, D)
-    k = k_ref[0]                                  # (block_k, D)
-    v = v_ref[0]
-    do = do_ref[0]                                # (block_q, D)
-    lse = lse_ref[0][:, 0]                        # (block_q,)
-    delta = delta_ref[0][:, 0]                    # (block_q,)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if masked:
-        row = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        col = ki * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(col > row, -jnp.inf, s)
-    p = jnp.exp(s - lse[:, None])                 # (block_q, block_k)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)       # (block_q, block_k)
-    ds = (p * (dp - delta[:, None]) * scale).astype(q.dtype)
-    return p, ds
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, block_q, block_k, causal, scale, n_kblocks):
-    """dQ for one (batch*head, q-block) cell; k innermost.
-    dQ += dS @ K."""
+def _dq_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+               delta_ref, dq_ref, dq_scr, lse_scr, delta_scr, *, block_q,
+               block_k, chunk, causal, scale, n_kblocks):
+    """dQ, one (q-block, k-block) tile of ``_steps`` a grid step. Flash-2
+    recompute per chunk of q rows: P = 2^(S2 - lse2) from Q/K and the
+    saved row logsumexp, dS = P * (dP - delta), dQ += dS @ K; the score
+    scale is applied once, to the finished accumulator. Masked scores
+    give p = 2^(-inf - lse2) = 0 exactly (causal rows always have a
+    finite lse — see _fwd_kernel). lse2 (= lse * log2 e) and delta come
+    as (1, block_q) rows and are laid out lane-replicated, (block_q,
+    128), once a q-block."""
     import jax.experimental.pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi = qi_ref[pl.program_id(1)]
+    ki = ki_ref[pl.program_id(1)]
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        lse_scr[:] = _replicated(lse_ref[0])
+        delta_scr[:] = _replicated(delta_ref[0])
 
-    def compute(masked):
-        _, ds = _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                delta_ref, qi, ki, block_q, block_k,
-                                masked, scale)
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(rel):
+        def rows_at(lo):
+            full_end, end = _visible(rel, lo, chunk, block_k, False)
+            if end == 0:
+                return
+            rows = pl.ds(lo, chunk)
+            k = k_ref[0, :end]
+            s = lax.mul(_dot(q_ref[0, rows], k, _NT), scale * _LOG2E)
+            if full_end < end:
+                s = _mask(s, full_end, end, rel + lo - full_end, 1)
+            p = lax.exp2(lax.sub(s, _lanes(lse_scr[rows], end)))
+            dp = _dot(do_ref[0, rows], v_ref[0, :end], _NT)
+            ds = lax.mul(p, lax.sub(dp, _lanes(delta_scr[rows], end)))
+            dq_scr[rows] = lax.add(dq_scr[rows], _dot(
+                lax.convert_element_type(ds, k.dtype), k, _NN))
+
+        _for_chunks(rel, block_q, chunk, _GROUP["dq"], rows_at)
 
     if causal:
-        _causal_dispatch(qi, ki, block_q, block_k, compute)
+        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile)
     else:
-        compute(False)
+        tile(None)
 
-    @pl.when(ki == n_kblocks - 1)
+    @pl.when(ki == _last_kblock(qi, block_q, block_k, n_kblocks, causal))
     def _write():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, block_q, block_k,
-                causal, scale, n_qblocks):
-    """dK/dV for one (batch*head, k-block) cell; q innermost.
-    dV += P^T @ dO; dK += dS^T @ Q."""
+def _dkv_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, block_q,
+                block_k, chunk, causal, scale, n_qblocks):
+    """dK/dV, one tile of ``_steps`` a grid step, a k-block's q-blocks in
+    a row from the first one the diagonal lets it see. The tile is
+    computed kv-major, S^T = K @ Q^T and dP^T = V @ dO^T, so that
+    dV += P^T @ dO and dK += dS^T @ Q are plain products: no operand is
+    contracted over its major dim, no tile is transposed. lse2 and delta
+    ride lanes as (1, block_q) rows."""
     import jax.experimental.pallas as pl
 
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi = qi_ref[pl.program_id(1)]
+    ki = ki_ref[pl.program_id(1)]
 
-    @pl.when(qi == 0)
+    @pl.when(qi == ((ki * block_k) // block_q if causal else 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def compute(masked):
-        do = do_ref[0]
-        p, ds = _recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                delta_ref, qi, ki, block_q, block_k,
-                                masked, scale)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (block_k, D)
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (block_k, D)
+    def tile(rel):
+        def rows_at(lo):
+            start, full_start = _visible(rel, lo, chunk, block_q, True)
+            if start == block_q:
+                return
+            rows = pl.ds(lo, chunk)
+            q = q_ref[0, start:]                          # (n, D)
+            do = do_ref[0, start:]
+            s = lax.mul(_dot(k_ref[0, rows], q, _NT), scale * _LOG2E)
+            if start < full_start:
+                s = _mask(s, 0, full_start - start, rel - lo + start, 0)
+            across = lambda row: lax.broadcast_in_dim(  # noqa: E731
+                row, s.shape, (0, 1))                     # (chunk, n)
+            p = lax.exp2(lax.sub(s, across(lse_ref[0, :, start:])))
+            dv_scr[rows] = lax.add(dv_scr[rows], _dot(
+                lax.convert_element_type(p, do.dtype), do, _NN))
+            ds = lax.mul(p, lax.sub(_dot(v_ref[0, rows], do, _NT),
+                                    across(delta_ref[0, :, start:])))
+            dk_scr[rows] = lax.add(dk_scr[rows], _dot(
+                lax.convert_element_type(ds, q.dtype), q, _NN))
+
+        _for_chunks(rel, block_k, chunk, _GROUP["dkv"], rows_at)
 
     if causal:
-        _causal_dispatch(qi, ki, block_q, block_k, compute)
+        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile)
     else:
-        compute(False)
+        tile(None)
 
     @pl.when(qi == n_qblocks - 1)
     def _write():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_operands(q, k, v, o, lse, g):
+    """Flattened operands the two backward kernels share. delta_i =
+    sum_d dO_i * O_i is the rowwise correction in dS; O(S*D)."""
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    dof = g.reshape(b * h, sq, d)
+    delta = jnp.sum(dof.astype(jnp.float32)
+                    * o.reshape(b * h, sq, d).astype(jnp.float32), axis=-1)
+    # the O(S) per-row vectors cross HBM as (bh, 1, sq) rows
+    return (q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
+            v.reshape(b * h, sk, d), dof, (lse * _LOG2E)[:, None, :],
+            delta[:, None, :])
+
+
+def _pallas_dq(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
+               block_k, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, sq, d = qf.shape
+    sk = kf.shape[1]
+    block_q, block_k = _fit(block_q, block_k, sq, sk)
+    chunk = _chunk(block_q)
+    n_kblocks = sk // block_k
+    steps = _steps(sq // block_q, n_kblocks, block_q, block_k, causal, False)
+    item = qf.dtype.itemsize
+    vmem = ((6 * block_q + 4 * block_k) * d * item
+            + block_q * (d + 2 * _LANES) * 4 + 6 * chunk * block_k * 4)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
+                          chunk=chunk, causal=causal, scale=scale,
+                          n_kblocks=n_kblocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, steps[0].size),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), _q_rows),
+                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_q, d), _q_rows),
+                pl.BlockSpec((1, 1, block_q), _q_lanes),
+                pl.BlockSpec((1, 1, block_q), _q_lanes),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d), _q_rows),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                            pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, _LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), qf.dtype),
+        compiler_params=_compiler_params(interpret, vmem),
+        interpret=interpret,
+        name="mx_flash_dq",
+    )(*steps, qf, kf, vf, dof, lse2, delta)
+
+
+def _pallas_dkv(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
+                block_k, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, sq, d = qf.shape
+    sk = kf.shape[1]
+    block_q, block_k = _fit(block_q, block_k, sq, sk)
+    chunk = _chunk(block_k)
+    n_qblocks = sq // block_q
+    steps = _steps(n_qblocks, sk // block_k, block_q, block_k, causal, True)
+    item = qf.dtype.itemsize
+    vmem = ((4 * block_q + 8 * block_k) * d * item
+            + 2 * block_k * d * 4 + 6 * chunk * block_q * 4)
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
+                          chunk=chunk, causal=causal, scale=scale,
+                          n_qblocks=n_qblocks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, steps[0].size),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), _q_rows),
+                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_q, d), _q_rows),
+                pl.BlockSpec((1, 1, block_q), _q_lanes),
+                pl.BlockSpec((1, 1, block_q), _q_lanes),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_k, d), _k_rows),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sk, d), kf.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), vf.dtype),
+        ],
+        compiler_params=_compiler_params(interpret, vmem),
+        interpret=interpret,
+        name="mx_flash_dkv",
+    )(*steps, qf, kf, vf, dof, lse2, delta)
+
+
+def _backward(q, k, v, o, lse, g, causal, scale, dq_blocks, dkv_blocks,
+              interpret):
+    """dq, dk, dv from the saved output and row logsumexp ``lse``
+    (bh, sq), each kernel on its own (block_q, block_k)."""
+    ops = _bwd_operands(q, k, v, o, lse, g)
+    dq = _pallas_dq(*ops, causal, scale, *dq_blocks, interpret)
+    dk, dv = _pallas_dkv(*ops, causal, scale, *dkv_blocks, interpret)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _pallas_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                      interpret):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    block_q = _fit_block(block_q, sq, 8)
-    block_k = _fit_block(block_k, sk, 128)
-    qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
-    dof = g.reshape(b * h, sq, d)
-    # the O(S) per-row residual/correction vectors ride a 128-lane minor
-    # dim only here, transiently, for the Mosaic block constraint — the
-    # saved residual itself is (bh, sq)
-    lse = jnp.broadcast_to(lse[:, :, None], (b * h, sq, _LANES))
-    # delta_i = sum_d dO_i * O_i — the rowwise correction in dS; O(S*D)
-    delta = jnp.broadcast_to(
-        jnp.sum(dof.astype(jnp.float32)
-                * o.reshape(b * h, sq, d).astype(jnp.float32),
-                axis=-1, keepdims=True), (b * h, sq, _LANES))
-    n_qblocks = sq // block_q
-    n_kblocks = sk // block_k
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale, n_kblocks=n_kblocks),
-        grid=(b * h, n_qblocks, n_kblocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, i, j: (bh, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name="mx_flash_dq",
-    )(qf, kf, vf, dof, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale, n_qblocks=n_qblocks),
-        grid=(b * h, n_kblocks, n_qblocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, j, i: (bh, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-        name="mx_flash_dkv",
-    )(qf, kf, vf, dof, lse, delta)
-
-    return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
-            dv.reshape(b, h, sk, d))
+    """``_backward`` with one (block_q, block_k) for both kernels: what
+    parallel/ring_flash.py calls per hop."""
+    return _backward(q, k, v, o, lse, g, causal, scale, (block_q, block_k),
+                     (block_q, block_k), interpret)
 
 
 def _use_pallas():
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, scale, blocks, interpret):
+    """``blocks``: the (block_q, block_k) of forward, dq and dk/dv."""
     if interpret or _use_pallas():
-        return _pallas_forward(q, k, v, causal, scale, block_q, block_k,
+        return _pallas_forward(q, k, v, causal, scale, *blocks[0],
                                interpret)[0]
     return attention_reference(q, k, v, causal=causal, scale=scale)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, blocks, interpret):
     if interpret or _use_pallas():
-        out, lse = _pallas_forward(q, k, v, causal, scale, block_q, block_k,
+        out, lse = _pallas_forward(q, k, v, causal, scale, *blocks[0],
                                    interpret)
         # keep one lane of the (bh, sq, 128) kernel output — the lane dim
         # exists only for Mosaic's block constraint, not worth 128x HBM
@@ -416,112 +699,90 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return out, (q, k, v, None, None)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, scale, blocks, interpret, res, g):
     q, k, v, o, lse = res
     if lse is None:
         _, vjp = jax.vjp(
             lambda q_, k_, v_: attention_reference(q_, k_, v_, causal=causal,
                                                    scale=scale), q, k, v)
         return vjp(g)
-    return _pallas_backward(q, k, v, o, lse, g, causal, scale, block_q,
-                            block_k, interpret)
+    return _backward(q, k, v, o, lse, g, causal, scale, blocks[1], blocks[2],
+                     interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# Measured block optima, one v5e chip, causal fwd+bwd (round-3 scans).
-# Isolated-kernel winners and in-context (full remat train step) winners
-# DIFFER: at seq 2048 the isolated scan prefers (512,512) by 20%, but
-# inside the remat'd transformer step (1024,1024) is 2% faster end to
-# end — VMEM pressure and recompute scheduling shift the optimum. The
-# table holds in-context winners; MXTPU_FLASH_AUTOTUNE=1 searches the
-# exact shape (isolated — verify winners in context before pinning).
-_BLOCK_TABLE = {
-    2048: (1024, 1024),
-    4096: (1024, 1024),
-    8192: (1024, 1024),
-}
-_TUNE_CANDIDATES = [(512, 512), (512, 1024), (1024, 512), (1024, 1024),
-                    (2048, 512), (256, 512)]
-_TUNE_CACHE = {}  # mxlint: disable=MX003 (GIL-atomic memo of measured block sizes; a racing duplicate tune costs time, never correctness)
+def _default_blocks(sq, sk, d=128, dtype=jnp.bfloat16):
+    """((block_q, block_k) of forward, of dq, of dk/dv): each kernel's
+    fastest tile on one v5e chip (PERF.md, PR 27, has the sweeps at
+    [8, 32, 2048, 128] and [1, 32, 16384, 128] bf16). Forward wants a
+    tall tile (K/V stream once per 2048 q rows, the accumulator rescale
+    is shared by 1024 columns); dq and dk/dv, which carry no running
+    statistics, the largest square that leaves VMEM room. Wider heads
+    and 4-byte operands halve the tile to keep that room. Blocks clamp
+    to the sequence."""
+    big = 2048 if d <= 128 and jnp.dtype(dtype).itemsize <= 2 else 1024
+    return tuple(_fit(bq, bk, sq, sk)
+                 for bq, bk in ((big, 1024), (big, big), (big, big)))
 
 
-def _default_blocks(seq):
-    if seq in _BLOCK_TABLE:
-        return _BLOCK_TABLE[seq]
-    if seq <= 2048:
-        return (512, 512)
-    if seq <= 4096:
-        return (1024, 1024)
-    return (2048, 512)
+def _computed_pairs(sq, sk, block_q, block_k, chunk, causal, kv_major):
+    """Score pairs one kernel computes for one head, from the same
+    classification its body uses."""
+    if not causal:
+        return sq * sk
+    static = len(_diag_rels(block_q, block_k)) <= _MAX_DIAG_BODIES
+    chunked, other = (block_k, block_q) if kv_major else (block_q, block_k)
+    total = 0
+    for qi in range(sq // block_q):
+        for ki in range(sk // block_k):
+            rel = qi * block_q - ki * block_k
+            if rel <= -block_q:
+                continue
+            if rel >= block_k - 1 or not static:
+                total += block_q * block_k
+                continue
+            for lo in range(0, chunked, chunk):
+                first, last = _visible(rel, lo, chunk, other, kv_major)
+                total += chunk * ((other - first) if kv_major else last)
+    return total
 
 
-def _autotune_blocks(q, k, v, causal, scale):
-    """Measure every candidate on the attached device for this exact
-    shape and cache the winner (enabled by MXTPU_FLASH_AUTOTUNE=1 —
-    the analog of the reference's cuDNN algo search,
-    ref: src/operator/nn/cudnn/cudnn_algoreg-inl.h)."""
-    import time
-    key = (q.shape, causal)
-    if key in _TUNE_CACHE:
-        return _TUNE_CACHE[key]
-    best, best_dt = None, float("inf")
-    for bq, bk in _TUNE_CANDIDATES:
-        if bq > q.shape[2] or bk > k.shape[2]:
-            continue
-        def loss(q_, k_, v_, bq=bq, bk=bk):
-            o = _flash(q_, k_, v_, causal, float(scale), bq, bk, False)
-            return jnp.sum(o.astype(jnp.float32))
-        # grad over ALL inputs so the dk/dv backward kernel is part
-        # of what gets timed (grad on q alone would let XLA DCE it)
-        grad = jax.grad(loss, argnums=(0, 1, 2))
+# metrics()["flash"] / dumps(): one entry per distinct call shape, made
+# when the call is traced — the tile shape each kernel took and the
+# score pairs it computes over the pairs the mask keeps (1.0 = no work
+# above the diagonal). Costs nothing a step.
+_CALLS = {}  # mxlint: disable=MX003 (GIL-atomic trace-time record, one string per call shape; a racing duplicate writes the same value)
 
-        @jax.jit  # mxlint: disable=MX005,MX022 (tuning micro-bench: compiled once per candidate block size inside the memoized autotune pass, timed by the autotuner itself)
-        def many(q_, k_, v_):
-            # chained fori so the device actually serializes the
-            # iterations (async dispatch would lie to the timer)
-            def body(i, qkv):
-                qq, kk, vv = qkv
-                dq, dk, dv = grad(qq, kk, vv)
-                return (qq + 1e-12 * dq, kk + 1e-12 * dk,
-                        vv + 1e-12 * dv)
-            return lax.fori_loop(0, 5, body, (q_, k_, v_))[0]
 
-        # a candidate the compiler refuses raises: the candidate list
-        # is ours, so a refusal is a wrong list, not a slow block size
-        warm = many(q, k, v)  # compile
-        # allocation-ledger choke point (ISSUE 13a): the autotune
-        # trial buffers are the 'workspace' tag — the transient HBM
-        # spike a tuning pass costs shows up attributed, not as
-        # anonymous growth
-        from .. import storage as _storage
-        _storage.ledger_register(warm, "workspace",
-                                 site="flash.autotune")
-        float(jnp.sum(warm.astype(jnp.float32)))
-        # mxlint: disable=MX014 (host-side autotune timing: the measured winner is memoized per shape and MXTPU_FLASH_AUTOTUNE is a signature token, so timing noise never changes an already-cached executable)
-        t0 = time.perf_counter()
-        float(jnp.sum(many(q, k, v).astype(jnp.float32)))
-        # mxlint: disable=MX014 (host-side autotune timing, see t0 above)
-        dt = time.perf_counter() - t0
-        if dt < best_dt:
-            best, best_dt = (bq, bk), dt
-    if best is None:
-        # every candidate is larger than this sequence: nothing to time
-        return _default_blocks(q.shape[2])
-    _TUNE_CACHE[key] = best
-    return best
+def _record_call(q, k, causal, blocks):
+    b, h, sq, d = q.shape
+    sk = k.shape[2]
+    kept = sq * (sq + 1) // 2 if causal else sq * sk
+    parts = []
+    for name, (bq, bk) in zip(("fwd", "dq", "dkv"), blocks):
+        kv_major = name == "dkv"
+        chunk = _chunk(bk if kv_major else bq)
+        pairs = _computed_pairs(sq, sk, bq, bk, chunk, causal, kv_major)
+        parts.append("%s=%dx%d/%.4f" % (name, bq, bk, pairs / kept))
+    _CALLS["%dx%dx%dx%dx%d.%s.%s" % (
+        b, h, sq, sk, d, jnp.dtype(q.dtype).name,
+        "causal" if causal else "full")] = " ".join(parts)
+
+
+_profiler.register_stats_provider("flash", lambda: dict(_CALLS),
+                                  _CALLS.clear)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=False):
     """Tiled attention. q,k,v: [B, H, S, D]. On TPU runs the Pallas
-    kernel; elsewhere the jnp reference (or the kernel under
-    ``interpret=True`` for testing). block_q/block_k default to the
-    measured per-shape optimum (table above; exact-shape search with
-    MXTPU_FLASH_AUTOTUNE=1); explicit values override. Blocks clamp to
-    the sequence length."""
-    import os
+    kernels; elsewhere the jnp reference (or the kernels under
+    ``interpret=True`` for testing). By default each of the three kernels
+    takes its own measured tile shape (``_default_blocks``); an explicit
+    block_q/block_k applies to all three. Blocks clamp to the sequence
+    length."""
     if causal and q.shape[2] != k.shape[2]:
         # This kernel's causal mask is LEFT-aligned (col > row masked),
         # which is only the right semantics when q and kv index the
@@ -540,20 +801,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             % (q.shape[2], k.shape[2]))
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if block_q is None or block_k is None:
-        # autotune needs CONCRETE arrays (it executes candidates); under
-        # jit tracing fall back to the table — tune eagerly once with
-        # the training shapes, then the cached winner applies
-        concrete = not isinstance(q, jax.core.Tracer)
-        key = (q.shape, causal)
-        if key in _TUNE_CACHE:
-            dq, dk = _TUNE_CACHE[key]
-        elif _getenv("MXTPU_FLASH_AUTOTUNE") == "1" \
-                and concrete and jax.devices()[0].platform == "tpu":
-            dq, dk = _autotune_blocks(q, k, v, causal, float(scale))
-        else:
-            dq, dk = _default_blocks(q.shape[2])
-        block_q = dq if block_q is None else block_q
-        block_k = dk if block_k is None else block_k
-    return _flash(q, k, v, causal, float(scale), int(block_q), int(block_k),
+    sq, sk = q.shape[2], k.shape[2]
+    blocks = tuple(
+        _fit(bq if block_q is None else int(block_q),
+             bk if block_k is None else int(block_k), sq, sk)
+        for bq, bk in _default_blocks(sq, sk, q.shape[-1], q.dtype))
+    if interpret or _use_pallas():
+        _record_call(q, k, causal, blocks)
+    return _flash(q, k, v, bool(causal), float(scale), blocks,
                   bool(interpret))

@@ -83,6 +83,7 @@ def test_phase_a_fails_on_a_fallback(monkeypatch):
 def test_phase_b_kernels_against_references():
     rec = chip_smoke.phase_b(
         interpret=True, flash_shape=(2, 1, 128, 64), flash_long_shape=None,
+        flash_cell_shapes=(),
         flash_dtype="float32", bn_shapes=(((128, 128), "bfloat16"),),
         qmm_shape=(32, 128, 128), twobit_n=2048)
     assert len(rec["kernels"]) == 4
@@ -95,7 +96,8 @@ def test_phase_b_fails_when_the_kernel_is_wrong(monkeypatch):
                         lambda w, n, **kw: PK.dequantize_2bit_jnp(w, n) + 1)
     with pytest.raises(AssertionError, match="quantize_2bit"):
         chip_smoke.phase_b(interpret=True, flash_shape=None,
-                           flash_long_shape=None, bn_shapes=(),
+                           flash_long_shape=None, flash_cell_shapes=(),
+                           bn_shapes=(),
                            qmm_shape=None, twobit_n=2048)
 
 

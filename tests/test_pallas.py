@@ -1,6 +1,8 @@
 """Pallas kernel tests — run under interpret mode on the CPU test platform
 (ref slot: src/common/rtc.cc custom-kernel tests, tests/python/gpu/test_rtc.py;
 gradient compression: tests/nightly/test_kvstore.py compression cases)."""
+import importlib
+
 import numpy as onp
 import pytest
 
@@ -98,6 +100,118 @@ class TestFlashAttention:
                                                     interpret=True))
         ref = attention_reference(q, k, v, causal=True)
         assert float(jnp.abs(f(q, k, v) - ref).max()) < 1e-5
+
+
+FA = importlib.import_module("mxnet_tpu.pallas_kernels.flash_attention")
+
+
+def _fwd_and_grads(f, q, k, v):
+    """Output and all three gradients of sum(f(q, k, v) * w) for a fixed
+    random cotangent w."""
+    w = jnp.asarray(onp.random.RandomState(7).randn(*q.shape)
+                    .astype("float32"))
+    out, vjp = jax.vjp(f, q, k, v)
+    return (out,) + tuple(vjp(w.astype(out.dtype)))
+
+
+def _assert_close(got, want, tol):
+    for g, r in zip(got, want):
+        scale = float(jnp.abs(r).max())
+        err = float(jnp.abs(g.astype(jnp.float32) - r).max())
+        assert err <= tol * scale, (err, scale)
+
+
+class TestFlashTiles:
+    """The three kernels at their own default tile shapes and the causal
+    chunking at its edges (ISSUE 27), in interpret mode."""
+
+    @pytest.fixture
+    def scaled(self, monkeypatch):
+        """The kernels' defaults at a cell's sequence length, everything
+        (sequence, blocks, chunk rows) divided by ``by``."""
+        def make(cell_seq, by):
+            monkeypatch.setattr(FA, "_CHUNK", FA._CHUNK // by)
+            blocks = tuple((bq // by, bk // by) for bq, bk in
+                           FA._default_blocks(cell_seq, cell_seq))
+            return cell_seq // by, blocks
+        return make
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("d", [128, 64])
+    @pytest.mark.parametrize("cell_seq", [2048, 16384])
+    def test_default_tiles_scaled_down(self, scaled, cell_seq, d, dtype,
+                                       causal):
+        s, blocks = scaled(cell_seq, 8)
+        q, k, v = (x.astype(dtype) for x in _qkv(b=1, h=1, s=s, d=d))
+        got = _fwd_and_grads(
+            lambda a, b, c: FA._flash(a, b, c, causal, d ** -0.5, blocks,
+                                      True), q, k, v)
+        want = _fwd_and_grads(
+            lambda a, b, c: attention_reference(a, b, c, causal=causal),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        assert all(g.dtype == q.dtype for g in got)
+        _assert_close(got, want, 4e-2 if dtype == "bfloat16" else 2e-5)
+
+    @pytest.mark.parametrize("blocks,chunk", [
+        ((256, 256), 128),      # two chunks a diagonal tile, ranges end
+        #                         on lane tiles: the shape of the chip's
+        ((256, 256), 256),      # chunk equal to the block
+        ((512, 512), 128),      # a sequence of exactly one block
+        ((512, 128), 128),      # tall tile: four diagonal bodies
+        ((128, 512), 64),       # wide tile, chunks inside one lane tile
+        ((64, 128), 8),         # too many diagonal bodies: dynamic rel
+    ])
+    def test_causal_chunks(self, monkeypatch, blocks, chunk):
+        monkeypatch.setattr(FA, "_CHUNK", chunk)
+        q, k, v = _qkv(b=1, h=2, s=512, d=64)
+        got = _fwd_and_grads(
+            lambda a, b, c: flash_attention(a, b, c, causal=True,
+                                            block_q=blocks[0],
+                                            block_k=blocks[1],
+                                            interpret=True), q, k, v)
+        want = _fwd_and_grads(
+            lambda a, b, c: attention_reference(a, b, c, causal=True),
+            q, k, v)
+        _assert_close(got, want, 2e-5)
+
+    def test_cross_attention_per_kernel_tiles(self):
+        """sq != sk, non-causal, each kernel on a tile shape of its own."""
+        q, _, _ = _qkv(b=1, h=2, s=256, d=64)
+        _, k, v = _qkv(b=1, h=2, s=512, d=64, seed=1)
+        blocks = ((128, 256), (256, 128), (128, 512))
+        got = _fwd_and_grads(
+            lambda a, b, c: FA._flash(a, b, c, False, 0.125, blocks, True),
+            q, k, v)
+        want = _fwd_and_grads(attention_reference, q, k, v)
+        _assert_close(got, want, 2e-5)
+
+    @pytest.mark.parametrize("seq,at_most", [(2048, 1.25), (16384, 1.06)])
+    def test_flash_entry_reports_tiles_and_computed_pairs(self, seq,
+                                                          at_most):
+        """metrics()['flash'] has one entry a call shape: each kernel's
+        tile and the score pairs it computes over the pairs the mask
+        keeps. Whole diagonal tiles cost 1.50x at 2048 and 1.125x at
+        16384 with 1024 x 1024 tiles; chunked ones stay under the
+        issue's 1.25x / 1.06x."""
+        import mxnet_tpu as mx
+        x = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.bfloat16)
+        jax.eval_shape(lambda a, b, c: flash_attention(
+            a, b, c, causal=True, interpret=True), x, x, x)
+        entry = mx.profiler.metrics()["flash"][
+            "1x2x%dx%dx128.bfloat16.causal" % (seq, seq)]
+        kernels = dict(part.split("=") for part in entry.split())
+        assert sorted(kernels) == ["dkv", "dq", "fwd"]
+        for name, val in kernels.items():
+            tile, ratio = val.split("/")
+            bq, bk = map(int, tile.split("x"))
+            assert seq % bq == 0 and seq % bk == 0
+            assert 1.0 <= float(ratio) <= at_most, (name, val)
+            whole = FA._computed_pairs(seq, seq, bq, bk, bk if name == "dkv"
+                                       else bq, True, name == "dkv")
+            assert whole / (seq * (seq + 1) // 2) > float(ratio)
+        assert any(line.startswith("flash: ")
+                   for line in mx.profiler.dumps().splitlines())
 
 
 class TestCompression:

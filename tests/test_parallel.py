@@ -409,10 +409,20 @@ def test_selective_remat_matches_full():
 
 
 def test_flash_block_defaults_table():
-    """Per-shape default blocks come from the measured table and clamp
-    to the sequence length."""
+    """Each kernel has a default tile shape of its own, a function of the
+    call's shape and dtype, clamped to the sequence length."""
+    import jax.numpy as jnp
     from mxnet_tpu.pallas_kernels.flash_attention import _default_blocks
-    assert _default_blocks(2048) == (1024, 1024)
-    assert _default_blocks(8192) == (1024, 1024)
-    bq, bk = _default_blocks(64)
-    assert bq <= 512 and bk <= 512
+    # (forward, dq, dk/dv) at the benchmark's two attention shapes
+    assert _default_blocks(2048, 2048) == (
+        (2048, 1024), (2048, 2048), (2048, 2048))
+    assert _default_blocks(16384, 16384) == _default_blocks(2048, 2048)
+    # 4-byte operands and heads wider than 128 halve the tall side
+    assert _default_blocks(8192, 8192, 128, jnp.float32) == (
+        (1024, 1024),) * 3
+    assert _default_blocks(8192, 8192, 256) == ((1024, 1024),) * 3
+    # cross-attention: q and kv blocks clamp to their own lengths
+    assert _default_blocks(512, 4096) == ((512, 1024), (512, 2048),
+                                          (512, 2048))
+    for bq, bk in _default_blocks(64, 64):
+        assert bq <= 64 and bk <= 64

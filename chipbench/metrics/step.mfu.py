@@ -1,6 +1,7 @@
 """The whole step's share of the chips' bf16 peak: operations the forward
-and backward REQUIRE (chipbench/flops.py, from shapes) over the traced steady
-step time x chips x peak."""
+and backward REQUIRE (the kind's count, chipbench/counts/, from shapes) over
+the traced steady step time x chips x peak. The step time is the traced
+window over its whole steps, the cut first execution left out (trace.py)."""
 
 
 def read(run):

@@ -162,8 +162,8 @@ class Cell:
             classes=self.c["classes"])
 
     def work(self):
-        return {"kind": "resnet_v1", "model": self.c,
-                "batch": self.t["batch"], "image": self.t["image"],
+        return {"model": self.c, "batch": self.t["batch"],
+                "image": self.t["image"],
                 "items_per_step": self.t["batch"], "item": "img",
                 "dtype": self.a["dtype"]}
 

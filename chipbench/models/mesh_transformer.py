@@ -167,6 +167,17 @@ class Cell:
     def counters(self):
         return None  # the transformer path has no off-path counters yet
 
+    def program_text(self):
+        """The compiled step's text: each instruction with the ``mx.*``
+        scopes the program gave it (``metadata.op_name``), which the trace's
+        events lack. The jitted call has compiled the same program, so this
+        is answered from the process's own cache (0.03 s on the chip); the
+        harness still asks only in a traced run, after the window."""
+        tokens, targets = self.batches[0]
+        with self.mesh.mesh:
+            return self.step_fn.lower(self.state, tokens,
+                                      targets).compile().as_text()
+
     def free(self):
         import jax
         for leaf in jax.tree_util.tree_leaves((self.state, self.batches)):
@@ -188,8 +199,8 @@ class Cell:
             steps, variant=variant, devices=list(self.devices))
 
     def work(self):
-        return {"kind": "dense_decoder", "model": self.m,
-                "batch": self.t["batch"], "seq_len": self.t["seq_len"],
+        return {"model": self.m, "batch": self.t["batch"],
+                "seq_len": self.t["seq_len"],
                 "items_per_step": self.t["batch"] * self.t["seq_len"],
                 "item": "tokens", "dtype": self.a["dtype"]}
 

@@ -17,6 +17,7 @@ import importlib
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 
@@ -28,7 +29,9 @@ if ROOT not in sys.path:
 WARM_STEPS = 4      # steps 1-3 are followed by the reference; the arrival
 #                     of step 4's loss opens the window
 TRACE_FROM = 3      # in a traced run: profile from the window's 3rd arrival
-TRACE_SECONDS = 4.0  # ... for this long, and for three steps at least
+TRACE_SECONDS = 4.0  # ... for this long, and until five more have arrived:
+TRACE_ARRIVALS = 5   # the execution running at the start is cut and left
+#                      out (trace.py), so these hold three whole steps
 
 
 def say(*a):
@@ -49,21 +52,35 @@ def load_cell(name, root=ROOT):
         raise SystemExit("no workload %r in BENCHMARK.json" % name)
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     data = os.path.dirname(os.path.dirname(os.path.join(root, entry["file"])))
+    config = load_json(root, entry["file"])
     return {
-        "bench": bench, "cell": cell,
-        "config": load_json(root, entry["file"]),
+        "bench": bench, "cell": cell, "data": data, "config": config,
         "traffic": load_json(data, "traffic", cell["traffic"] + ".json"),
         "limits": load_json(data, "limits", name + ".json"),
+        "required": load_named("counts", config["flops"], data).required,
     }
 
 
-def metric_reader(name):
+def load_named(group, name, where=HERE):
+    """The module ``<group>/<name>.py`` under ``where`` (a test's own root
+    or directory) or else under chipbench/. A name with no file in either
+    is an error that names the file wanted."""
+    tried = [os.path.join(base, group, name + ".py")
+             for base in dict.fromkeys((where, HERE))]
+    path = next((p for p in tried if os.path.exists(p)), None)
+    if path is None:
+        raise SystemExit("chipbench: %r needs the file %s"
+                         % (name, " or ".join(tried)))
     spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"),
-        os.path.join(HERE, "metrics", name + ".py"))
+        "chipbench_%s_%s" % (group, re.sub(r"\W", "_", name)), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name, where=HERE):
+    """``read(run) -> float or None`` of ``metrics/<name>.py``."""
+    return load_named("metrics", name, where).read
 
 
 def metrics_of(bench, cell_name, group):
@@ -180,7 +197,7 @@ def timed_window(cell, seconds, first, trace_dir=None, clock=time.perf_counter):
             jax.profiler.start_trace(trace_dir)
             tracing, traced_from = True, (clock(), len(gaps))
         elif tracing and clock() - traced_from[0] >= TRACE_SECONDS \
-                and len(gaps) - traced_from[1] >= 4:
+                and len(gaps) - traced_from[1] >= TRACE_ARRIVALS:
             jax.profiler.stop_trace()
             tracing = False
     if tracing:
@@ -226,13 +243,24 @@ def run_cell(workload, seed, seconds, trace, devices=None, keep_trace=False,
     counters = None if counters1 is None else {
         k: counters1[k] - counters0.get(k, 0) for k in counters1}
     work = cell.work()
+    required = spec["required"](work)
+    # the compiled step's text, for the trace's scopes: asked for only here,
+    # after the window and the peak, so in neither setup_s, step_ms nor the
+    # peak
+    program_text, program_text_s = None, 0.0
+    if trace and hasattr(cell, "program_text"):
+        t = time.perf_counter()
+        program_text = cell.program_text()
+        program_text_s = time.perf_counter() - t
     failed = sum(1 for v in losses if v != v or v in (float("inf"),
                                                       float("-inf")))
     step_ms = 1e3 * window_s / len(gaps)
-    say("chipbench: %s seed %d: %d steps in %.3f s, %.3f ms a step, %.1f %s/s;"
-        " set-up %.1f s; three largest gaps (ms): %s"
+    say("chipbench: %s seed %d: %d steps in %.3f s, %.3f ms a step, %.1f %s/s,"
+        " %.6g GF a step required (counts/%s.py); set-up %.1f s; three "
+        "largest gaps (ms): %s"
         % (workload, seed, len(gaps), window_s, step_ms,
            work["items_per_step"] / (window_s / len(gaps)), work["item"],
+           required["step_flops"] / 1e9, config["flops"],
            setup_s, ", ".join("%.1f" % (1e3 * g)
                               for g in sorted(gaps, reverse=True)[:3])))
     if not trace:
@@ -263,26 +291,31 @@ def run_cell(workload, seed, seconds, trace, devices=None, keep_trace=False,
     result = {"correct": bool(correct), "attempted": len(gaps),
               "failed": failed}
     if trace:
-        from chipbench import flops, trace as tr
-        reduced = tr.reduce(tr.load(tr.find_xplane(trace_dir)))
+        from chipbench import trace as tr
+        reduced = tr.reduce(tr.load(tr.find_xplane(trace_dir)), program_text)
         if not keep_trace:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        for name, k in sorted(reduced["kernels"].items()):
+            say("chipbench: kernel %s: %g calls and %.3f ms a step"
+                % (name, k["calls"] / reduced["steps"],
+                   1e3 * k["s"] / reduced["steps"]))
         run = {"gaps_ms": [1e3 * g for g in gaps],
                "dispatch_ms": [1e3 * d for d in dispatch],
                "trace": reduced, "work": work,
-               "required": flops.required(work),
+               "required": required,
                "peaks": peaks_for(dev0.device_kind) if on_chip else None,
                "chips": len(devices), "counters": counters,
                "first_call_s": first_call_s, "memory_peak_bytes": peak}
         group, values = "per_layer", {}
         for m in metrics_of(spec["bench"], workload, group):
-            v = metric_reader(m["name"])(run)
+            v = metric_reader(m["name"], spec["data"])(run)
             if v is not None:
                 values[m["name"]] = v
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+        result["program_text_s"] = program_text_s
     units = {m["name"]: m["unit"] for m in spec["bench"][group]}
     result["metrics"] = {
         m["name"]: {"value": values[m["name"]], "unit": units[m["name"]]}

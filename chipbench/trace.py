@@ -7,18 +7,23 @@ tested on a hand-made trace):
                                    "modules": [(name, start_ns, dur_ns), ...]}},
      "host": [(name, start_ns, dur_ns), ...]}     # the harness's own spans
 
-The traced window runs from the start of the step program's first execution
+The traced window runs from the start of the step program's SECOND execution
 in the trace to the start of its last, on the first device: whole steps with
-the gaps between them, nothing of the profiler's own start and stop.
+the gaps between them, nothing of the profiler's own start and stop. The
+execution that was running when the trace began is recorded from the trace's
+start, not its own, so it is left out.
 """
 import glob
 import os
 import re
 
+from chipbench import scopes
+
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "all-to-all")
 SMALL_GAP_NS = 20_000
-HOST_SPANS = ("chipbench.dispatch", "chipbench.read_loss")
+# the harness's own spans and the program's own call inside the first
+HOST_SPANS = ("chipbench.dispatch", "chipbench.read_loss", "mx.train_step")
 
 
 def find_xplane(trace_dir):
@@ -67,8 +72,24 @@ def opcode(name):
 
 
 def is_mosaic(name):
-    """A Pallas kernel runs as a custom call (target tpu_custom_call)."""
-    return opcode(name) == "custom-call"
+    """A Pallas kernel runs as a custom call whose target is
+    ``tpu_custom_call``; the compiler's own custom calls (ConcatBitcast,
+    AllocateBuffer: no time at all) are none."""
+    return (opcode(name) == "custom-call"
+            and 'custom_call_target="tpu_custom_call"' in name)
+
+
+def instruction(name):
+    """The name of the instruction an "XLA Ops" event ran: the text before
+    `` = ``, without its ``%``. The compiled program's text has the same."""
+    return name.partition(" = ")[0].strip().lstrip("%")
+
+
+def kernel_name(name):
+    """A custom call's kernel: its instruction's name without the ``.<n>``
+    the compiler appends (``%mx_flash_fwd.18`` -> ``mx_flash_fwd``: what the
+    Pallas call gave as ``name=``)."""
+    return re.sub(r"\.\d+$", "", instruction(name))
 
 
 def is_collective(name):
@@ -102,13 +123,15 @@ def step_module(modules):
 
 
 def window_of(dev):
-    """(start_ns, end_ns, whole steps) of the traced window on one device."""
+    """(start_ns, end_ns, whole steps) of the traced window on one device:
+    from the start of the step program's second execution in the trace (the
+    first is cut: see the top) to the start of its last."""
     name = step_module(dev["modules"])
     starts = sorted(s for n, s, _ in dev["modules"] if n == name)
-    if len(starts) < 2:
-        raise ValueError("the trace holds %d executions of %s: no whole step"
-                         % (len(starts), name))
-    return starts[0], starts[-1], len(starts) - 1
+    if len(starts) < 3:
+        raise ValueError("the trace holds %d executions of %s: the first is "
+                         "cut, so no whole step" % (len(starts), name))
+    return starts[1], starts[-1], len(starts) - 2
 
 
 def _clip(ops, lo, hi):
@@ -118,8 +141,10 @@ def _clip(ops, lo, hi):
             yield name, a, b
 
 
-def reduce(trace):
-    """-> the numbers the per-layer readers and the result line take."""
+def reduce(trace, program_text=None):
+    """-> the numbers the per-layer readers and the result line take.
+    ``program_text``: the compiled step's text where the adapter has it;
+    then device time is also split by ``mx.*`` scope and phase."""
     names = sorted(trace["devices"])
     if not names:
         raise ValueError("the trace holds no TPU device plane")
@@ -136,43 +161,90 @@ def reduce(trace):
                         + [(max(b for _, _, b in ops), hi)])
             first = (ops, [g for g in gaps if g[1] > g[0]])
     ops, gaps = first
-    by_name, mosaic, coll = {}, 0.0, 0.0
+    op_names = None if program_text is None else scopes.scope_map(program_text)
+    by_label, kernels, by_scope = {}, {}, {}
+    op_sum = mosaic = coll = 0.0
     for name, a, b in ops:
-        if opcode(name) in CONTAINERS:
+        what = opcode(name)
+        if what in CONTAINERS:
             continue
-        by_name[name] = by_name.get(name, 0.0) + (b - a)
+        op_sum += b - a
         if is_collective(name):
             coll += b - a
         elif is_mosaic(name):
             mosaic += b - a
-    busy0 = busy_each[0]
-    op_sum = sum(by_name.values())
-    # what the host was doing in each idle gap: the harness's span that
-    # covers the gap's middle
+            what = kernel_name(name)
+            k = kernels.setdefault(what, {"s": 0.0, "calls": 0})
+            k["s"] += (b - a) / 1e9
+            k["calls"] += 1
+        label = name    # the instruction's text, where no scope is known
+        if op_names is not None:
+            scope, phase = scopes.classify(op_names.get(instruction(name), ""))
+            row = by_scope.setdefault(scope, dict.fromkeys(scopes.PHASES, 0.0))
+            row[phase] += (b - a) / 1e9
+            label = "%s %s %s" % (scope, phase, what)
+        by_label[label] = by_label.get(label, 0.0) + (b - a)
+    # what the host was doing in each idle gap: the innermost (shortest) of
+    # the harness's and the program's spans that cover the gap's middle
     by_host = {}
     for a, b in gaps:
         if b - a < SMALL_GAP_NS:
             label = "between_operations_each_under_20_us"
         else:
             mid = (a + b) / 2
-            label = next((n for n, s, d in trace["host"]
-                          if s <= mid <= s + d), "outside_chipbench_spans")
+            over = [(d, n) for n, s, d in trace["host"] if s <= mid <= s + d]
+            label = min(over)[1] if over else "outside_chipbench_spans"
         by_host[label] = by_host.get(label, 0.0) + (b - a)
     top = lambda d: [[k[:160], v / 1e9] for k, v in
                      sorted(d.items(), key=lambda kv: -kv[1])[:10]]
-    return {
+    out = {
         "window_s": window / 1e9,
         "busy_s": sum(busy_each) / len(busy_each) / 1e9,
         "steps": steps,
         "step_s": window / steps / 1e9,
         "chips": len(names),
-        "idle_share": 1.0 - busy0 / window,
+        "idle_share": 1.0 - busy_each[0] / window,
         "op_sum_s": op_sum / 1e9,
         "mosaic_s": mosaic / 1e9,
         "collective_s": coll / 1e9,
-        "device_ops": top(by_name),
+        "kernels": kernels,
+        "device_ops": top(by_label),
         "idle_gaps": top(by_host),
     }
+    if op_names is not None:
+        rest = [r for s, r in by_scope.items() if s != scopes.OPTIMIZER]
+        out["scopes"] = by_scope
+        out["phases"] = {p: sum(r[p] for r in rest) for p in scopes.PHASES}
+        out["phases"]["optimizer"] = sum(
+            by_scope.get(scopes.OPTIMIZER, {}).values())
+    return out
+
+
+def kernel_roofline(run, prefix):
+    """A kernel family's share (%) of its roofline: the least time a chip
+    could take for the work the family REQUIRES in a step (the count's
+    ``kernels[prefix]``, from shapes: the larger of flops over ``bf16_flops``
+    and bytes over ``hbm_bytes_per_s``, per chip) over the device time a step
+    of the events whose kernel name starts with ``prefix``, on the first
+    device. None where the kind counts no such family or no such event ran."""
+    t, need = run["trace"], run["required"].get("kernels", {}).get(prefix)
+    spent = sum(k["s"] for n, k in t["kernels"].items() if n.startswith(prefix))
+    if need is None or spent <= 0:
+        return None
+    p = run["peaks"]
+    least = max(need["flops"] / p["bf16_flops"],
+                need["bytes"] / p["hbm_bytes_per_s"]) / run["chips"]
+    return 100.0 * least / (spent / t["steps"])
+
+
+def phase_share(run, phase):
+    """Device time (%) of ``phase`` (forward, backward, recompute or
+    optimizer) over the summed device time in the traced window. None where
+    the adapter gave no program text, so no scope is known."""
+    t = run["trace"]
+    if "phases" not in t or t["op_sum_s"] <= 0:
+        return None
+    return 100.0 * t["phases"][phase] / t["op_sum_s"]
 
 
 def describe(path, n=40):

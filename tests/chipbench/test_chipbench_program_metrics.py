@@ -37,10 +37,13 @@ def driven():
                          jax.devices()[:1])
     run.first_steps(cell)
     gaps, _, dispatch = run.timed_window(cell, 0.2, run.WARM_STEPS - 1)
-    # what compiles after the window (the reference does) is not set-up
+    # what compiles after the window (the reference does, and the step's
+    # text for a traced run's scopes) is not set-up
     jax.jit(lambda x: x - 2)(jax.numpy.ones(5))
+    text = cell.program_text()
     assert len(dispatch) >= 2 and len(gaps) == len(dispatch) + 1
-    yield {"dispatch_ms": [1e3 * d for d in dispatch]}, profiler.metrics()
+    yield {"dispatch_ms": [1e3 * d for d in dispatch],
+           "program_text": text}, profiler.metrics()
     profiler._reset()
 
 
@@ -77,3 +80,18 @@ def test_a_reader_returns_none_where_the_section_is_missing(
               if k not in ("train_step", "jax_compile")}
     monkeypatch.setattr(profiler, "metrics", lambda reset=False: parent)
     assert run.metric_reader(name)(made) is None
+
+
+def test_the_adapters_program_text_carries_the_programs_scopes(driven):
+    """What ``run_cell`` hands ``trace.reduce`` in a traced run: the compiled
+    step's own text, every phase of the layer scopes in it; asking for it
+    after the window is no retrace of the step."""
+    from chipbench import scopes
+    made, m = driven
+    seen = {scopes.classify(op_name) for op_name in
+            scopes.scope_map(made["program_text"]).values()}
+    assert {(s, p) for s in ("mx.ffn", "mx.attn_proj", "mx.flash")
+            for p in scopes.PHASES} <= seen
+    assert {("mx.head_ce", "backward"), ("mx.embed", "forward"),
+            ("mx.optimizer", "forward")} <= seen
+    assert m["train_step"]["retraces"] == 0

@@ -19,6 +19,7 @@ from chipbench import check, limits, run  # noqa: E402
 TINY = os.path.join(ROOT, "tests", "chipbench", "tiny")
 SEED = 3000000019       # past 2**31, as the driver's seeds are
 DECODER, RESNET = "tiny_decoder-seq128", "tiny_resnet-img64"
+NEWKIND = "tiny_newkind-seq128"     # its count is a file of the tiny root
 
 
 def _run(workload, wrap=None, seconds=0.3):
@@ -75,6 +76,28 @@ def test_a_tiny_decoder_run_is_correct_and_whole(decoder_run):
 def test_the_same_seed_gives_the_same_first_steps(decoder_run):
     again = _run(DECODER, seconds=0.05)
     assert again["compared"] == decoder_run["compared"]
+
+
+def test_a_kind_whose_count_is_a_file_of_the_tiny_root_is_found(capsys):
+    r = _run(NEWKIND, seconds=0.05)
+    assert r["correct"] is True and r["attempted"] >= 2
+    assert ("%.6g GF a step required (counts/tiny_two_families.py)"
+            % (6000 * 4 * 128 / 1e9)) in capsys.readouterr().err
+
+
+def test_a_configuration_whose_count_has_no_file_fails_before_it_is_built(
+        tmp_path):
+    import shutil
+    root = shutil.copytree(TINY, str(tmp_path / "tiny"))
+    path = os.path.join(root, "configs", "tiny_newkind.json")
+    with open(path) as f:
+        config = json.load(f)
+    config["flops"] = "absent_kind"
+    with open(path, "w") as f:
+        json.dump(config, f)
+    with pytest.raises(SystemExit) as e:
+        run.run_cell(NEWKIND, SEED, 0.05, False, devices=[], root=root)
+    assert os.path.join(root, "counts", "absent_kind.py") in str(e.value)
 
 
 def _unchanged(cell):

@@ -13,7 +13,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import check, flops, run, trace  # noqa: E402
+from chipbench import check, run, scopes, trace  # noqa: E402
+
+TINY = os.path.join(ROOT, "tests", "chipbench", "tiny")
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -28,22 +30,55 @@ FLASH = ("%checkpoint.22 = (bf16[256,2048,128]{2,1,0:T(8,128)(2,1)}, bf16["
 WHILE = ("%while.15 = (s32[]{:T(128)}, /*index=5*/bf16[5,4096,11008]{2,1,0:"
          "T(8,128)(2,1)}) while((s32[]{:T(128)}) %tuple.1), condition=%c")
 ALLRED = "%all-reduce.3 = f32[16]{0} all-reduce(f32[16]{0} %x), channel_id=1"
+# a Pallas call's event is named by the kernel's ``name=`` (PERF.md)
+KERNEL = ("%%%s = (bf16[32,16384,128]{2,1,0:T(8,128)(2,1)}, f32[32,1,16384]"
+          "{2,1,0:T(1,128)}) custom-call(bf16[32,16384,128]{2,1,0:T(8,128)"
+          "(2,1)} %%bitcast.7), custom_call_target=\"tpu_custom_call\"")
+FLASH_FWD, FLASH_DQ = KERNEL % "mx_flash_fwd.18", KERNEL % "mx_flash_dq.11"
+GROUP = KERNEL % "mx_group_mm.3"
+CUT = 400.0     # us of the execution that was running when the trace began
+# the compiled step's text for the events above, as this runtime writes it
+TEXT = """
+HloModule jit_step_fn, is_scheduled=true
+%fused_computation.9 (p0: bf16[8,2048,4096]) -> bf16[8,2048,11008] {
+  ROOT %convolution.5 = bf16[8,2048,11008]{2,1,0} convolution(%p0, %p1), metadata={op_name="jit(step_fn)/transpose(jvp(mx.layer))/while/body/closed_call/checkpoint/mx.ffn/bsd,df->bsf/dot_general" stack_frame_id=9}
+}
+  %fusion.430 = bf16[8,2048,11008]{2,1,0:T(8,128)(2,1)} fusion(%remat2.225, %custom-call.15), kind=kOutput, calls=%fused_computation.9, metadata={op_name="jit(step_fn)/transpose(jvp(mx.layer))/while/body/closed_call/checkpoint/mx.ffn/bsd,df->bsf/dot_general" stack_frame_id=9}
+  %checkpoint.22 = (bf16[256,2048,128]{2,1,0}) custom-call(%pad.8), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/jvp(mx.layer)/while/body/closed_call/mx.flash/checkpoint"}
+  %mx_flash_fwd.18 = (bf16[32,16384,128]{2,1,0}) custom-call(%bitcast.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/transpose(jvp(mx.layer))/while/body/closed_call/checkpoint/rematted_computation/mx.flash/mx_flash_fwd" stack_frame_id=4}
+  %mx_flash_dq.11 = (bf16[32,16384,128]{2,1,0}) custom-call(%bitcast.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/transpose(jvp(mx.layer))/while/body/closed_call/checkpoint/mx.flash/mx_flash_dq"}
+  %mx_group_mm.3 = (bf16[32,16384,128]{2,1,0}) custom-call(%bitcast.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/jvp(mx.layer)/while/body/closed_call/mx.ffn/mx_group_mm"}
+  %all-reduce.3 = f32[16]{0} all-reduce(%x), channel_id=1, metadata={op_name="jit(step_fn)/mx.optimizer/psum"}
+  %while.15 = (s32[]) while(%tuple.1), condition=%c, body=%b, metadata={op_name="jit(step_fn)/jvp(mx.layer)/while"}
+"""
 
 
-def _trace():
-    """Two whole steps of 1000 us on two devices. Device 0 a step: a while
-    from 0 to 900 holding a fusion (0-400), a flash call (400-600) and an
-    all-reduce (650-900); idle 600-650 and 900-1000."""
-    ops, modules, us = [], [], 1000.0
-    for k in range(3):
-        t = 1000 * us * k
+def _trace(whole=2):
+    """``whole`` whole steps of 1000 us on two devices, after the execution
+    that was running when the trace began: recorded from the trace's start,
+    so only its last 400 us are there (the end of a flash call, an
+    all-reduce). Device 0 a step: a while from 0 to 900 holding a fusion
+    (0-400), a flash forward (400-500) and a flash dq (500-600), a grouped
+    product of a second kernel family (600-650) and an all-reduce (650-900);
+    idle 900-1000."""
+    us = 1000.0
+    modules = [("jit_step_fn(1)", 0.0, (CUT - 100) * us)]
+    ops = [(WHILE, 0.0, 300 * us), (FLASH_FWD, 0.0, 50 * us),
+           (ALLRED, 50 * us, 250 * us)]
+    for k in range(whole + 1):
+        t = (CUT + 1000 * k) * us
         modules.append(("jit_step_fn(1)", t, 900 * us))
         ops += [(WHILE, t, 900 * us), (FUSION, t, 400 * us),
-                (FLASH, t + 400 * us, 200 * us),
+                (FLASH_FWD, t + 400 * us, 100 * us),
+                (FLASH_DQ, t + 500 * us, 100 * us),
+                (GROUP, t + 600 * us, 50 * us),
                 (ALLRED, t + 650 * us, 250 * us)]
-    modules.append(("jit_small(2)", 2950 * us, 10 * us))
-    dev1 = {"ops": [(FUSION, 0.0, 2000 * us)], "modules": modules}
-    host = [("chipbench.read_loss", 0.0, 2500 * us)]
+    end = CUT + 1000 * (whole + 1)
+    modules.append(("jit_small(2)", (end - 50) * us, 10 * us))
+    dev1 = {"ops": [(FUSION, 0.0, end * us)], "modules": modules}
+    host = [("chipbench.read_loss", 0.0, (CUT + 1900) * us),
+            ("chipbench.dispatch", (CUT + 1900) * us, 100 * us),
+            ("mx.train_step", (CUT + 1910) * us, 80 * us)]
     return {"devices": {"/device:TPU:0": {"ops": ops, "modules": modules},
                         "/device:TPU:1": dev1}, "host": host}
 
@@ -52,6 +87,14 @@ def test_opcodes_are_read_from_the_instruction_not_its_operands():
     assert trace.opcode(FUSION) == "fusion"
     assert not trace.is_mosaic(FUSION)        # %custom-call.15 is an operand
     assert trace.is_mosaic(FLASH) and not trace.is_collective(FLASH)
+    assert trace.instruction(FUSION) == "fusion.430"
+    assert trace.kernel_name(FLASH_FWD) == "mx_flash_fwd"
+    assert trace.kernel_name(GROUP) == "mx_group_mm"
+    assert trace.kernel_name(FLASH) == "checkpoint"   # a kernel with no name=
+    # the compiler's own custom calls are no kernels
+    assert not trace.is_mosaic(
+        '%custom-call.7 = f32[8]{0:T(128)} custom-call(), '
+        'custom_call_target="AllocateBuffer"')
     assert trace.opcode(WHILE) == "while"
     assert trace.is_collective(ALLRED)
     assert trace.is_collective("%ar = f32[4] all-reduce-start(f32[4] %y)")
@@ -68,13 +111,20 @@ def test_trace_reduction_on_a_hand_made_trace():
     assert r["idle_share"] == pytest.approx(0.1)
     assert r["busy_s"] == pytest.approx((1800 + 2000) / 2 * 1e-6)
     # the while is a container: its body's ops are counted, it is not
-    assert r["op_sum_s"] == pytest.approx(2 * 850e-6)
-    assert r["mosaic_s"] == pytest.approx(2 * 200e-6)
+    assert r["op_sum_s"] == pytest.approx(2 * 900e-6)
+    assert r["mosaic_s"] == pytest.approx(2 * 250e-6)
     assert r["collective_s"] == pytest.approx(2 * 250e-6)
     assert all(trace.opcode(n) != "while" for n, _ in r["device_ops"])
     assert r["device_ops"][0][1] == pytest.approx(800e-6)
+    # no program text: raw instruction text, no scopes
+    assert r["device_ops"][0][0] == FUSION[:160]
+    assert "scopes" not in r and "phases" not in r
+    # a step's last 100 us are idle: the first step's under read_loss, the
+    # second's under dispatch and, inside it, the program's own call, which
+    # as the innermost span is the one named
     gaps = dict(r["idle_gaps"])
-    assert gaps["chipbench.read_loss"] == pytest.approx(200e-6)
+    assert gaps["chipbench.read_loss"] == pytest.approx(100e-6)
+    assert gaps["mx.train_step"] == pytest.approx(100e-6)
 
 
 def test_a_trace_without_a_whole_step_is_refused():
@@ -84,6 +134,158 @@ def test_a_trace_without_a_whole_step_is_refused():
         trace.reduce(t)
     with pytest.raises(ValueError):
         trace.reduce({"devices": {}, "host": []})
+
+
+def test_the_cut_first_execution_is_left_out_and_two_executions_refused():
+    r = trace.reduce(_trace())
+    # first start to last start would read 2400 us over 3 steps: 800 a step
+    assert r["window_s"] == pytest.approx(2000e-6) and r["steps"] == 2
+    # nothing of the cut execution's 400 us: its flash call is no call
+    assert r["kernels"]["mx_flash_fwd"]["calls"] == 2
+    assert trace.reduce(_trace(whole=1))["steps"] == 1
+    with pytest.raises(ValueError, match="2 executions"):
+        trace.reduce(_trace(whole=0))   # the cut one and one start: no step
+
+
+def test_each_kernel_family_has_its_own_seconds_and_calls():
+    r = trace.reduce(_trace())
+    assert set(r["kernels"]) == {"mx_flash_fwd", "mx_flash_dq",
+                                 "mx_group_mm"}      # all, and no container
+    for name, us in (("mx_flash_fwd", 100), ("mx_flash_dq", 100),
+                     ("mx_group_mm", 50)):
+        assert r["kernels"][name]["calls"] == 2
+        assert r["kernels"][name]["s"] == pytest.approx(2 * us * 1e-6)
+    assert r["mosaic_s"] == pytest.approx(
+        sum(k["s"] for k in r["kernels"].values()))
+
+
+def test_scopes_and_phases_where_the_program_text_is_given():
+    r = trace.reduce(_trace(), TEXT)
+    s = r["scopes"]
+    assert s["mx.ffn"]["backward"] == pytest.approx(2 * 400e-6)
+    assert s["mx.ffn"]["forward"] == pytest.approx(2 * 50e-6)    # the group
+    assert s["mx.flash"]["recompute"] == pytest.approx(2 * 100e-6)
+    assert s["mx.flash"]["backward"] == pytest.approx(2 * 100e-6)
+    assert s["mx.optimizer"]["forward"] == pytest.approx(2 * 250e-6)
+    p = r["phases"]
+    assert p == {"forward": pytest.approx(100e-6),
+                 "backward": pytest.approx(1000e-6),
+                 "recompute": pytest.approx(200e-6),
+                 "optimizer": pytest.approx(500e-6)}
+    assert sum(p.values()) == pytest.approx(r["op_sum_s"])
+    # the result line's device_ops: scope, phase, kernel or opcode
+    assert r["device_ops"][:2] == [
+        ["mx.ffn backward fusion", pytest.approx(800e-6)],
+        ["mx.optimizer forward all-reduce", pytest.approx(500e-6)]]
+    assert ["mx.flash recompute mx_flash_fwd",
+            pytest.approx(200e-6)] in r["device_ops"]
+    # an instruction the text does not have is unscoped, not lost
+    r = trace.reduce(_trace(), "HloModule empty")
+    assert r["scopes"] == {"unscoped": {
+        "forward": pytest.approx(r["op_sum_s"]), "backward": 0.0,
+        "recompute": 0.0}}
+
+
+def test_the_scope_parser_on_lines_of_compiled_text():
+    """Lines as XLA wrote them for the tiny decoder (CPU) and, for the
+    kernel and the fusion, as the v5e's traces name them."""
+    m = scopes.scope_map(TEXT + """
+  %add.215 = f32[4,128,64]{2,1,0} add(%convert.4306, %bitcast.133), metadata={op_name="jit(step_fn)/jvp(mx.layer)/while/body/closed_call/mx.ffn/add" stack_frame_id=92}
+  %add_any.192 = f32[512,64]{1,0} add(%convert.4400, %convert.4398), metadata={op_name="jit(step_fn)/transpose(jvp(mx.layer))/while/body/closed_call/checkpoint/mx.ffn/bsd,df->bsf/add_any" stack_frame_id=9}
+  %broadcast.445 = f32[4,128,64]{2,1,0} broadcast(%convert.4402), dimensions={2}, metadata={op_name="jit(step_fn)/transpose(jvp(mx.layer))/while/body/closed_call/checkpoint/rematted_computation/mx.ffn/mul" stack_frame_id=9}
+  %subtract.13 = f32[2,64]{1,0} subtract(%a, %b), metadata={op_name="jit(step_fn)/transpose(jvp(mx.layer))/while/body/sub"}
+  %broadcast.477 = f32[64]{0} broadcast(%c), metadata={op_name="jit(step_fn)/mx.optimizer/mul" stack_frame_id=3}
+  %reduce_sum.253 = f32[] reduce(%x, %zero), dimensions={0}, metadata={op_name="reduce_sum"}
+  %constant.437 = f32[] constant(0)
+""")
+    at = lambda n: scopes.classify(m.get(n, ""))
+    assert at("add.215") == ("mx.ffn", "forward")        # innermost wins
+    assert at("add_any.192") == ("mx.ffn", "backward")   # a bare checkpoint/
+    assert at("broadcast.445") == ("mx.ffn", "recompute")
+    assert at("subtract.13") == ("mx.layer", "backward")
+    assert at("broadcast.477") == ("mx.optimizer", "forward")
+    assert at("reduce_sum.253") == at("constant.437") == ("unscoped",
+                                                          "forward")
+    assert "constant.437" not in m
+    assert at("convolution.5") == at("fusion.430")       # a ROOT line too
+    assert at("mx_flash_fwd.18") == ("mx.flash", "recompute")
+
+
+def _run_of(reduced, cell="tiny_newkind-seq128"):
+    """What ``run_cell`` hands a reader, from a reduced trace: the count
+    found by the configuration's name, two chips of round peaks."""
+    spec = run.load_cell(cell, TINY)
+    work = {"batch": 4, "seq_len": 128, "model": spec["config"],
+            "dtype": "bfloat16"}
+    return spec, {"trace": reduced, "required": spec["required"](work),
+                  "chips": 2, "work": work,
+                  "peaks": {"bf16_flops": 1e10, "hbm_bytes_per_s": 1e9}}
+
+
+def test_a_kind_a_kernel_roofline_and_a_scope_share_arrive_as_files():
+    """Nothing under chipbench/ knows this kind, this kernel family or this
+    scope's share: a count under the tiny root's counts/, two readers under
+    its metrics/, entries in its BENCHMARK.json."""
+    for group, name in (("counts", "tiny_two_families"),
+                        ("metrics", "kernels.group_roofline"),
+                        ("metrics", "scope.ffn_bwd_share")):
+        assert os.path.exists(os.path.join(TINY, group, name + ".py"))
+        assert not os.path.exists(os.path.join(ROOT, "chipbench", group,
+                                               name + ".py"))
+    spec, made = _run_of(trace.reduce(_trace(), TEXT))
+    assert made["required"]["step_flops"] == 6000 * 512
+    read = lambda name: run.metric_reader(name, spec["data"])(made)
+    # 512 tokens x 100 bytes at 1 GB/s over two chips = 25.6 us of the 50
+    # the group kernel takes a step on a chip: bound by bytes
+    assert read("kernels.group_roofline") == pytest.approx(51.2)
+    assert read("scope.ffn_bwd_share") == pytest.approx(100 * 800 / 1800)
+    # the harness's own readers, found from the same root
+    # 512 tokens x 1000 flops at 10 GF/s over two chips = 25.6 us of 200
+    assert read("kernels.flash_roofline") == pytest.approx(12.8)
+    reported = {m["name"]: read(m["name"])
+                for m in run.metrics_of(spec["bench"],
+                                        "tiny_newkind-seq128", "per_layer")}
+    assert {"kernels.group_roofline", "scope.ffn_bwd_share", "step.mfu",
+            "kernels.flash_roofline"} <= set(reported)
+    assert all(0 < v <= 100 for v in reported.values())
+
+
+def test_a_reader_finds_nothing_where_there_is_nothing_to_read():
+    spec, made = _run_of(trace.reduce(_trace()))        # no program text
+    for name in ("step.fwd_share", "step.bwd_share", "step.recompute_share",
+                 "step.optimizer_share", "scope.ffn_bwd_share"):
+        assert run.metric_reader(name, spec["data"])(made) is None
+    # a kind that counts no such family; a family of which no kernel ran
+    spec, made = _run_of(trace.reduce(_trace()), "tiny_decoder-seq128")
+    assert run.metric_reader("kernels.group_roofline",
+                             spec["data"])(made) is None
+    made["trace"]["kernels"] = {}
+    assert run.metric_reader("kernels.flash_roofline")(made) is None
+
+
+def test_no_share_of_the_hand_made_trace_exceeds_100():
+    """The least time of a sound count is never more than the time taken:
+    the dense decoder's own count on the tiny decoder's shapes against
+    kernels that take their roofline's time exactly reads 100, and the four
+    phases sum to 100."""
+    spec, made = _run_of(trace.reduce(_trace(), TEXT), "tiny_decoder-seq128")
+    need = made["required"]["kernels"]["mx_flash_"]
+    made["peaks"] = {"bf16_flops": need["flops"] / 2 / 200e-6,
+                     "hbm_bytes_per_s": 1e30}
+    assert run.metric_reader("kernels.flash_roofline")(made) == \
+        pytest.approx(100.0)
+    shares = [run.metric_reader("step.%s_share" % p)(made)
+              for p in ("fwd", "bwd", "recompute", "optimizer")]
+    assert all(0 <= v <= 100 for v in shares)
+    assert sum(shares) == pytest.approx(100.0)
+    assert shares[3] == pytest.approx(100 * 500 / 1800)
+    assert run.metric_reader("kernels.mosaic_share")(made) == \
+        pytest.approx(100 * 500 / 1800)
+
+
+def _count(kind, where=None):
+    """``required`` of counts/<kind>.py, found as a cell's is."""
+    return run.load_named("counts", kind, where or run.HERE).required
 
 
 BAICHUAN = {"hidden_size": 4096, "intermediate_size": 11008,
@@ -99,19 +301,21 @@ BAICHUAN = {"hidden_size": 4096, "intermediate_size": 11008,
      3 * 5 * 8192 * 16385 * 16384 / 1e12),
 ])
 def test_required_flops_of_baichuan_7b(batch, seq, gf_per_token, attn_tf):
-    r = flops.required({"kind": "dense_decoder", "model": BAICHUAN,
-                        "batch": batch, "seq_len": seq, "dtype": "bfloat16"})
+    r = _count("dense_decoder")({"model": BAICHUAN, "batch": batch,
+                                 "seq_len": seq, "dtype": "bfloat16"})
     assert r["step_flops"] / (batch * seq) / 1e9 == pytest.approx(
         gf_per_token, rel=1e-12)
-    assert r["attention_flops"] / 1e12 == pytest.approx(attn_tf, rel=1e-12)
-    assert r["attention_bytes"] == 12 * batch * seq * 4096 * 2 * 5
+    flash = r["kernels"]["mx_flash_"]
+    assert flash["flops"] / 1e12 == pytest.approx(attn_tf, rel=1e-12)
+    assert flash["bytes"] == 12 * batch * seq * 4096 * 2 * 5
     # the issue's round figures: 7.9 GF a token and 4.1 TF of attention at 2k
     if seq == 2048:
         assert round(gf_per_token, 1) == 7.9 and round(attn_tf, 1) == 4.1
 
 
 def test_required_flops_of_resnet50_v1():
-    convs = dict((n, m) for m, n in flops.resnet_v1_convs())
+    resnet_v1_convs = run.load_named("counts", "resnet_v1").resnet_v1_convs
+    convs = dict((n, m) for m, n in resnet_v1_convs())
     assert convs["conv0"] == 112 * 112 * 64 * 3 * 49
     assert convs["stage1.block0.conv3x3"] == 56 * 56 * 64 * 64 * 9
     # v1: the stride sits on the first 1x1, so stage 2's 3x3 runs at 28x28
@@ -121,9 +325,42 @@ def test_required_flops_of_resnet50_v1():
     assert len(convs) == 1 + 16 * 3 + 4 + 1
     macs = sum(convs.values())
     assert 3.8e9 < macs < 3.9e9     # He et al., table 1: 3.8e9 for 50 layers
-    r = flops.required({"kind": "resnet_v1", "batch": 128, "image": 224,
-                        "model": {"layers": [3, 4, 6, 3], "classes": 1000}})
+    r = _count("resnet_v1")({"batch": 128, "image": 224,
+                             "model": {"layers": [3, 4, 6, 3],
+                                       "classes": 1000}})
     assert r["step_flops"] == 6 * macs * 128
+
+
+@pytest.mark.parametrize("kind,work,pinned", [
+    # what chipbench/flops.py gave at the parent (d3e5a09), to the digit
+    ("dense_decoder", {"model": BAICHUAN, "batch": 8, "seq_len": 2048,
+                       "dtype": "bfloat16"},
+     {"step_flops": 129366428221440,
+      "kernels": {"mx_flash_": {"flops": 4125181870080,
+                                "bytes": 8053063680}}}),
+    ("dense_decoder", {"model": BAICHUAN, "batch": 1, "seq_len": 16384,
+                       "dtype": "bfloat16"},
+     {"step_flops": 158228608450560,
+      "kernels": {"mx_flash_": {"flops": 32987362099200,
+                                "bytes": 8053063680}}}),
+    ("resnet_v1", {"batch": 128, "image": 224,
+                   "model": {"layers": [3, 4, 6, 3], "classes": 1000}},
+     {"step_flops": 2962923454464}),
+])
+def test_the_moved_counts_give_the_parents_numbers(kind, work, pinned):
+    assert _count(kind)(work) == pinned
+
+
+def test_a_kind_with_no_count_file_is_an_error_that_names_the_file():
+    with pytest.raises(SystemExit) as e:
+        _count("no_such_kind", TINY)
+    for base in (TINY, os.path.join(ROOT, "chipbench")):
+        assert os.path.join(base, "counts", "no_such_kind.py") in str(
+            e.value)
+    # the tiny root's own kind is found from that root and from no other
+    assert _count("tiny_two_families", TINY)
+    with pytest.raises(SystemExit):
+        _count("tiny_two_families")
 
 
 def test_leaf_gaps_are_gaps_of_norms_against_the_larger_of_leaf_and_median():
@@ -197,7 +434,9 @@ def test_every_cell_finds_its_files_by_name():
         spec = run.load_cell(name)
         assert os.path.exists(os.path.join(
             ROOT, "chipbench", "models", spec["config"]["adapter"] + ".py"))
-        assert spec["config"]["flops"] in flops.KINDS
+        assert os.path.exists(os.path.join(
+            ROOT, "chipbench", "counts", spec["config"]["flops"] + ".py"))
+        assert callable(spec["required"])
         # every number check.py computes has a limit or is named as not
         # compared; PERF.md gives the readings either way
         listed = set(spec["limits"]) | set(spec["limits"].get(

@@ -31,6 +31,7 @@ from .quantized_matmul import quantized_matmul  # noqa: F401
 # BENCH_MODEL=fused_kernels iterates the 'fused_kernels' entries)
 KERNEL_BENCH = {
     "flash_attention": "transformer",
+    "grouped_matmul": "transformer",
     "compression": "comm_overlap",
     "conv_fused": "resnet50",
     "batchnorm_fused": "fused_kernels",

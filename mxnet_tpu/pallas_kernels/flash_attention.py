@@ -11,7 +11,11 @@ loop) — HBM traffic is O(S·D) instead of the O(S^2) score matrix. Only
 tiles the causal mask leaves something of are grid steps (``_steps``), and
 inside a tile the work goes in chunks of rows, each stopping at the last
 column its rows can see, so a diagonal tile costs about 5/8 of a full one
-and only its last sub-block pays for the mask.
+and only its last sub-block pays for the mask. K and V may have fewer heads
+than Q (grouped-query attention: a group's query heads read one key-value
+head from its own rows, and dk/dv are summed over the group inside the
+kv-major kernel), and a sliding ``window`` drops the tiles wholly outside
+it from the grid the same way and masks the chunks on its far edge.
 
 Composition with the parallelism layer: ring attention
 (parallel/ring_attention.py) shards the sequence over the mesh and
@@ -32,6 +36,7 @@ the kernel is bypassed) the jnp reference's XLA vjp is used instead.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -73,11 +78,16 @@ def _fit_block(requested, size, quantum):
     return size
 
 
-def attention_reference(q, k, v, causal=False, scale=None):
+def attention_reference(q, k, v, causal=False, scale=None, window=None):
     """Plain O(S^2) attention in jnp — fallback + autodiff path.
-    q,k,v: [B, H, S, D]."""
+    q: [B, H, S, D]; k, v: [B, G, S, D] with H a multiple of G (query head
+    h reads key-value head h // (H/G)). ``window``: a query also sees only
+    the last ``window`` keys, itself among them (needs ``causal``)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if k.shape[1] != q.shape[1]:
+        rep = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
     # scores + softmax in fp32 regardless of input dtype — same as the
     # Pallas kernel's accumulators, so the two paths agree under AMP bf16
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -87,6 +97,8 @@ def attention_reference(q, k, v, causal=False, scale=None):
         row = lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         col = lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
         s = jnp.where(col > row, -jnp.inf, s)
+        if window is not None:
+            s = jnp.where(col <= row - window, -jnp.inf, s)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype),
                       v).astype(q.dtype)
@@ -198,43 +210,60 @@ def _floor_to(x, q):
     return (x // q) * q
 
 
-def _visible(rel, lo, n, width, kv_major):
-    """Which part of the other axis one chunk of a causal tile computes.
+def _span(rel, lo, n, width, kv_major, window=None):
+    """Which part of the other axis one chunk of a masked tile computes:
+    (start, full_start, full_end, end) in lane tiles (the whole width where
+    it has none). Positions [start, end) are computed; [start, full_start)
+    and [full_end, end) are seen by some of the chunk's rows only and are
+    masked, what lies between by all of them.
 
     q-major (forward, dq): the chunk is q rows [lo, lo+n) of the tile and
-    the answer (full_end, end) says k columns [0, full_end) are visible
-    to every row and [full_end, end) to some (masked); columns from
-    ``end`` on are not computed. kv-major (dk/dv): the chunk is k rows
-    and the answer (start, full_start) says q columns [start, full_start)
-    are masked and [full_start, width) fully visible. ``rel`` None means
-    no mask at all; a traced ``rel`` masks the whole width. Ranges end
-    on lane-tile boundaries (the whole width where it has none)."""
+    the positions are k columns; the causal mask cuts the high side and the
+    window the low one. kv-major (dk/dv): the chunk is k rows, the positions
+    q columns, the causal mask on the low side and the window on the high.
+    ``rel`` None means no mask at all; a traced ``rel`` masks the whole
+    width, on both sides if there is a window."""
     quantum = _LANES if width % _LANES == 0 else width
     if rel is None:
-        return (width, width) if not kv_major else (0, 0)
+        return 0, 0, width, width
     if not isinstance(rel, int):
-        return (0, width)
+        # traced: the causal side masks the whole width, and so does the
+        # window's where there is one
+        low = kv_major or window is not None
+        high = not kv_major or window is not None
+        return 0, width if low else 0, 0 if high else width, width
+    clamp = lambda x: min(max(x, 0), width)  # noqa: E731
     if not kv_major:
-        # column j is visible to row i iff j - i <= rel
+        # column j is visible to row i iff rel - window < j - i <= rel
         full_end = _floor_to(rel + lo + 1, quantum)
         end = -_floor_to(-(rel + lo + n), quantum)
-        return (min(max(full_end, 0), width), min(max(end, 0), width))
-    # q column i is visible to k row j iff i >= j - rel
+        start = full_start = 0
+        if window is not None:
+            start = _floor_to(rel + lo - window + 1, quantum)
+            full_start = -_floor_to(-(rel + lo + n - window), quantum)
+        return clamp(start), clamp(full_start), clamp(full_end), clamp(end)
+    # q column i is visible to k row j iff j - rel <= i < j - rel + window
     start = _floor_to(lo - rel, quantum)
     full_start = -_floor_to(-(lo + n - 1 - rel), quantum)
-    return (min(max(start, 0), width), min(max(full_start, 0), width))
+    full_end = end = width
+    if window is not None:
+        full_end = _floor_to(lo - rel + window, quantum)
+        end = -_floor_to(-(lo + n - 1 - rel + window), quantum)
+    return clamp(start), clamp(full_start), clamp(full_end), clamp(end)
 
 
-def _mask(s, lo, hi, d, k_axis):
-    """``s`` with -inf where (k index) - (q index) > d, for columns
-    [lo, hi) only: the rest of a chunk's columns its rows see whole. The
-    k index runs along ``k_axis`` of ``s`` and d counts from column lo."""
-    if lo == hi:
+def _mask(s, lo, hi, d, k_axis, out=lax.gt):
+    """``s`` with -inf where (k index) - (q index) > d (the causal mask;
+    with ``out=lax.lt`` where it is < d, the window's far edge), for
+    columns [lo, hi) only: the rest of a chunk's columns its rows see
+    whole. The k index runs along ``k_axis`` of ``s`` and d counts from
+    column lo."""
+    if lo >= hi:
         return s
     part = lax.slice_in_dim(s, lo, hi, axis=1)
     kk = lax.broadcasted_iota(jnp.int32, part.shape, k_axis)
     qq = lax.broadcasted_iota(jnp.int32, part.shape, 1 - k_axis)
-    part = lax.select(lax.gt(lax.sub(kk, qq), lax.full_like(kk, d)),
+    part = lax.select(out(lax.sub(kk, qq), lax.full_like(kk, d)),
                       lax.full_like(part, -jnp.inf), part)
     pieces = [lax.slice_in_dim(s, 0, lo, axis=1), part,
               lax.slice_in_dim(s, hi, s.shape[1], axis=1)]
@@ -245,18 +274,49 @@ def _mask(s, lo, hi, d, k_axis):
 def _diag_rels(block_q, block_k):
     """The values rel takes on tiles the diagonal crosses: tiles wholly
     above it have rel <= -block_q, tiles wholly below rel >= block_k - 1."""
-    import math
     g = math.gcd(block_q, block_k)
     return list(range(-block_q + g, block_k - 1, g))
 
 
-def _causal_bodies(rel, block_q, block_k, body):
+def _edge_rels(block_q, block_k, window):
+    """The values rel takes on tiles the window's far edge crosses: tiles
+    with rel <= window - block_q lie wholly inside it, tiles with
+    rel >= window + block_k - 1 wholly outside (no grid steps)."""
+    g = math.gcd(block_q, block_k)
+    first = (window - block_q) // g * g + g
+    return list(range(first, window + block_k - 1, g))
+
+
+def _masked_rels(block_q, block_k, window):
+    """Every rel whose tile needs a mask, or None where they are too many
+    for a body each."""
+    rels = _diag_rels(block_q, block_k)
+    if window is not None:
+        rels = sorted(set(rels) | set(_edge_rels(block_q, block_k, window)))
+    return rels if len(rels) <= _MAX_DIAG_BODIES else None
+
+
+def _causal_bodies(rel, block_q, block_k, body, window=None):
     """Run ``body`` for one causal tile of ``_steps`` (none lies above
-    the diagonal): ``body(None)``, no mask, wholly below it, and on it
+    the diagonal or wholly outside the window): ``body(None)``, no mask,
+    wholly below the diagonal and inside the window, and on either edge
     ``body`` with the tile's static rel, so each chunk's visible range is
     a constant. Shared by the three kernels so the classification cannot
     drift."""
     import jax.experimental.pallas as pl
+
+    if window is not None:
+        inside = (rel >= block_k - 1) & (rel <= window - block_q)
+        rels = _masked_rels(block_q, block_k, window)
+        if any(block_k - 1 <= r <= window - block_q
+               for r in range(0, window, math.gcd(block_q, block_k))):
+            pl.when(inside)(lambda: body(None))
+        if rels is None:
+            pl.when(jnp.logical_not(inside))(lambda: body(rel))
+            return
+        for r in rels:
+            pl.when(rel == r)(functools.partial(body, r))
+        return
 
     @pl.when(rel >= block_k - 1)
     def _():
@@ -276,7 +336,7 @@ def _causal_bodies(rel, block_q, block_k, body):
 
 def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
                 l_scr, o_scr, *, block_q, block_k, chunk, causal, scale2,
-                n_kblocks):
+                n_kblocks, window=None):
     """One (batch*head, step) grid cell; a step is one (q-block, k-block)
     tile of ``_steps``. The TPU grid runs sequentially with a q-block's
     k-blocks in a row, so VMEM scratch carries the m/l/o online-softmax
@@ -289,7 +349,7 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
     ki = ki_ref[pl.program_id(1)]
     d = o_scr.shape[-1]
 
-    @pl.when(ki == 0)
+    @pl.when(ki == _first_kblock(qi, block_q, block_k, window))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -303,19 +363,33 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
         # on, exp2(-inf - finite) is exactly 0 for masked scores and for
         # the m_prev=-inf init, and no exp2(-inf+inf) NaN can form.
         # (Fully-masked rows cannot occur: causal row r sees cols 0..r.)
+        # With a window a q-block's first tiles may hold nothing yet for
+        # its later rows: there the maximum stays -inf and is replaced by
+        # 0 in the exponents, so corr and p come out 0 and not NaN.
         def rows_at(lo):
-            full_end, end = _visible(rel, lo, chunk, block_k, False)
-            if end == 0:
+            start, full_start, full_end, end = _span(
+                rel, lo, chunk, block_k, False, window)
+            if end <= start:
                 return
             rows = pl.ds(lo, chunk)
-            s = lax.mul(_dot(q_ref[0, rows], k_ref[0, :end], _NT), scale2)
+            s = lax.mul(_dot(q_ref[0, rows], k_ref[0, start:end], _NT),
+                        scale2)
             if full_end < end:
-                s = _mask(s, full_end, end, rel + lo - full_end, 1)
+                s = _mask(s, full_end - start, end - start,
+                          rel + lo - full_end, 1)
+            if start < full_start:
+                s = _mask(s, 0, full_start - start,
+                          rel + lo - start - window + 1, 1, lax.lt)
             m_prev = m_scr[rows]
             m_new = lax.max(m_prev, _row_stat(lax.reduce_max, s))
-            corr = lax.exp2(lax.sub(m_prev, m_new))
-            p = lax.exp2(lax.sub(s, _lanes(m_new, end)))
-            v = v_ref[0, :end]
+            m_ref = m_new
+            if start < full_start:
+                m_ref = lax.select(
+                    lax.eq(m_new, lax.full_like(m_new, -jnp.inf)),
+                    lax.full_like(m_new, 0.0), m_new)
+            corr = lax.exp2(lax.sub(m_prev, m_ref))
+            p = lax.exp2(lax.sub(s, _lanes(m_ref, end - start)))
+            v = v_ref[0, start:end]
             m_scr[rows] = m_new
             l_scr[rows] = lax.add(lax.mul(corr, l_scr[rows]),
                                   _row_stat(lax.reduce_sum, p))
@@ -326,7 +400,8 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
         _for_chunks(rel, block_q, chunk, _GROUP["fwd"], rows_at)
 
     if causal:
-        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile)
+        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile,
+                       window)
     else:
         tile(None)
 
@@ -341,15 +416,25 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
         o_ref[0] = (o_scr[:] / _lanes(l, d)).astype(o_ref.dtype)
 
 
-def _steps(n_qblocks, n_kblocks, block_q, block_k, causal, kv_major):
+def _steps(n_qblocks, n_kblocks, block_q, block_k, causal, kv_major,
+           window=None, group=1):
     """The (q-block, k-block) pairs that compute, in grid order (k
     innermost; q innermost for dk/dv), as two int32 tables the kernels
     and their index maps read from SMEM. Tiles wholly above the diagonal
-    are not grid steps at all: no step overhead, no DMA."""
+    or wholly outside the window are not grid steps at all: no step
+    overhead, no DMA. ``group`` (dk/dv with grouped query heads): a
+    k-block's steps go through its q-blocks once for each of the group's
+    query heads, and the q table holds head * n_qblocks + q-block: the
+    block's index in the group's heads laid end to end."""
     pairs = [(i, j) for i in range(n_qblocks) for j in range(n_kblocks)
-             if not causal or j * block_k <= i * block_q + block_q - 1]
+             if not causal or j * block_k <= i * block_q + block_q - 1
+             and (window is None
+                  or i * block_q - j * block_k < window + block_k - 1)]
     if kv_major:
         pairs.sort(key=lambda p: (p[1], p[0]))
+    if group > 1:
+        pairs = [(h * n_qblocks + i, j) for j in range(n_kblocks)
+                 for h in range(group) for i, j2 in pairs if j2 == j]
     # numpy, not jnp: a jnp array made while tracing is an eager device
     # computation, a program of its own to compile or fetch from the cache
     qi, ki = np.asarray(pairs, np.int32).T
@@ -369,6 +454,22 @@ def _k_rows(bh, t, qi, ki):
 
 def _q_lanes(bh, t, qi, ki):
     return bh, 0, qi[t]
+
+
+def _kv_rows(group):
+    """``_k_rows`` where ``group`` query heads read one key-value head: the
+    grid's first axis counts query heads, K and V hold a row of blocks a
+    key-value head, and no copy of either is made."""
+    if group == 1:
+        return _k_rows
+    return lambda bh, t, qi, ki: (bh // group, ki[t], 0)
+
+
+def _first_kblock(qi, block_q, block_k, window):
+    """The first k-block a q-block's steps visit."""
+    if window is None:
+        return 0
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
 
 
 def _last_kblock(qi, block_q, block_k, n_kblocks, causal):
@@ -400,22 +501,26 @@ def _fit(block_q, block_k, sq, sk):
     return _fit_block(block_q, sq, _LANES), _fit_block(block_k, sk, _LANES)
 
 
-def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret):
+def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    g, sk = k.shape[1], k.shape[2]
     block_q, block_k = _fit(block_q, block_k, sq, sk)
     chunk = _chunk(block_q)
     qf = q.reshape(b * h, sq, d)
-    kf = k.reshape(b * h, sk, d)
-    vf = v.reshape(b * h, sk, d)
+    kf = k.reshape(b * g, sk, d)
+    vf = v.reshape(b * g, sk, d)
     n_kblocks = sk // block_k
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, chunk=chunk,
-        causal=causal, scale2=scale * _LOG2E, n_kblocks=n_kblocks)
-    steps = _steps(sq // block_q, n_kblocks, block_q, block_k, causal, False)
+        causal=causal, scale2=scale * _LOG2E, n_kblocks=n_kblocks,
+        **({} if window is None else {"window": window}))
+    steps = _steps(sq // block_q, n_kblocks, block_q, block_k, causal, False,
+                   window)
+    kv_rows = _kv_rows(h // g)
     item = q.dtype.itemsize
     vmem = (4 * (block_q + block_k) * d * item
             + block_q * (2 * _LANES + d) * 4 + 5 * chunk * block_k * 4)
@@ -426,8 +531,8 @@ def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret):
             grid=(b * h, steps[0].size),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), _q_rows),
-                pl.BlockSpec((1, block_k, d), _k_rows),
-                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_k, d), kv_rows),
+                pl.BlockSpec((1, block_k, d), kv_rows),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, d), _q_rows),
@@ -454,7 +559,7 @@ def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret):
 
 def _dq_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                delta_ref, dq_ref, dq_scr, lse_scr, delta_scr, *, block_q,
-               block_k, chunk, causal, scale, n_kblocks):
+               block_k, chunk, causal, scale, n_kblocks, window=None):
     """dQ, one (q-block, k-block) tile of ``_steps`` a grid step. Flash-2
     recompute per chunk of q rows: P = 2^(S2 - lse2) from Q/K and the
     saved row logsumexp, dS = P * (dP - delta), dQ += dS @ K; the score
@@ -468,7 +573,7 @@ def _dq_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     qi = qi_ref[pl.program_id(1)]
     ki = ki_ref[pl.program_id(1)]
 
-    @pl.when(ki == 0)
+    @pl.when(ki == _first_kblock(qi, block_q, block_k, window))
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
         lse_scr[:] = _replicated(lse_ref[0])
@@ -476,24 +581,31 @@ def _dq_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     def tile(rel):
         def rows_at(lo):
-            full_end, end = _visible(rel, lo, chunk, block_k, False)
-            if end == 0:
+            start, full_start, full_end, end = _span(
+                rel, lo, chunk, block_k, False, window)
+            if end <= start:
                 return
             rows = pl.ds(lo, chunk)
-            k = k_ref[0, :end]
+            k = k_ref[0, start:end]
             s = lax.mul(_dot(q_ref[0, rows], k, _NT), scale * _LOG2E)
             if full_end < end:
-                s = _mask(s, full_end, end, rel + lo - full_end, 1)
-            p = lax.exp2(lax.sub(s, _lanes(lse_scr[rows], end)))
-            dp = _dot(do_ref[0, rows], v_ref[0, :end], _NT)
-            ds = lax.mul(p, lax.sub(dp, _lanes(delta_scr[rows], end)))
+                s = _mask(s, full_end - start, end - start,
+                          rel + lo - full_end, 1)
+            if start < full_start:
+                s = _mask(s, 0, full_start - start,
+                          rel + lo - start - window + 1, 1, lax.lt)
+            p = lax.exp2(lax.sub(s, _lanes(lse_scr[rows], end - start)))
+            dp = _dot(do_ref[0, rows], v_ref[0, start:end], _NT)
+            ds = lax.mul(p, lax.sub(dp, _lanes(delta_scr[rows],
+                                               end - start)))
             dq_scr[rows] = lax.add(dq_scr[rows], _dot(
                 lax.convert_element_type(ds, k.dtype), k, _NN))
 
         _for_chunks(rel, block_q, chunk, _GROUP["dq"], rows_at)
 
     if causal:
-        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile)
+        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile,
+                       window)
     else:
         tile(None)
 
@@ -504,52 +616,66 @@ def _dq_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _dkv_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, block_q,
-                block_k, chunk, causal, scale, n_qblocks):
+                block_k, chunk, causal, scale, n_qblocks, window=None,
+                group=1):
     """dK/dV, one tile of ``_steps`` a grid step, a k-block's q-blocks in
     a row from the first one the diagonal lets it see. The tile is
     computed kv-major, S^T = K @ Q^T and dP^T = V @ dO^T, so that
     dV += P^T @ dO and dK += dS^T @ Q are plain products: no operand is
     contracted over its major dim, no tile is transposed. lse2 and delta
-    ride lanes as (1, block_q) rows."""
+    ride lanes as (1, block_q) rows. With ``group`` query heads to a
+    key-value head the q table counts blocks through the group's heads
+    laid end to end (``_steps``), and the sums run over all of them."""
     import jax.experimental.pallas as pl
 
-    qi = qi_ref[pl.program_id(1)]
+    qh = qi = qi_ref[pl.program_id(1)]
     ki = ki_ref[pl.program_id(1)]
+    if group > 1:
+        qi = qh % n_qblocks
+    last = n_qblocks - 1
+    if window is not None:
+        last = jnp.minimum(last,
+                           (ki * block_k + block_k + window - 2) // block_q)
 
-    @pl.when(qi == ((ki * block_k) // block_q if causal else 0))
+    @pl.when(qh == ((ki * block_k) // block_q if causal else 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def tile(rel):
         def rows_at(lo):
-            start, full_start = _visible(rel, lo, chunk, block_q, True)
-            if start == block_q:
+            start, full_start, full_end, end = _span(
+                rel, lo, chunk, block_q, True, window)
+            if end <= start:
                 return
             rows = pl.ds(lo, chunk)
-            q = q_ref[0, start:]                          # (n, D)
-            do = do_ref[0, start:]
+            q = q_ref[0, start:end]                       # (n, D)
+            do = do_ref[0, start:end]
             s = lax.mul(_dot(k_ref[0, rows], q, _NT), scale * _LOG2E)
             if start < full_start:
                 s = _mask(s, 0, full_start - start, rel - lo + start, 0)
+            if full_end < end:
+                s = _mask(s, full_end - start, end - start,
+                          rel - lo + full_end - window + 1, 0, lax.lt)
             across = lambda row: lax.broadcast_in_dim(  # noqa: E731
                 row, s.shape, (0, 1))                     # (chunk, n)
-            p = lax.exp2(lax.sub(s, across(lse_ref[0, :, start:])))
+            p = lax.exp2(lax.sub(s, across(lse_ref[0, :, start:end])))
             dv_scr[rows] = lax.add(dv_scr[rows], _dot(
                 lax.convert_element_type(p, do.dtype), do, _NN))
             ds = lax.mul(p, lax.sub(_dot(v_ref[0, rows], do, _NT),
-                                    across(delta_ref[0, :, start:])))
+                                    across(delta_ref[0, :, start:end])))
             dk_scr[rows] = lax.add(dk_scr[rows], _dot(
                 lax.convert_element_type(ds, q.dtype), q, _NN))
 
         _for_chunks(rel, block_k, chunk, _GROUP["dkv"], rows_at)
 
     if causal:
-        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile)
+        _causal_bodies(qi * block_q - ki * block_k, block_q, block_k, tile,
+                       window)
     else:
         tile(None)
 
-    @pl.when(qi == n_qblocks - 1)
+    @pl.when(qh == (group - 1) * n_qblocks + last)
     def _write():
         dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -559,18 +685,18 @@ def _bwd_operands(q, k, v, o, lse, g):
     """Flattened operands the two backward kernels share. delta_i =
     sum_d dO_i * O_i is the rowwise correction in dS; O(S*D)."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    kv, sk = k.shape[1], k.shape[2]
     dof = g.reshape(b * h, sq, d)
     delta = jnp.sum(dof.astype(jnp.float32)
                     * o.reshape(b * h, sq, d).astype(jnp.float32), axis=-1)
     # the O(S) per-row vectors cross HBM as (bh, 1, sq) rows
-    return (q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
-            v.reshape(b * h, sk, d), dof, (lse * _LOG2E)[:, None, :],
+    return (q.reshape(b * h, sq, d), k.reshape(b * kv, sk, d),
+            v.reshape(b * kv, sk, d), dof, (lse * _LOG2E)[:, None, :],
             delta[:, None, :])
 
 
 def _pallas_dq(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
-               block_k, interpret):
+               block_k, interpret, window=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -579,21 +705,24 @@ def _pallas_dq(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
     block_q, block_k = _fit(block_q, block_k, sq, sk)
     chunk = _chunk(block_q)
     n_kblocks = sk // block_k
-    steps = _steps(sq // block_q, n_kblocks, block_q, block_k, causal, False)
+    steps = _steps(sq // block_q, n_kblocks, block_q, block_k, causal, False,
+                   window)
+    kv_rows = _kv_rows(bh // kf.shape[0])
     item = qf.dtype.itemsize
     vmem = ((6 * block_q + 4 * block_k) * d * item
             + block_q * (d + 2 * _LANES) * 4 + 6 * chunk * block_k * 4)
     return pl.pallas_call(
         functools.partial(_dq_kernel, block_q=block_q, block_k=block_k,
                           chunk=chunk, causal=causal, scale=scale,
-                          n_kblocks=n_kblocks),
+                          n_kblocks=n_kblocks,
+                          **({} if window is None else {"window": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, steps[0].size),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), _q_rows),
-                pl.BlockSpec((1, block_k, d), _k_rows),
-                pl.BlockSpec((1, block_k, d), _k_rows),
+                pl.BlockSpec((1, block_k, d), kv_rows),
+                pl.BlockSpec((1, block_k, d), kv_rows),
                 pl.BlockSpec((1, block_q, d), _q_rows),
                 pl.BlockSpec((1, 1, block_q), _q_lanes),
                 pl.BlockSpec((1, 1, block_q), _q_lanes),
@@ -610,26 +739,39 @@ def _pallas_dq(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
 
 
 def _pallas_dkv(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
-                block_k, interpret):
+                block_k, interpret, window=None):
+    """dK and dV of each key-value head, summed inside the kernel over
+    the query heads that read it: a group's heads are laid end to end, as
+    one operand of group * sq rows a key-value head (a reshape, no copy),
+    and a k-block's accumulators stay in VMEM through all of them."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, sq, d = qf.shape
+    (bh, sq, d), bg = qf.shape, kf.shape[0]
     sk = kf.shape[1]
+    group = bh // bg
     block_q, block_k = _fit(block_q, block_k, sq, sk)
     chunk = _chunk(block_k)
     n_qblocks = sq // block_q
-    steps = _steps(n_qblocks, sk // block_k, block_q, block_k, causal, True)
+    steps = _steps(n_qblocks, sk // block_k, block_q, block_k, causal, True,
+                   window, group)
+    if group > 1:
+        qf, dof = qf.reshape(bg, group * sq, d), dof.reshape(bg, group * sq, d)
+        lse2 = lse2.reshape(bg, 1, group * sq)
+        delta = delta.reshape(bg, 1, group * sq)
+    extra = {} if window is None else {"window": window}
+    if group > 1:
+        extra["group"] = group
     item = qf.dtype.itemsize
     vmem = ((4 * block_q + 8 * block_k) * d * item
             + 2 * block_k * d * 4 + 6 * chunk * block_q * 4)
     return pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, block_k=block_k,
                           chunk=chunk, causal=causal, scale=scale,
-                          n_qblocks=n_qblocks),
+                          n_qblocks=n_qblocks, **extra),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(bh, steps[0].size),
+            grid=(bg, steps[0].size),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), _q_rows),
                 pl.BlockSpec((1, block_k, d), _k_rows),
@@ -647,8 +789,8 @@ def _pallas_dkv(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
                 pltpu.VMEM((block_k, d), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), kf.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), vf.dtype),
+            jax.ShapeDtypeStruct((bg, sk, d), kf.dtype),
+            jax.ShapeDtypeStruct((bg, sk, d), vf.dtype),
         ],
         compiler_params=_compiler_params(interpret, vmem),
         interpret=interpret,
@@ -657,12 +799,13 @@ def _pallas_dkv(qf, kf, vf, dof, lse2, delta, causal, scale, block_q,
 
 
 def _backward(q, k, v, o, lse, g, causal, scale, dq_blocks, dkv_blocks,
-              interpret):
+              interpret, window=None):
     """dq, dk, dv from the saved output and row logsumexp ``lse``
     (bh, sq), each kernel on its own (block_q, block_k)."""
     ops = _bwd_operands(q, k, v, o, lse, g)
-    dq = _pallas_dq(*ops, causal, scale, *dq_blocks, interpret)
-    dk, dv = _pallas_dkv(*ops, causal, scale, *dkv_blocks, interpret)
+    w = () if window is None else (window,)
+    dq = _pallas_dq(*ops, causal, scale, *dq_blocks, interpret, *w)
+    dk, dv = _pallas_dkv(*ops, causal, scale, *dkv_blocks, interpret, *w)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
@@ -678,36 +821,39 @@ def _use_pallas():
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, blocks, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, scale, blocks, interpret, window=None):
     """``blocks``: the (block_q, block_k) of forward, dq and dk/dv."""
     if interpret or _use_pallas():
         return _pallas_forward(q, k, v, causal, scale, *blocks[0],
-                               interpret)[0]
-    return attention_reference(q, k, v, causal=causal, scale=scale)
+                               interpret, window)[0]
+    return attention_reference(q, k, v, causal=causal, scale=scale,
+                               window=window)
 
 
-def _flash_fwd(q, k, v, causal, scale, blocks, interpret):
+def _flash_fwd(q, k, v, causal, scale, blocks, interpret, window):
     if interpret or _use_pallas():
         out, lse = _pallas_forward(q, k, v, causal, scale, *blocks[0],
-                                   interpret)
+                                   interpret, window)
         # keep one lane of the (bh, sq, 128) kernel output — the lane dim
         # exists only for Mosaic's block constraint, not worth 128x HBM
         # across the fwd->bwd interval
         return out, (q, k, v, out, lse[:, :, 0])
-    out = attention_reference(q, k, v, causal=causal, scale=scale)
+    out = attention_reference(q, k, v, causal=causal, scale=scale,
+                              window=window)
     return out, (q, k, v, None, None)
 
 
-def _flash_bwd(causal, scale, blocks, interpret, res, g):
+def _flash_bwd(causal, scale, blocks, interpret, window, res, g):
     q, k, v, o, lse = res
     if lse is None:
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: attention_reference(q_, k_, v_, causal=causal,
-                                                   scale=scale), q, k, v)
+            lambda q_, k_, v_: attention_reference(
+                q_, k_, v_, causal=causal, scale=scale, window=window),
+            q, k, v)
         return vjp(g)
     return _backward(q, k, v, o, lse, g, causal, scale, blocks[1], blocks[2],
-                     interpret)
+                     interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -727,26 +873,39 @@ def _default_blocks(sq, sk, d=128, dtype=jnp.bfloat16):
                  for bq, bk in ((big, 1024), (big, big), (big, big)))
 
 
-def _computed_pairs(sq, sk, block_q, block_k, chunk, causal, kv_major):
-    """Score pairs one kernel computes for one head, from the same
+def _computed_pairs(sq, sk, block_q, block_k, chunk, causal, kv_major,
+                    window=None):
+    """Score pairs one kernel computes for one query head, from the same
     classification its body uses."""
     if not causal:
         return sq * sk
-    static = len(_diag_rels(block_q, block_k)) <= _MAX_DIAG_BODIES
+    static = _masked_rels(block_q, block_k, window) is not None
     chunked, other = (block_k, block_q) if kv_major else (block_q, block_k)
+    inside = float("inf") if window is None else window - block_q
     total = 0
     for qi in range(sq // block_q):
         for ki in range(sk // block_k):
             rel = qi * block_q - ki * block_k
-            if rel <= -block_q:
+            if rel <= -block_q or (window is not None
+                                   and rel >= window + block_k - 1):
                 continue
-            if rel >= block_k - 1 or not static:
+            if block_k - 1 <= rel <= inside or not static:
                 total += block_q * block_k
                 continue
             for lo in range(0, chunked, chunk):
-                first, last = _visible(rel, lo, chunk, other, kv_major)
-                total += chunk * ((other - first) if kv_major else last)
+                start, _, _, end = _span(rel, lo, chunk, other, kv_major,
+                                         window)
+                total += chunk * max(end - start, 0)
     return total
+
+
+def _kept_pairs(sq, sk, causal, window=None):
+    """Score pairs the mask keeps, for one query head."""
+    if not causal:
+        return sq * sk
+    if window is None or window >= sq:
+        return sq * (sq + 1) // 2
+    return window * (window + 1) // 2 + (sq - window) * window
 
 
 # metrics()["flash"] / dumps(): one entry per distinct call shape, made
@@ -756,19 +915,26 @@ def _computed_pairs(sq, sk, block_q, block_k, chunk, causal, kv_major):
 _CALLS = {}  # mxlint: disable=MX003 (GIL-atomic trace-time record, one string per call shape; a racing duplicate writes the same value)
 
 
-def _record_call(q, k, causal, blocks):
+def _record_call(q, k, causal, blocks, window=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    kept = sq * (sq + 1) // 2 if causal else sq * sk
+    kept = _kept_pairs(sq, sk, causal, window)
     parts = []
     for name, (bq, bk) in zip(("fwd", "dq", "dkv"), blocks):
         kv_major = name == "dkv"
         chunk = _chunk(bk if kv_major else bq)
-        pairs = _computed_pairs(sq, sk, bq, bk, chunk, causal, kv_major)
+        pairs = _computed_pairs(sq, sk, bq, bk, chunk, causal, kv_major,
+                                window)
         parts.append("%s=%dx%d/%.4f" % (name, bq, bk, pairs / kept))
-    _CALLS["%dx%dx%dx%dx%d.%s.%s" % (
+    # grouped heads and a window are named only where the call has them
+    key = "%dx%dx%dx%dx%d.%s.%s" % (
         b, h, sq, sk, d, jnp.dtype(q.dtype).name,
-        "causal" if causal else "full")] = " ".join(parts)
+        "causal" if causal else "full")
+    if k.shape[1] != h:
+        key += ".kv%d" % k.shape[1]
+    if window is not None:
+        key += ".window%d" % window
+    _CALLS[key] = " ".join(parts)
 
 
 _profiler.register_stats_provider("flash", lambda: dict(_CALLS),
@@ -776,13 +942,27 @@ _profiler.register_stats_provider("flash", lambda: dict(_CALLS),
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=False):
-    """Tiled attention. q,k,v: [B, H, S, D]. On TPU runs the Pallas
+                    block_k=None, interpret=False, window=None):
+    """Tiled attention. q: [B, H, S, D]; k, v: [B, G, S, D] with H a
+    multiple of G: query head h reads key-value head h // (H/G) from its
+    own rows, and dk/dv come summed over the group. ``window`` (needs
+    ``causal``): a query sees the last ``window`` keys only, itself among
+    them; tiles wholly outside are no grid steps. On TPU runs the Pallas
     kernels; elsewhere the jnp reference (or the kernels under
     ``interpret=True`` for testing). By default each of the three kernels
     takes its own measured tile shape (``_default_blocks``); an explicit
     block_q/block_k applies to all three. Blocks clamp to the sequence
     length."""
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            "flash_attention: %d query heads cannot share %d key and %d "
+            "value heads" % (q.shape[1], k.shape[1], v.shape[1]))
+    if window is not None:
+        if not causal:
+            raise ValueError("flash_attention: a window needs causal=True")
+        window = int(window)
+        if window >= q.shape[2]:
+            window = None       # it cuts nothing: the plain causal call
     if causal and q.shape[2] != k.shape[2]:
         # This kernel's causal mask is LEFT-aligned (col > row masked),
         # which is only the right semantics when q and kv index the
@@ -807,6 +987,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
              bk if block_k is None else int(block_k), sq, sk)
         for bq, bk in _default_blocks(sq, sk, q.shape[-1], q.dtype))
     if interpret or _use_pallas():
-        _record_call(q, k, causal, blocks)
+        _record_call(q, k, causal, blocks, window)
     return _flash(q, k, v, bool(causal), float(scale), blocks,
-                  bool(interpret))
+                  bool(interpret), window)

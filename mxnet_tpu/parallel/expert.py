@@ -7,13 +7,30 @@ tokens reach their experts via einsum dispatch/combine (Shazeer et al.
 arXiv:1701.06538, GShard arXiv:2006.16668). GSPMD turns the dispatch einsum
 into an all-to-all over ICI. Dense dispatch keeps shapes static — the XLA
 requirement — with capacity_factor bounding per-expert load.
+
+``moe_share`` is the other kind of expert layer: one chip's share of an
+expert-parallel layer. It is told which experts it holds (a contiguous
+range), routes over all of them (sigmoid scores, top-k of score + bias,
+weights normalised over the k chosen), sorts the token-slots by expert, runs
+grouped products over the slots of the experts held
+(``pallas_kernels/grouped_matmul.py``) and gathers the weighted results
+back. No capacity and no drops: the slot buffer holds every slot there is,
+and slots of absent experts get no row and cost no product. What the absent
+experts would add is left out: the exchange between chips is not this
+layer's.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-__all__ = ["top_k_routing", "moe_ffn", "MoELayer"]
+from .. import profiler as _profiler
+
+__all__ = ["top_k_routing", "moe_ffn", "MoELayer", "route_sigmoid",
+           "moe_share", "MOE_STATS"]
 
 
 def top_k_routing(logits, k=2, capacity=None):
@@ -93,3 +110,217 @@ class MoELayer:
     def __call__(self, params, x):
         return moe_ffn(x, params["router"], params["w1"], params["w2"],
                        k=self.k, capacity_factor=self.capacity_factor)
+
+
+# -- one chip's share of an expert-parallel layer ----------------------------
+
+MOE_STATS = ("layers", "slots_held", "slots_dropped", "max_load")
+
+
+def route_sigmoid(h, router_w, bias, k, route_scale=1.0):
+    """Scores over ALL experts in float32, the k chosen by score + bias
+    (``bias`` is a buffer: it selects and carries no gradient), weights the
+    chosen scores normalised over the k and scaled. h: [T, D]; router_w:
+    [D, E]; bias: [E]. -> (experts [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = lax.top_k(scores + lax.stop_gradient(
+        bias.astype(jnp.float32)), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return experts, weights * route_scale
+
+
+def _plan(experts, first, n_held, tile):
+    """Where each token-slot goes. experts: [T, k] over all E. The slots of
+    the experts held ([first, first + n_held)) are sorted by expert and laid
+    into a buffer an expert's group after the other, each group padded to
+    whole tiles of ``tile`` rows (and at least one: ``grouped_matmul``'s
+    layout). The buffer has a row for every slot there is, so nothing is
+    dropped whatever the imbalance; the slots of absent experts get no row.
+    -> (held [T, k] bool, row_of [T, k]: a held slot's row (0 for the
+        others), slot_of [rows]: the slot in each row (0 where ``live``
+        [rows] is false: padding, or behind the last group), counts and
+        sizes [n_held]: each group's slots and rows, group_of [rows / tile]:
+        each tile's expert)."""
+    T, k = experts.shape
+    whole = T * k
+    rows = -(-whole // tile) * tile + n_held * tile
+    local = experts - first
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held).reshape(whole).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32)
+    counts = jnp.sum(key[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32)
+    sizes = jnp.maximum(-(-counts // tile), 1) * tile
+    sorted_from = jnp.cumsum(counts) - counts     # a group's first sorted slot
+    row_from = jnp.cumsum(sizes) - sizes          # ... and its first row
+    mine = jnp.minimum(key, n_held - 1)
+    row_of = jnp.where(held.reshape(whole),
+                       row_from[mine] + place - sorted_from[mine], 0)
+    r = jnp.arange(rows, dtype=jnp.int32)
+    group = jnp.minimum(jnp.searchsorted(jnp.cumsum(sizes), r, side="right",
+                                         method="compare_all"),
+                        n_held - 1).astype(jnp.int32)
+    rank = r - row_from[group]
+    live = rank < counts[group]
+    slot_of = jnp.where(live, order[jnp.clip(sorted_from[group] + rank, 0,
+                                             whole - 1)], 0)
+    return (held, row_of.reshape(T, k), slot_of, live, counts, sizes,
+            group[::tile])
+
+
+def _slot_rows(table, row_of, held, j):
+    """table[row_of[:, j]] [T, D] in float32, nought where slot j is not
+    ``held``. The sums over a token's k slots below take k such gathers of
+    T rows and add as they go: one gather of T*k rows would lay a [T, k, D]
+    array down first, and read 16.9 -> 33.0 ms a step in the Trinity cell's
+    combine backward (PERF.md, PR 30)."""
+    rows = jnp.take(table, row_of[:, j], axis=0)
+    return jnp.where(held[:, j, None], rows, 0).astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _dispatch(x, slot_of, row_of, held, k):
+    """Rows of ``x`` [T, D] in slot order [rows, D]: a gather. Its transpose
+    is a gather too: a token's gradient is the sum over its own slots'
+    rows, so no scatter runs in either direction."""
+    return jnp.take(x, slot_of // k, axis=0)
+
+
+def _dispatch_fwd(x, slot_of, row_of, held, k):
+    return _dispatch(x, slot_of, row_of, held, k), (row_of, held)
+
+
+def _dispatch_bwd(res, g):
+    row_of, held = res
+    dx = sum(_slot_rows(g, row_of, held, j) for j in range(row_of.shape[1]))
+    return dx.astype(g.dtype), None, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _combine(ys, weights, slot_of, row_of, held, live, dtype):
+    """y[t] = sum over the token's held slots of weight * ys[row]: a gather
+    by ``row_of``. Rows that are not ``live`` (padding and what lies behind
+    the last group, where no product ran) are never read."""
+    return sum(_slot_rows(ys, row_of, held, j) * weights[:, j, None]
+               for j in range(row_of.shape[1])).astype(dtype)
+
+
+def _combine_fwd(ys, weights, slot_of, row_of, held, live, dtype):
+    return (_combine(ys, weights, slot_of, row_of, held, live, dtype),
+            (ys, weights, slot_of, row_of, held, live))
+
+
+def _combine_bwd(dtype, res, g):
+    ys, weights, slot_of, row_of, held, live = res
+    k = weights.shape[1]
+    g32 = g.astype(jnp.float32)
+    dw = jnp.stack([jnp.sum(_slot_rows(ys, row_of, held, j) * g32, axis=-1)
+                    for j in range(k)], axis=1)
+    w_row = jnp.take(weights.reshape(-1), slot_of)           # [rows]
+    dys = jnp.where(live[:, None],
+                    jnp.take(g, slot_of // k, axis=0) * w_row[:, None], 0)
+    return dys.astype(ys.dtype), dw, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _gated(x, w_gate, w_up, w_down, product):
+    """A three-matrix SiLU-gated feed-forward under ``product(a, w)``."""
+    return product(jax.nn.silu(product(x, w_gate)) * product(x, w_up),
+                   w_down)
+
+
+def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
+              first=0, route_scale=1.0, interpret=False):
+    """The share of an expert layer that holds experts ``first`` to
+    ``first + w_gate.shape[0]`` of the ``router_w.shape[1]`` routed over.
+
+    x: [B, S, D]; router_w: [D, E]; bias: [E]; w_gate, w_up: [held, D, F];
+    w_down: [held, F, D]; ``shared``: (w_gate [D, Fs], w_up, w_down
+    [Fs, D]) of the shared expert, computed for every token.
+    -> (y [B, S, D]: shared(x) + sum over the chosen experts HELD of weight
+    * expert(x), int32 [4] as ``MOE_STATS`` names them: 1, slots routed to
+    experts held, those of them that no product covered (0: the buffer
+    holds every slot), the largest load of an expert held)."""
+    from ..pallas_kernels import grouped_matmul as _gmm
+    B, S, D = x.shape
+    T, n_held = B * S, w_gate.shape[0]
+    xt = x.reshape(T, D)
+    with jax.named_scope("mx.moe_route"):
+        experts, weights = route_sigmoid(xt, router_w, bias, k, route_scale)
+    with jax.named_scope("mx.moe_dispatch"):
+        held, row_of, slot_of, live, counts, sizes, group_of = _plan(
+            experts, first, n_held, _gmm.TILE)
+        xs = _dispatch(xt, slot_of, row_of, held, k)
+    with jax.named_scope("mx.moe_experts"):
+        ys = _gated(xs, w_gate, w_up, w_down,
+                    lambda a, w: _gmm.grouped_matmul(a, w, sizes, group_of,
+                                                     interpret))
+    with jax.named_scope("mx.moe_combine"):
+        y = _combine(ys, weights, slot_of, row_of, held, live, x.dtype)
+    if shared is not None:
+        with jax.named_scope("mx.moe_shared"):
+            y = y + _gated(xt, *shared, jnp.dot)
+    n_held_slots = jnp.sum(held, dtype=jnp.int32)
+    stats = jnp.stack([jnp.int32(1), n_held_slots,
+                       n_held_slots - jnp.sum(live, dtype=jnp.int32),
+                       jnp.max(counts)])
+    return y.reshape(B, S, D), stats
+
+
+def merge_stats(a, b):
+    """Two layers' (or steps') ``MOE_STATS``: sums, and the larger load."""
+    return jnp.concatenate([a[:3] + b[:3], jnp.maximum(a[3:], b[3:])])
+
+
+# metrics()["moe"]: the counters of the train steps that carry them, kept
+# on the device as the steps left them and fetched only when asked for
+_LIVE_COUNTERS = []  # mxlint: disable=MX003 (weak references appended when a step is built; read as a snapshot)
+
+
+def track(holder):
+    """``holder.moe_counters`` (a device array or None) is summed into
+    ``metrics()['moe']`` for as long as ``holder`` lives."""
+    import weakref
+    _LIVE_COUNTERS.append(weakref.ref(holder))
+
+
+def moe_stats():
+    """``metrics()['moe']``: over every live train step with an expert share:
+    ``layers`` (expert-layer calls), ``slots_held`` (token-slots routed to
+    experts held), ``slots_dropped`` (must read 0), ``max_load`` (the most
+    slots one held expert got in one call) and ``mean_load``."""
+    import numpy as np
+    total = np.zeros(len(MOE_STATS), np.int64)
+    experts = 0
+    for ref in list(_LIVE_COUNTERS):
+        holder = ref()
+        if holder is None:
+            _LIVE_COUNTERS.remove(ref)
+        elif holder.moe_counters is not None:
+            got = np.asarray(jax.device_get(holder.moe_counters), np.int64)
+            total[:3] += got[:3]
+            total[3] = max(total[3], got[3])
+            experts = max(experts, holder.moe_held)
+    out = {n: int(v) for n, v in zip(MOE_STATS, total)}
+    out["mean_load"] = (out["slots_held"] / (out["layers"] * experts)
+                        if out["layers"] and experts else 0.0)
+    return out
+
+
+def _reset_moe_stats():
+    for ref in list(_LIVE_COUNTERS):
+        holder = ref()
+        if holder is not None and holder.moe_counters is not None:
+            holder.moe_counters = jnp.zeros_like(holder.moe_counters)
+
+
+_profiler.register_stats_provider("moe", moe_stats, _reset_moe_stats)

@@ -43,6 +43,7 @@ from .compat import NamedSharding, PartitionSpec as P
 
 from .ring_attention import ring_attention, blockwise_attention
 from .ulysses import ulysses_attention_local
+from . import expert as _expert
 from .expert import moe_ffn
 
 __all__ = ["TransformerConfig", "init_params", "apply", "loss_fn",
@@ -95,13 +96,67 @@ class TransformerConfig:
     # MXTPU_CE_LOCAL_ACCUM env var ('auto'/'1'/'0', a compile-signature
     # token) overrides the auto default process-wide.
     ce_local_accum: Optional[bool] = None
+    # -- what a decoder with grouped heads, windows and an expert share
+    # needs; every default leaves the plain decoder's program as it is ------
+    # key-value heads (None = n_heads): query head h reads head h // (H/G)
+    n_kv_heads: Optional[int] = None
+    # a head's size where it is not dim // n_heads
+    head_size: Optional[int] = None
+    # one period of attention kinds, "sliding" (a query sees the last
+    # ``window`` keys) or "full"; the trunk scans over whole periods, the
+    # body holding the period's layers in turn. () = every layer "full",
+    # scanned one layer a step.
+    layer_pattern: tuple = ()
+    window: Optional[int] = None
+    # attention kinds of the leading dense layers (gated FFN of width
+    # ffn_hidden) that come before the scanned periods; only with a
+    # layer_pattern. n_layers = len(dense_layers) + periods * len(pattern)
+    dense_layers: tuple = ()
+    rope_on: str = "all"               # 'all' | 'sliding': which kinds rotate
+    norm_eps: float = 1e-6
+    qk_norm: bool = False              # RMSNorm of q and k over a head
+    attn_gate: bool = False            # attention output * sigmoid(h W_g)
+    post_norms: bool = False           # a norm after attention and after FFN
+    embed_scale: bool = False          # embedding * sqrt(dim)
+    # the scanned layers' FFN as one chip's SHARE of an expert layer
+    # (expert.moe_share): set moe_hidden (the experts' width); the layer
+    # routes over num_experts, holds experts_held = (first, count) of them
+    # (None = all), moe_shared shared experts of the same width, three-
+    # matrix SiLU experts, sigmoid scores, no drops, no auxiliary loss
+    moe_hidden: Optional[int] = None
+    experts_held: Optional[tuple] = None
+    moe_shared: int = 0
+    route_scale: float = 1.0
 
     @property
     def head_dim(self):
-        return self.dim // self.n_heads
+        return self.head_size or self.dim // self.n_heads
+
+    @property
+    def kv_heads(self):
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def expert_share(self):
+        """(first, count) of the experts held, where the scanned layers
+        are shares of an expert layer; else None."""
+        if self.moe_hidden is None or self.num_experts <= 0:
+            return None
+        return tuple(self.experts_held or (0, self.num_experts))
+
+    @property
+    def periods(self):
+        """Whole periods of ``layer_pattern`` after the dense layers."""
+        n, p = self.n_layers - len(self.dense_layers), len(self.layer_pattern)
+        if p == 0 or n <= 0 or n % p:
+            raise ValueError(
+                "n_layers=%d is not %d dense layers and whole periods of %d"
+                % (self.n_layers, len(self.dense_layers), p))
+        return n // p
 
 
-def _rms_norm(x, scale, eps=1e-6):
+def _rms_norm(x, scale, eps):
+    """``eps`` is the configuration's (``TransformerConfig.norm_eps``)."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
@@ -129,6 +184,9 @@ def init_params(key, cfg: TransformerConfig):
 
     def norm(k, shape, fan_in):
         return (jr.normal(k, shape) * (fan_in ** -0.5)).astype(dt)
+
+    if cfg.layer_pattern:
+        return _init_pattern_params(key, cfg, norm)
 
     layer = {
         "ln1": jnp.ones((L, D), dt),
@@ -160,11 +218,92 @@ def init_params(key, cfg: TransformerConfig):
     }
 
 
+def _pattern_leaves(cfg, experts):
+    """{leaf: (shape of one layer, fan_in or None for a norm's scale or 0
+    for the router's bias, spec of one layer)} of a pattern model's dense
+    (``experts`` False) or scanned layer."""
+    D, H, G, Dh = cfg.dim, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    out = {"ln1": ((D,), None, (None,)),
+           "wq": ((D, H, Dh), D, (None, "tp", None)),
+           "wk": ((D, G, Dh), D, (None, "tp", None)),
+           "wv": ((D, G, Dh), D, (None, "tp", None)),
+           "wo": ((H, Dh, D), H * Dh, ("tp", None, None)),
+           "ln2": ((D,), None, (None,))}
+    if cfg.attn_gate:
+        out["w_attn_gate"] = ((D, H, Dh), D, (None, "tp", None))
+    if cfg.qk_norm:
+        out["q_norm"] = out["k_norm"] = ((Dh,), None, (None,))
+    if cfg.post_norms:
+        out["ln1_post"] = out["ln2_post"] = ((D,), None, (None,))
+    share = cfg.expert_share if experts else None
+    if share is None:
+        F = cfg.ffn_hidden
+        out.update({"w_gate": ((D, F), D, (None, "tp")),
+                    "w_up": ((D, F), D, (None, "tp")),
+                    "w_down": ((F, D), F, ("tp", None))})
+        return out
+    E, Fm, held = cfg.num_experts, cfg.moe_hidden, share[1]
+    out.update({"moe_router": ((D, E), D, (None, None)),
+                "moe_bias": ((E,), 0, (None,)),
+                "moe_w_gate": ((held, D, Fm), D, (None, None, None)),
+                "moe_w_up": ((held, D, Fm), D, (None, None, None)),
+                "moe_w_down": ((held, Fm, D), Fm, (None, None, None))})
+    if cfg.moe_shared:
+        Fs = Fm * cfg.moe_shared
+        out.update({"ws_gate": ((D, Fs), D, (None, "tp")),
+                    "ws_up": ((D, Fs), D, (None, "tp")),
+                    "ws_down": ((Fs, D), Fs, ("tp", None))})
+    return out
+
+
+def _init_pattern_params(key, cfg, norm):
+    """Params of a pattern model: ``dense`` stacked [n_dense, ...] (where
+    there are leading dense layers) and ``layers`` stacked [periods, P,
+    ...], one row a period and one column a place in the pattern."""
+    dt = jnp.dtype(cfg.dtype)
+    D = cfg.dim
+
+    def stack(lead, leaves, salt):
+        out = {}
+        for i, (name, (shape, fan_in, _)) in enumerate(leaves.items()):
+            k = jr.fold_in(key, salt + i)
+            if fan_in is None:
+                out[name] = jnp.ones(lead + shape, dt)
+            elif fan_in == 0:   # the router's bias: a buffer, N(0, 0.01^2)
+                out[name] = (jr.normal(k, lead + shape) * 0.01).astype(dt)
+            else:
+                out[name] = norm(k, lead + shape, fan_in)
+        return out
+
+    emb_key, out_key = jr.split(jr.fold_in(key, 99))
+    embed = norm(emb_key, (cfg.vocab_size, D), D)
+    params = {
+        "embed": embed if cfg.embed_scale else embed * (D ** 0.5),
+        "layers": stack((cfg.periods, len(cfg.layer_pattern)),
+                        _pattern_leaves(cfg, True), 1000),
+        "ln_f": jnp.ones((D,), dt),
+        "w_out": norm(out_key, (D, cfg.vocab_size), D),
+    }
+    if cfg.dense_layers:
+        params["dense"] = stack((len(cfg.dense_layers),),
+                                _pattern_leaves(cfg, False), 2000)
+    return params
+
+
 def param_specs(cfg: TransformerConfig):
     """PartitionSpecs matching init_params structure (GSPMD mode).
     Column-parallel on heads/ffn over 'tp'; fsdp composes by sharding the
     layer-stack axis? No — fsdp shards the largest non-tp dim via
     sharding.fsdp rules; here we give the Megatron TP layout."""
+    if cfg.layer_pattern:
+        of = lambda lead, experts: {  # noqa: E731
+            n: P(*(lead + spec))
+            for n, (_, _, spec) in _pattern_leaves(cfg, experts).items()}
+        specs = {"embed": P("tp", None), "layers": of((None, None), True),
+                 "ln_f": P(None), "w_out": P(None, "tp")}
+        if cfg.dense_layers:
+            specs["dense"] = of((None,), False)
+        return specs
     lead = ("pp",) if cfg.pp > 1 else (None,)
     lead = lead + ((None,) if cfg.pp > 1 else ())
 
@@ -204,11 +343,18 @@ def param_specs(cfg: TransformerConfig):
 # GSPMD mode forward (pp == 1)
 # --------------------------------------------------------------------------
 
-def _attention(cfg, mesh, q, k, v, positions):
-    """q/k/v: [B, S, H, Dh] -> [B, S, H, Dh]. Global arrays (GSPMD mode)."""
+def _attention(cfg, mesh, q, k, v, positions, window=None):
+    """q: [B, S, H, Dh], k/v: [B, S, G, Dh] -> [B, S, H, Dh]. Global arrays
+    (GSPMD mode). ``window``: this layer's queries see that many keys."""
     qt = jnp.transpose(q, (0, 2, 1, 3))  # [B, H, S, Dh]
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
+    if (window is not None or kt.shape[1] != qt.shape[1]) \
+            and cfg.attn_mode != "local":
+        raise NotImplementedError(
+            "grouped key-value heads and windows run in attn_mode='local' "
+            "only (the flash kernels); attn_mode=%r has neither"
+            % cfg.attn_mode)
     if cfg.attn_mode == "ring_flash" and mesh is not None:
         # inter-chip ppermute ring x intra-chip Pallas flash blocks,
         # differentiable both directions (parallel/ring_flash.py)
@@ -232,6 +378,8 @@ def _attention(cfg, mesh, q, k, v, positions):
         S = qt.shape[2]
         if S % 128 == 0:
             attend = functools.partial(flash_attention, causal=cfg.causal)
+            if window is not None:
+                attend = functools.partial(attend, window=window)
             sizes = _mesh_sizes(mesh)
             if any(n > 1 for n in sizes.values()):
                 # GSPMD cannot partition a Mosaic kernel ("wrap the call
@@ -244,14 +392,16 @@ def _attention(cfg, mesh, q, k, v, positions):
                 from .compat import shard_map
                 spec = P(
                     "dp" if qt.shape[0] % sizes.get("dp", 1) == 0 else None,
-                    "tp" if qt.shape[1] % sizes.get("tp", 1) == 0 else None,
+                    "tp" if qt.shape[1] % sizes.get("tp", 1) == 0
+                    and kt.shape[1] % sizes.get("tp", 1) == 0 else None,
                     None, None)
                 attend = shard_map(attend, mesh, in_specs=(spec,) * 3,
                                    out_specs=spec, check_vma=False)
             ot = attend(qt, kt, vt)
         else:
             from ..pallas_kernels.flash_attention import attention_reference
-            ot = attention_reference(qt, kt, vt, causal=cfg.causal)
+            ot = attention_reference(qt, kt, vt, causal=cfg.causal,
+                                     window=window)
     return jnp.transpose(ot, (0, 2, 1, 3))
 
 
@@ -263,31 +413,58 @@ def _mesh_sizes(mesh):
             dict(getattr(mesh, "mesh", mesh).shape).items()}
 
 
-def _layer_body(cfg, mesh, positions, x, lp):
-    """One transformer layer. x: [B, S, D]; lp: this layer's params."""
+def _layer_body(cfg, mesh, positions, x, lp, kind="full"):
+    """One transformer layer. x: [B, S, D]; lp: this layer's params, which
+    say what its feed-forward is (dense, the GShard experts, or a share of
+    an expert layer); ``kind``: its attention, "full" or "sliding".
+    -> (x, aux): the GShard load-balance loss, or the share's counters."""
+    eps, sliding = cfg.norm_eps, kind == "sliding"
     with jax.named_scope("mx.attn_proj"):
-        h = _rms_norm(x, lp["ln1"])
+        h = _rms_norm(x, lp["ln1"], eps)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
         k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
         v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)), positions),
-                          (0, 2, 1, 3))
-        k = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)), positions),
-                          (0, 2, 1, 3))
+        if cfg.attn_gate:
+            gate = jnp.einsum("bsd,dhk->bshk", h, lp["w_attn_gate"])
+        if cfg.qk_norm:
+            q = _rms_norm(q, lp["q_norm"], eps)
+            k = _rms_norm(k, lp["k_norm"], eps)
+        if cfg.rope_on == "all" or sliding:
+            q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)),
+                                    positions), (0, 2, 1, 3))
+            k = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)),
+                                    positions), (0, 2, 1, 3))
     with jax.named_scope("mx.flash"):
-        o = _ckpt_name(_attention(cfg, mesh, q, k, v, positions), "attn_o")
+        o = _ckpt_name(_attention(cfg, mesh, q, k, v, positions,
+                                  cfg.window if sliding else None), "attn_o")
     with jax.named_scope("mx.attn_out"):
-        x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+        if cfg.attn_gate:
+            o = o * jax.nn.sigmoid(gate)
+        a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+        if cfg.post_norms:
+            a = _rms_norm(a, lp["ln1_post"], eps)
+        x = x + a
     with jax.named_scope("mx.ffn"):
-        h = _rms_norm(x, lp["ln2"])
-        if cfg.num_experts > 0:
+        h = _rms_norm(x, lp["ln2"], eps)
+        if "moe_w_gate" in lp:
+            shared = (lp["ws_gate"], lp["ws_up"], lp["ws_down"]) \
+                if "ws_gate" in lp else None
+            y, aux = _expert.moe_share(
+                h, lp["moe_router"], lp["moe_bias"], lp["moe_w_gate"],
+                lp["moe_w_up"], lp["moe_w_down"], shared, k=cfg.moe_k,
+                first=cfg.expert_share[0], route_scale=cfg.route_scale)
+        elif cfg.num_experts > 0 and cfg.moe_hidden is None:
             y, aux = moe_ffn(h, lp["moe_router"], lp["moe_w1"],
                              lp["moe_w2"], k=cfg.moe_k)
             return x + y, aux
-        g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
-        u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
-        prod = _ckpt_name(g * u, "ffn_prod")
-        return x + jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), 0.0
+        else:
+            g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
+            u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
+            prod = _ckpt_name(g * u, "ffn_prod")
+            y, aux = jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), 0.0
+        if cfg.post_norms:
+            y = _rms_norm(y, lp["ln2_post"], eps)
+        return x + y, aux
 
 
 def apply(params, tokens, cfg: TransformerConfig, mesh=None,
@@ -316,7 +493,11 @@ def _hidden(params, tokens, cfg, mesh):
     returns (x [B,S,D], summed aux)."""
     with jax.named_scope("mx.embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.embed_scale:
+            x = (x * (cfg.dim ** 0.5)).astype(x.dtype)
     positions = jnp.arange(tokens.shape[1])
+    if cfg.layer_pattern:
+        return _hidden_pattern(params, x, positions, cfg, mesh)
 
     def body(x, lp):
         x, aux = _layer_body(cfg, mesh, positions, x, lp)
@@ -327,8 +508,45 @@ def _hidden(params, tokens, cfg, mesh):
     with jax.named_scope("mx.layer"):
         x, auxs = lax.scan(body, x, params["layers"])
     with jax.named_scope("mx.head_ce"):
-        x = _rms_norm(x, params["ln_f"])
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     return x, jnp.sum(auxs)
+
+
+def _hidden_pattern(params, x, positions, cfg, mesh):
+    """The trunk of a pattern model: the leading dense layers, then a scan
+    over whole periods whose body holds the period's layers in turn, each
+    under the layer remat. -> (x, the expert shares' counters summed, or
+    0.0 where no layer is a share)."""
+    counted = cfg.expert_share is not None
+    zero = jnp.zeros(len(_expert.MOE_STATS), jnp.int32)
+
+    def layer(kind):
+        def one(x, lp):
+            x, aux = _layer_body(cfg, mesh, positions, x, lp, kind)
+            return x, (aux if counted and "moe_w_gate" in lp else zero)
+        return jax.checkpoint(one, policy=_remat_policy(cfg)) \
+            if cfg.remat else one
+
+    at = lambda tree, i: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: a[i], tree)
+
+    def period(x, lp):
+        stats = zero
+        for j, kind in enumerate(cfg.layer_pattern):
+            x, one = layer(kind)(x, at(lp, j))
+            stats = _expert.merge_stats(stats, one)
+        return x, stats
+
+    with jax.named_scope("mx.layer"):
+        for i, kind in enumerate(cfg.dense_layers):
+            x, _ = layer(kind)(x, at(params["dense"], i))
+        x, stats = lax.scan(period, x, params["layers"])
+    with jax.named_scope("mx.head_ce"):
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    if not counted:
+        return x, 0.0
+    return x, jnp.concatenate([jnp.sum(stats[:, :3], axis=0),
+                               jnp.max(stats[:, 3:], axis=0)])
 
 
 def _chunked_ce(x, w_out, targets, n_chunks):
@@ -475,6 +693,15 @@ def ce_local_accum_active(cfg, mesh, batch, seq):
 
 
 def loss_fn(params, tokens, targets, cfg, mesh=None, aux_weight=0.01):
+    loss, aux = _loss_and_aux(params, tokens, targets, cfg, mesh)
+    if cfg.num_experts > 0 and cfg.moe_hidden is None:
+        loss = loss + aux_weight * aux  # GShard load-balance pressure
+    return loss
+
+
+def _loss_and_aux(params, tokens, targets, cfg, mesh):
+    """-> (mean token NLL, what the trunk gave beside the hidden state: the
+    GShard layers' load-balance loss, or an expert share's counters)."""
     if cfg.loss_chunks > 1:
         if tokens.shape[1] % cfg.loss_chunks != 0:
             # a silent full-logits fallback would re-materialize the
@@ -500,9 +727,7 @@ def loss_fn(params, tokens, targets, cfg, mesh=None, aux_weight=0.01):
             ll = jnp.take_along_axis(logp, targets[..., None],
                                      axis=-1)[..., 0]
             loss = -jnp.mean(ll)
-    if cfg.num_experts > 0:
-        loss = loss + aux_weight * aux  # GShard load-balance pressure
-    return loss
+    return loss, aux
 
 
 # --------------------------------------------------------------------------
@@ -514,7 +739,7 @@ def _layer_body_local(cfg, positions, x, lp):
     shards; row-parallel outputs need psum over 'tp'. Sequence dim of x is
     the local 'sp' shard; attention uses the ppermute ring."""
     with jax.named_scope("mx.attn_proj"):
-        h = _rms_norm(x, lp["ln1"])
+        h = _rms_norm(x, lp["ln1"], cfg.norm_eps)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
         k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
         v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
@@ -533,7 +758,7 @@ def _layer_body_local(cfg, positions, x, lp):
         attn_out = lax.psum(jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), "tp")
         x = x + attn_out
     with jax.named_scope("mx.ffn"):
-        h = _rms_norm(x, lp["ln2"])
+        h = _rms_norm(x, lp["ln2"], cfg.norm_eps)
         g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
         u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
         ffn_out = lax.psum(
@@ -569,7 +794,7 @@ def _pipeline_forward_local(cfg, params, tokens):
     outs = gpipe_loop(stage_fn, x_mb, "pp")
     x = outs.reshape(B, S_local, cfg.dim)
     with jax.named_scope("mx.head_ce"):
-        x = _rms_norm(x, params["ln_f"])
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
     return logits
 
@@ -586,6 +811,16 @@ def _pipeline_loss_local(cfg, params, tokens, targets):
 # --------------------------------------------------------------------------
 # Train-step builders
 # --------------------------------------------------------------------------
+
+def _sgd_momentum(params, mom, grads, learning_rate):
+    """The step's one optimizer: momentum 0.9, in the state's own type.
+    -> (new params, new momentum)."""
+    with jax.named_scope("mx.optimizer"):
+        new_mom = jax.tree_util.tree_map(lambda m, g: 0.9 * m + g, mom, grads)
+        new_params = jax.tree_util.tree_map(
+            lambda p, m: p - learning_rate * m, params, new_mom)
+    return new_params, new_mom
+
 
 def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
     """Return (init_fn, step_fn).
@@ -611,6 +846,10 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
         momentum = jax.tree_util.tree_map(jnp.zeros_like, params)
         return params, momentum
 
+    if cfg.pp == 1 and cfg.expert_share is not None:
+        return init_fn, _profiler.instrument_step(
+            _CountedStep(cfg, mesh, param_sh, learning_rate),
+            "mx.train_step")
     if cfg.pp == 1:
         def loss_of(params, tokens, targets):
             return loss_fn(params, tokens, targets, cfg, mesh)
@@ -626,11 +865,8 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
             params, mom = state
             loss, grads = jax.value_and_grad(loss_of)(params, tokens,
                                                       targets)
-            with jax.named_scope("mx.optimizer"):
-                new_mom = jax.tree_util.tree_map(
-                    lambda m, g: 0.9 * m + g, mom, grads)
-                new_params = jax.tree_util.tree_map(
-                    lambda p, m: p - learning_rate * m, params, new_mom)
+            new_params, new_mom = _sgd_momentum(params, mom, grads,
+                                                learning_rate)
             return (new_params, new_mom), loss
     else:
         from .compat import shard_map
@@ -657,11 +893,8 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
             grads = jax.tree_util.tree_map(
                 reduce_grad, grads, specs,
                 is_leaf=lambda l: hasattr(l, "shape"))
-            with jax.named_scope("mx.optimizer"):
-                new_mom = jax.tree_util.tree_map(
-                    lambda m, g: 0.9 * m + g, mom, grads)
-                new_params = jax.tree_util.tree_map(
-                    lambda p, m: p - learning_rate * m, params, new_mom)
+            new_params, new_mom = _sgd_momentum(params, mom, grads,
+                                                learning_rate)
             loss = lax.pmean(lax.pmean(loss, "dp"), "sp")
             return new_params, new_mom, loss
 
@@ -679,6 +912,60 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
     # the jitted step behind the program's own span and counters
     # (profiler.metrics()['train_step']); .lower/.trace reach the jit
     return init_fn, _profiler.instrument_step(step_fn, "mx.train_step")
+
+
+class _CountedStep:
+    """The GSPMD step of a model whose scanned layers are expert shares:
+    the same jitted, donated SGD-momentum step, with the shares' counters
+    (``expert.MOE_STATS``) carried through it as one more donated array.
+    They stay on the device from step to step and are fetched only when
+    ``profiler.metrics()['moe']`` is asked for. Callers see ``step(state,
+    tokens, targets) -> (state, loss)`` and ``lower`` of the same three."""
+
+    def __init__(self, cfg, mesh, param_sh, learning_rate):
+        raw_mesh = getattr(mesh, "mesh", mesh)
+        batch_sh = NamedSharding(raw_mesh, P("dp", "sp"))
+        everywhere = NamedSharding(raw_mesh, P())
+        self.moe_held = cfg.expert_share[1]
+        self.moe_counters = None
+        self._everywhere = everywhere
+
+        @functools.partial(
+            jax.jit,  # mxlint: disable=MX022 (benchmark/verification harness: callers AOT-compile the step and account inventories explicitly via comm_model)
+            in_shardings=((param_sh, param_sh), batch_sh, batch_sh,
+                          everywhere),
+            out_shardings=((param_sh, param_sh), None, everywhere),
+            donate_argnums=(0, 3))
+        def step_fn(state, tokens, targets, counters):
+            params, mom = state
+            (loss, stats), grads = jax.value_and_grad(
+                _loss_and_aux, has_aux=True)(params, tokens, targets, cfg,
+                                             mesh)
+            new_params, new_mom = _sgd_momentum(params, mom, grads,
+                                                learning_rate)
+            return ((new_params, new_mom), loss,
+                    _expert.merge_stats(counters, stats))
+
+        self._jitted = step_fn
+        _expert.track(self)
+
+    def _counters(self):
+        if self.moe_counters is None:
+            self.moe_counters = jax.device_put(
+                jnp.zeros(len(_expert.MOE_STATS), jnp.int32),
+                self._everywhere)
+        return self.moe_counters
+
+    def __call__(self, state, tokens, targets):
+        state, loss, self.moe_counters = self._jitted(
+            state, tokens, targets, self._counters())
+        return state, loss
+
+    def lower(self, state, tokens, targets):
+        return self._jitted.lower(state, tokens, targets, self._counters())
+
+    def trace(self, state, tokens, targets):
+        return self._jitted.trace(state, tokens, targets, self._counters())
 
 
 def _spec_mentions(spec, axis):

@@ -93,3 +93,48 @@ def test_flash_inside_the_sharded_transformer_step_lowers(as_on_tpu):
         exported = jax.export.export(step_fn, platforms=["tpu"])(
             (params, params), toks, toks)
     assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == 4
+
+
+def test_flash_with_groups_and_a_window_lowers_at_the_trinity_cells_shape(
+        as_on_tpu):
+    """[2, 32 / 4, 8192, 128] bf16, window 2048, each kernel on its default
+    tile: forward, dq and the dk/dv that sums over a group's eight heads."""
+    import jax.numpy as jnp
+    FA = importlib.import_module("mxnet_tpu.pallas_kernels.flash_attention")
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 4, 8192, 128), jnp.bfloat16)
+
+    def fwd_and_grads(q, k, v, g):
+        out, vjp = jax.vjp(lambda a, b, c: FA.flash_attention(
+            a, b, c, causal=True, window=2048), q, k, v)
+        return (out,) + vjp(g)
+
+    exported = jax.export.export(jax.jit(fwd_and_grads),
+                                 platforms=["tpu"])(q, kv, kv, q)
+    assert [a.shape for a in exported.out_avals] == [
+        q.shape, q.shape, kv.shape, kv.shape]
+    assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == 3
+
+
+def test_the_grouped_products_lower_at_the_trinity_cells_shape(monkeypatch):
+    """139264 rows (16384 tokens x 8 slots and a tile of padding an expert)
+    of width 2048 against 32 matrices of 2048 x 1024: forward, dx, dw."""
+    import jax.numpy as jnp
+    G = importlib.import_module("mxnet_tpu.pallas_kernels.grouped_matmul")
+    monkeypatch.setattr(G, "_use_pallas", lambda: True)
+    rows = 16384 * 8 + 32 * G.TILE
+    x = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((32, 2048, 1024), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((32,), jnp.int32)
+    group_of = jax.ShapeDtypeStruct((rows // G.TILE,), jnp.int32)
+
+    def grads(x, w, sizes, group_of):
+        out, vjp = jax.vjp(lambda x, w: G.grouped_matmul(
+            x, w, sizes, group_of), x, w)
+        return (out,) + vjp(out)
+
+    exported = jax.export.export(jax.jit(grads), platforms=["tpu"])(
+        x, w, sizes, group_of)
+    assert [a.shape for a in exported.out_avals] == [
+        (rows, 1024), x.shape, w.shape]
+    assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == 3

@@ -1,0 +1,162 @@
+"""The trunk with layer kinds: grouped-query heads, sliding and full layers
+in one scanned period after a leading dense layer, gated attention, q/k
+norms, four norms a layer, a scaled embedding, and scanned layers that are
+one chip's share of an expert layer. The program's loss and every gradient
+against the plain reference (``chipbench/reference/afmoe_decoder.py``) on
+seeded weights; and the dense decoder's step, which none of this may move,
+bitwise against the parent's."""
+import hashlib
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import jax.random as jr
+import numpy as onp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from mxnet_tpu.parallel import create_mesh  # noqa: E402
+from mxnet_tpu.parallel import transformer as T  # noqa: E402
+
+TINY = os.path.join(ROOT, "tests", "chipbench", "tiny_afmoe", "configs",
+                    "tiny_afmoe.json")
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """The tiny share in float32: configuration, weights, one batch."""
+    from chipbench.models import afmoe_decoder as adapter
+    with open(TINY) as f:
+        m = json.load(f)
+    a = dict(m["assumed"], dtype="float32")
+    cfg = adapter.transformer_config(m, a, 128)
+    words = adapter.seed_words(3000000019)
+    weights = adapter.make_weights(m, words, jnp.float32)
+    (tokens, targets), = adapter.make_batches(
+        m, {"n_batches": 1, "batch": 2, "seq_len": 128}, words)
+    return m, cfg, weights, tokens, targets
+
+
+def _reference_loss(m, weights, tokens, targets, variant="exact"):
+    """Mean token NLL by the reference's own layer and head."""
+    from chipbench.models import afmoe_decoder as adapter
+    from chipbench.reference import afmoe_decoder as R
+    model = R.Model(eps=m["rms_norm_eps"], window=m["sliding_window"],
+                    k=m["num_experts_per_tok"], route_scale=m["route_scale"],
+                    first=m["first_expert_held"], theta=float(m["rope_theta"]))
+    layers = R.split_layers(weights, adapter.kinds_of(m))
+    total = 0.0
+    for b in range(tokens.shape[0]):
+        x = jnp.take(weights["embed"], tokens[b], axis=0) \
+            * m["hidden_size"] ** 0.5
+        for _, kind, lp in layers:
+            x = R.layer(lp, x, kind, model, 64, variant)
+        total = total + R.head_nll(weights["ln_f"], weights["w_out"], x,
+                                   targets[b], model.eps, variant)
+    return total / tokens.size
+
+
+def test_loss_and_every_gradient_against_the_plain_reference(tiny):
+    m, cfg, weights, tokens, targets = tiny
+    assert cfg.layer_pattern == ("sliding", "full") and cfg.periods == 1
+    assert cfg.dense_layers == ("sliding",) and cfg.expert_share == (4, 4)
+    loss, grads = jax.value_and_grad(T.loss_fn)(weights, tokens, targets,
+                                                cfg)
+    want, want_grads = jax.value_and_grad(
+        lambda w: _reference_loss(m, w, tokens, targets))(weights)
+    assert abs(float(loss) - float(want)) < 2e-5 * float(want)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    ref = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    assert len(flat) == len(ref) == 3 + 14 + 19
+    for path, g in flat:
+        name = jax.tree_util.keystr(path)
+        top = float(jnp.max(jnp.abs(ref[path])))
+        if "moe_bias" in name:       # a buffer: it selects, and that is all
+            assert top == 0.0 and float(jnp.max(jnp.abs(g))) == 0.0
+            continue
+        assert top > 0.0, name
+        assert float(jnp.max(jnp.abs(g - ref[path]))) < 2e-4 * top, name
+
+
+@pytest.mark.parametrize("variant", ["no_window", "expert_missing"])
+def test_the_references_planted_faults_move_the_loss(tiny, variant):
+    m, cfg, weights, tokens, targets = tiny
+    sound = float(_reference_loss(m, weights, tokens, targets))
+    broken = float(_reference_loss(m, weights, tokens, targets, variant))
+    assert abs(broken - sound) > 1e-4 * sound
+
+
+def test_the_step_counts_its_slots_on_the_device_and_drops_none(tiny):
+    import dataclasses
+    import mxnet_tpu as mx
+    m, cfg, weights, tokens, targets = tiny
+    mesh = create_mesh(devices=jax.devices()[:1], dp=1)
+    _, step = T.make_train_step(cfg, mesh, learning_rate=0.1)
+    state = (weights, jax.tree_util.tree_map(jnp.zeros_like, weights))
+    before = mx.profiler.metrics()["moe"]
+    with mesh.mesh:
+        for _ in range(2):
+            state, loss = step(state, tokens, targets)
+    after = mx.profiler.metrics()["moe"]
+    assert after["layers"] - before["layers"] == 2 * 2   # 2 shares a step
+    assert after["slots_dropped"] == 0
+    held = after["slots_held"] - before["slots_held"]
+    assert 0 < held < 2 * 2 * tokens.size * m["num_experts_per_tok"]
+    assert onp.isfinite(float(loss))
+    # the caller's view is the dense step's: (state, loss), lower of three
+    with mesh.mesh:
+        text = step.lower(state, tokens, targets).as_text(debug_info=True)
+    for scope in ("mx.moe_route", "mx.moe_dispatch", "mx.moe_experts",
+                  "mx.moe_combine", "mx.moe_shared", "mx.flash", "mx.ffn"):
+        assert scope in text, scope
+    assert "ragged_dot" in text       # the grouped products
+    # whole periods or nothing
+    with pytest.raises(ValueError, match="whole periods"):
+        dataclasses.replace(cfg, n_layers=4).periods
+
+
+def test_the_norms_eps_is_the_configurations():
+    x = jnp.full((1, 4), 1e-3)
+    loose = T._rms_norm(x, jnp.ones(4), 1e-2)
+    tight = T._rms_norm(x, jnp.ones(4), 1e-6)
+    assert float(loose[0, 0]) == pytest.approx(1e-3 / (1e-6 + 1e-2) ** 0.5)
+    assert float(tight[0, 0]) == pytest.approx(1e-3 / (2e-6) ** 0.5)
+    assert T.TransformerConfig().norm_eps == 1e-6     # Baichuan's stays
+
+
+# The dense decoder's step at the parent commit (8ac7738), CPU: three losses,
+# the sha256 of the state after them and of the lowered program's text
+PARENT = {"losses": ["0x1.7cb0ec0000000p+2", "0x1.41f6f80000000p+2",
+                     "0x1.daa90c0000000p+1"],
+          "state": "f9467e1e4620f3524bac36bf099bee21415e08177ceb2243743d9bc9"
+                   "fa27670d",
+          "lowered": "a2ff0f9a0b8723a96f9148572bf1205bcb1665ac3b648648204cec"
+                     "c35c982f24"}
+
+
+def test_the_dense_decoders_step_is_the_parents_bit_for_bit():
+    cfg = T.TransformerConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
+                              ffn_hidden=128, max_seq_len=128,
+                              dtype="bfloat16", attn_mode="local", remat=True,
+                              loss_chunks=4)
+    mesh = create_mesh(devices=jax.devices()[:1], dp=1)
+    init, step = T.make_train_step(cfg, mesh, learning_rate=1.0)
+    with mesh.mesh:
+        state = init(jr.PRNGKey(7))
+        ids = jr.randint(jr.PRNGKey(8), (2, 129), 0, 256, jnp.int32)
+        losses = []
+        for _ in range(3):
+            state, loss = step(state, ids[:, :-1], ids[:, 1:])
+            losses.append(float(loss).hex())
+        text = step.lower(state, ids[:, :-1], ids[:, 1:]).as_text()
+    digest = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(state):
+        digest.update(onp.asarray(leaf.astype(jnp.float32)).tobytes())
+    assert losses == PARENT["losses"]
+    assert digest.hexdigest() == PARENT["state"]
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT["lowered"]

@@ -32,6 +32,7 @@ from .quantized_matmul import quantized_matmul  # noqa: F401
 KERNEL_BENCH = {
     "flash_attention": "transformer",
     "grouped_matmul": "transformer",
+    "moe_rows": "transformer",
     "compression": "comm_overlap",
     "conv_fused": "resnet50",
     "batchnorm_fused": "fused_kernels",

@@ -17,6 +17,14 @@ grid steps that do nothing: their index maps point at the last tile in use,
 so they move no data, and their rows of the output are never written. A
 caller reads only the rows it laid out.
 
+The invariant a caller and these kernels share (``parallel/expert.py _plan``
+lays the rows out, ``pallas_kernels/moe_rows.py`` fills and reads them): the
+tiles in use are a prefix, ``used`` = sum(sizes) / TILE of them. Rows behind
+it hold nothing: no kernel here reads or writes them, and whatever they hold
+(it need not be finite) reaches no row in use. The padding rows of a tile in
+use ARE read (``mx_gmm_dw`` multiplies them by a ``dy`` of nought) and so
+must be finite.
+
 XLA's own lowering of ``jax.lax.ragged_dot`` on this chip is a kernel of the
 same family with tiles of 512 x 512 x 512; it runs at 46% of the grouped
 product's roofline at the Trinity cell's shapes and drops the ``mx.*`` scope

@@ -22,6 +22,7 @@ layer's.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -114,7 +115,9 @@ class MoELayer:
 
 # -- one chip's share of an expert-parallel layer ----------------------------
 
-MOE_STATS = ("layers", "slots_held", "slots_dropped", "max_load")
+MOE_STATS = ("layers", "slots_held", "slots_dropped", "max_load",
+             "rows_live")
+_LARGEST = tuple(n == "max_load" for n in MOE_STATS)   # the rest are sums
 
 
 def route_sigmoid(h, router_w, bias, k, route_scale=1.0):
@@ -132,6 +135,28 @@ def route_sigmoid(h, router_w, bias, k, route_scale=1.0):
     return experts, weights * route_scale
 
 
+def buffer_rows(tokens, k, n_held, tile):
+    """Rows of a share's slot buffer: every slot there is, and a tile of
+    padding an expert held."""
+    return -(-tokens * k // tile) * tile + n_held * tile
+
+
+class _Plan(NamedTuple):
+    """Where each token-slot goes (``_plan``). held, row_of: [T, k];
+    slot_of, live: [rows]; sizes: [n_held]; group_of: [rows / tile]; used:
+    [1]; lists, fetched: ``moe_rows.tile_lists`` (None off the kernels)."""
+    held: jax.Array
+    row_of: jax.Array
+    slot_of: jax.Array
+    live: jax.Array
+    counts: jax.Array
+    sizes: jax.Array
+    group_of: jax.Array
+    used: jax.Array
+    lists: Optional[jax.Array] = None
+    fetched: Optional[jax.Array] = None
+
+
 def _plan(experts, first, n_held, tile):
     """Where each token-slot goes. experts: [T, k] over all E. The slots of
     the experts held ([first, first + n_held)) are sorted by expert and laid
@@ -139,14 +164,21 @@ def _plan(experts, first, n_held, tile):
     whole tiles of ``tile`` rows (and at least one: ``grouped_matmul``'s
     layout). The buffer has a row for every slot there is, so nothing is
     dropped whatever the imbalance; the slots of absent experts get no row.
-    -> (held [T, k] bool, row_of [T, k]: a held slot's row (0 for the
-        others), slot_of [rows]: the slot in each row (0 where ``live``
+
+    What a step's routing fills is a PREFIX of the buffer: ``used`` =
+    sum(sizes) / tile tiles. That is all a step touches. Rows behind it
+    hold nothing and are neither read nor written, by the row movements
+    here as by the grouped products; the padding rows of the tiles in use
+    are read (a group's product runs over whole tiles) and are finite: they
+    hold token 0's row on the way in and nought on the way back.
+    -> ``_Plan``: held [T, k] bool, row_of [T, k]: a held slot's row (0 for
+        the others), slot_of [rows]: the slot in each row (0 where ``live``
         [rows] is false: padding, or behind the last group), counts and
         sizes [n_held]: each group's slots and rows, group_of [rows / tile]:
-        each tile's expert)."""
+        each tile's expert, used [1]: the tiles in use."""
     T, k = experts.shape
     whole = T * k
-    rows = -(-whole // tile) * tile + n_held * tile
+    rows = buffer_rows(T, k, n_held, tile)
     local = experts - first
     held = (local >= 0) & (local < n_held)
     key = jnp.where(held, local, n_held).reshape(whole).astype(jnp.int32)
@@ -168,65 +200,88 @@ def _plan(experts, first, n_held, tile):
     live = rank < counts[group]
     slot_of = jnp.where(live, order[jnp.clip(sorted_from[group] + rank, 0,
                                              whole - 1)], 0)
-    return (held, row_of.reshape(T, k), slot_of, live, counts, sizes,
-            group[::tile])
+    return _Plan(held, row_of.reshape(T, k), slot_of, live, counts, sizes,
+                 group[::tile],
+                 (jnp.sum(sizes, dtype=jnp.int32) // tile).reshape(1))
 
 
-def _slot_rows(table, row_of, held, j):
-    """table[row_of[:, j]] [T, D] in float32, nought where slot j is not
-    ``held``. The sums over a token's k slots below take k such gathers of
-    T rows and add as they go: one gather of T*k rows would lay a [T, k, D]
-    array down first, and read 16.9 -> 33.0 ms a step in the Trinity cell's
-    combine backward (PERF.md, PR 30)."""
-    rows = jnp.take(table, row_of[:, j], axis=0)
-    return jnp.where(held[:, j, None], rows, 0).astype(jnp.float32)
+# The row movements. ``how`` is None for the plain ``jnp.take`` forms (off
+# the TPU, and what the tests hold the kernels to) or (tile, interpret) for
+# the ``mx_moe_*`` kernels (``pallas_kernels/moe_rows.py``), which move and
+# sum the rows of the slots held and no others.
+
+def _slot_sums(table, weights, plan, how, dtype):
+    """[T, D]: sum over a token's held slots j = 0 .. k-1, in that order and
+    in float32, of weights[t, j] * table[row_of[t, j]]."""
+    from ..pallas_kernels import moe_rows as _rows
+    if how is None:
+        return _rows.sum_rows_reference(table, plan.row_of, plan.held,
+                                        weights, dtype)
+    tile, interpret = how
+    packed = _rows.pack_rows(table, plan.used, tile, interpret)
+    return _rows.sum_rows(packed, plan.lists, plan.fetched,
+                          jnp.where(plan.held, weights, 0), table.shape[1],
+                          dtype, interpret=interpret)
 
 
-@jax.custom_vjp
-def _dispatch(x, slot_of, row_of, held, k):
+def _token_rows(x, plan, how, scale=None, other=None):
+    """[rows, D]: each buffer row's token's row of ``x`` [T, D] (times
+    ``scale`` [rows]); with ``other`` [rows, D] also its dot with that
+    row, [rows] float32."""
+    from ..pallas_kernels import moe_rows as _rows
+    token = plan.slot_of // plan.row_of.shape[1]
+    if how is None:
+        return _rows.gather_rows_reference(x, token, scale, other)
+    tile, interpret = how
+    every = jnp.full((1,), -(-x.shape[0] // tile), jnp.int32)
+    packed = _rows.pack_rows(x, every, tile, interpret)
+    return _rows.gather_rows(packed, token, plan.used, tile, x.shape[1],
+                             x.dtype, scale, other, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dispatch(x, plan, how):
     """Rows of ``x`` [T, D] in slot order [rows, D]: a gather. Its transpose
     is a gather too: a token's gradient is the sum over its own slots'
     rows, so no scatter runs in either direction."""
-    return jnp.take(x, slot_of // k, axis=0)
+    return _token_rows(x, plan, how)
 
 
-def _dispatch_fwd(x, slot_of, row_of, held, k):
-    return _dispatch(x, slot_of, row_of, held, k), (row_of, held)
+def _dispatch_fwd(x, plan, how):
+    return _dispatch(x, plan, how), plan
 
 
-def _dispatch_bwd(res, g):
-    row_of, held = res
-    dx = sum(_slot_rows(g, row_of, held, j) for j in range(row_of.shape[1]))
-    return dx.astype(g.dtype), None, None, None, None
+def _dispatch_bwd(how, plan, g):
+    ones = jnp.ones(plan.held.shape, jnp.float32)
+    return _slot_sums(g, ones, plan, how, g.dtype), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _combine(ys, weights, slot_of, row_of, held, live, dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(ys, weights, plan, how, dtype):
     """y[t] = sum over the token's held slots of weight * ys[row]: a gather
     by ``row_of``. Rows that are not ``live`` (padding and what lies behind
     the last group, where no product ran) are never read."""
-    return sum(_slot_rows(ys, row_of, held, j) * weights[:, j, None]
-               for j in range(row_of.shape[1])).astype(dtype)
+    return _slot_sums(ys, weights, plan, how, dtype)
 
 
-def _combine_fwd(ys, weights, slot_of, row_of, held, live, dtype):
-    return (_combine(ys, weights, slot_of, row_of, held, live, dtype),
-            (ys, weights, slot_of, row_of, held, live))
+def _combine_fwd(ys, weights, plan, how, dtype):
+    return _combine(ys, weights, plan, how, dtype), (ys, weights, plan)
 
 
-def _combine_bwd(dtype, res, g):
-    ys, weights, slot_of, row_of, held, live = res
-    k = weights.shape[1]
-    g32 = g.astype(jnp.float32)
-    dw = jnp.stack([jnp.sum(_slot_rows(ys, row_of, held, j) * g32, axis=-1)
-                    for j in range(k)], axis=1)
-    w_row = jnp.take(weights.reshape(-1), slot_of)           # [rows]
-    dys = jnp.where(live[:, None],
-                    jnp.take(g, slot_of // k, axis=0) * w_row[:, None], 0)
-    return dys.astype(ys.dtype), dw, None, None, None, None
+def _combine_bwd(how, dtype, res, g):
+    """dys[row] = weight of the row's slot * g[its token] (nought on
+    padding); dw[t, j] = ys[row] . g[t], taken where g[t] already lies
+    beside ys[row], in slot order, and carried back as a gather of
+    scalars."""
+    ys, weights, plan = res
+    w_row = jnp.where(plan.live, jnp.take(weights.reshape(-1), plan.slot_of),
+                      0)
+    dys, dots = _token_rows(g, plan, how, w_row, ys)
+    dw = jnp.where(plan.held, jnp.take(dots, plan.row_of), 0)
+    return dys, dw.astype(weights.dtype), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -247,38 +302,60 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     w_down: [held, F, D]; ``shared``: (w_gate [D, Fs], w_up, w_down
     [Fs, D]) of the shared expert, computed for every token.
     -> (y [B, S, D]: shared(x) + sum over the chosen experts HELD of weight
-    * expert(x), int32 [4] as ``MOE_STATS`` names them: 1, slots routed to
+    * expert(x), int32 [5] as ``MOE_STATS`` names them: 1, slots routed to
     experts held, those of them that no product covered (0: the buffer
-    holds every slot), the largest load of an expert held)."""
+    holds every slot), the largest load of an expert held, the buffer rows
+    in use (the slots held and their groups' padding to whole tiles)).
+
+    The buffer is sized for every slot there is; a step touches the prefix
+    its routing fills (``_plan``) and a token's held slots only, so the
+    time around the products follows the slots held, as theirs does. On the
+    TPU (and under ``interpret``) the rows move through the ``mx_moe_*``
+    kernels; elsewhere, or where a row is not whole tiles
+    (``moe_rows.fits``), through ``jnp.take``."""
     from ..pallas_kernels import grouped_matmul as _gmm
+    from ..pallas_kernels import moe_rows as _rows
     B, S, D = x.shape
     T, n_held = B * S, w_gate.shape[0]
     xt = x.reshape(T, D)
+    on_tpu = jax.default_backend() == "tpu"
+    how = (_gmm.TILE, bool(interpret)) if (interpret or on_tpu) and \
+        _rows.fits(D, x.dtype, on_tpu and not interpret) else None
     with jax.named_scope("mx.moe_route"):
         experts, weights = route_sigmoid(xt, router_w, bias, k, route_scale)
     with jax.named_scope("mx.moe_dispatch"):
-        held, row_of, slot_of, live, counts, sizes, group_of = _plan(
-            experts, first, n_held, _gmm.TILE)
-        xs = _dispatch(xt, slot_of, row_of, held, k)
+        plan = _plan(experts, first, n_held, _gmm.TILE)
+        if how is not None:
+            lists, fetched = _rows.tile_lists(plan.row_of, plan.held,
+                                              plan.slot_of.shape[0])
+            plan = plan._replace(lists=lists, fetched=fetched)
+        xs = _dispatch(xt, plan, how)
     with jax.named_scope("mx.moe_experts"):
         ys = _gated(xs, w_gate, w_up, w_down,
-                    lambda a, w: _gmm.grouped_matmul(a, w, sizes, group_of,
-                                                     interpret))
+                    lambda a, w: _gmm.grouped_matmul(
+                        a, w, plan.sizes, plan.group_of, interpret))
     with jax.named_scope("mx.moe_combine"):
-        y = _combine(ys, weights, slot_of, row_of, held, live, x.dtype)
+        y = _combine(ys, weights, plan, how, x.dtype)
     if shared is not None:
         with jax.named_scope("mx.moe_shared"):
             y = y + _gated(xt, *shared, jnp.dot)
-    n_held_slots = jnp.sum(held, dtype=jnp.int32)
+    n_held_slots = jnp.sum(plan.held, dtype=jnp.int32)
     stats = jnp.stack([jnp.int32(1), n_held_slots,
-                       n_held_slots - jnp.sum(live, dtype=jnp.int32),
-                       jnp.max(counts)])
+                       n_held_slots - jnp.sum(plan.live, dtype=jnp.int32),
+                       jnp.max(plan.counts),
+                       jnp.sum(plan.sizes, dtype=jnp.int32)])
     return y.reshape(B, S, D), stats
 
 
 def merge_stats(a, b):
     """Two layers' (or steps') ``MOE_STATS``: sums, and the larger load."""
-    return jnp.concatenate([a[:3] + b[:3], jnp.maximum(a[3:], b[3:])])
+    return jnp.where(jnp.array(_LARGEST), jnp.maximum(a, b), a + b)
+
+
+def sum_stats(stats):
+    """[n, 5] ``MOE_STATS`` of n calls as one: sums, and the largest load."""
+    return jnp.where(jnp.array(_LARGEST), jnp.max(stats, axis=0),
+                     jnp.sum(stats, axis=0))
 
 
 # metrics()["moe"]: the counters of the train steps that carry them, kept
@@ -288,7 +365,9 @@ _LIVE_COUNTERS = []  # mxlint: disable=MX003 (weak references appended when a st
 
 def track(holder):
     """``holder.moe_counters`` (a device array or None) is summed into
-    ``metrics()['moe']`` for as long as ``holder`` lives."""
+    ``metrics()['moe']`` for as long as ``holder`` lives;
+    ``holder.moe_held`` is the experts it holds and ``holder.moe_rows`` the
+    rows of its layers' buffer."""
     import weakref
     _LIVE_COUNTERS.append(weakref.ref(holder))
 
@@ -297,22 +376,26 @@ def moe_stats():
     """``metrics()['moe']``: over every live train step with an expert share:
     ``layers`` (expert-layer calls), ``slots_held`` (token-slots routed to
     experts held), ``slots_dropped`` (must read 0), ``max_load`` (the most
-    slots one held expert got in one call) and ``mean_load``."""
+    slots one held expert got in one call), ``rows_live`` (the buffer rows
+    the steps' routing put in use: all that the row movements and the
+    products touch), ``mean_load`` and ``live_share`` (``rows_live`` over
+    the rows the layers' buffers have)."""
     import numpy as np
     total = np.zeros(len(MOE_STATS), np.int64)
-    experts = 0
+    experts = rows = 0
     for ref in list(_LIVE_COUNTERS):
         holder = ref()
         if holder is None:
             _LIVE_COUNTERS.remove(ref)
         elif holder.moe_counters is not None:
             got = np.asarray(jax.device_get(holder.moe_counters), np.int64)
-            total[:3] += got[:3]
-            total[3] = max(total[3], got[3])
+            total = np.where(_LARGEST, np.maximum(total, got), total + got)
             experts = max(experts, holder.moe_held)
+            rows += int(got[0]) * holder.moe_rows
     out = {n: int(v) for n, v in zip(MOE_STATS, total)}
     out["mean_load"] = (out["slots_held"] / (out["layers"] * experts)
                         if out["layers"] and experts else 0.0)
+    out["live_share"] = out["rows_live"] / rows if rows else 0.0
     return out
 
 

@@ -545,8 +545,7 @@ def _hidden_pattern(params, x, positions, cfg, mesh):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     if not counted:
         return x, 0.0
-    return x, jnp.concatenate([jnp.sum(stats[:, :3], axis=0),
-                               jnp.max(stats[:, 3:], axis=0)])
+    return x, _expert.sum_stats(stats)
 
 
 def _chunked_ce(x, w_out, targets, n_chunks):
@@ -928,6 +927,8 @@ class _CountedStep:
         everywhere = NamedSharding(raw_mesh, P())
         self.moe_held = cfg.expert_share[1]
         self.moe_counters = None
+        self.moe_rows = 0       # of a layer's slot buffer: set by a call
+        self._moe_k = cfg.moe_k
         self._everywhere = everywhere
 
         @functools.partial(
@@ -957,6 +958,9 @@ class _CountedStep:
         return self.moe_counters
 
     def __call__(self, state, tokens, targets):
+        from ..pallas_kernels.grouped_matmul import TILE
+        self.moe_rows = _expert.buffer_rows(tokens.size, self._moe_k,
+                                            self.moe_held, TILE)
         state, loss, self.moe_counters = self._jitted(
             state, tokens, targets, self._counters())
         return state, loss

@@ -138,3 +138,37 @@ def test_the_grouped_products_lower_at_the_trinity_cells_shape(monkeypatch):
     assert [a.shape for a in exported.out_avals] == [
         (rows, 1024), x.shape, w.shape]
     assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == 3
+
+
+def test_the_expert_shares_row_movements_lower_at_the_trinity_cells_shape():
+    """16384 tokens x 8 slots of width 2048 in bfloat16, 32 experts held:
+    dispatch, combine and both transposes through ``mx_moe_pack``,
+    ``mx_moe_gather`` and ``mx_moe_sum``: a pack and a movement each."""
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel import expert as X
+    from mxnet_tpu.pallas_kernels import moe_rows
+    T, k, D, held, tile = 16384, 8, 2048, 32, 256
+    rows = X.buffer_rows(T, k, held, tile)
+    assert rows == 139264 and moe_rows.fits(D, jnp.bfloat16, True)
+    how = (tile, False)
+
+    def movements(experts, x, ys, w, g_rows, g_tokens):
+        plan = X._plan(experts, 0, held, tile)
+        lists, fetched = moe_rows.tile_lists(plan.row_of, plan.held, rows)
+        plan = plan._replace(lists=lists, fetched=fetched)
+        xs, back = jax.vjp(lambda x: X._dispatch(x, plan, how), x)
+        y, back_y = jax.vjp(lambda ys, w: X._combine(
+            ys, w, plan, how, jnp.bfloat16), ys, w)
+        return (xs, y) + back(g_rows) + back_y(g_tokens)
+
+    s = jax.ShapeDtypeStruct
+    exported = jax.export.export(jax.jit(movements), platforms=["tpu"])(
+        s((T, k), jnp.int32), s((T, D), jnp.bfloat16),
+        s((rows, D), jnp.bfloat16), s((T, k), jnp.float32),
+        s((rows, D), jnp.bfloat16), s((T, D), jnp.bfloat16))
+    assert [a.shape for a in exported.out_avals] == [
+        (rows, D), (T, D), (T, D), (rows, D), (T, k)]
+    text = exported.mlir_module()
+    assert text.count(chip_smoke.MOSAIC_CALL) == 8
+    for name in ("mx_moe_pack", "mx_moe_gather", "mx_moe_sum"):
+        assert name in text, name

@@ -173,55 +173,20 @@ def _rope(x, positions):
     return rot.astype(x.dtype)
 
 
-def init_params(key, cfg: TransformerConfig):
-    """Param pytree. Layer params are STACKED on a leading axis: [L, ...]
-    in GSPMD mode, [pp, L/pp, ...] in explicit pipeline mode — the leading
-    axis is scanned (one compiled layer body) and, for pp, mesh-sharded."""
-    dt = jnp.dtype(cfg.dtype)
-    D, H, Dh, F = cfg.dim, cfg.n_heads, cfg.head_dim, cfg.ffn_hidden
-    L = cfg.n_layers
-    keys = jr.split(key, 8)
-
-    def norm(k, shape, fan_in):
-        return (jr.normal(k, shape) * (fan_in ** -0.5)).astype(dt)
-
-    if cfg.layer_pattern:
-        return _init_pattern_params(key, cfg, norm)
-
-    layer = {
-        "ln1": jnp.ones((L, D), dt),
-        "wq": norm(keys[0], (L, D, H, Dh), D),
-        "wk": norm(keys[1], (L, D, H, Dh), D),
-        "wv": norm(keys[2], (L, D, H, Dh), D),
-        "wo": norm(keys[3], (L, H, Dh, D), H * Dh),
-        "ln2": jnp.ones((L, D), dt),
-        "w_gate": norm(keys[4], (L, D, F), D),
-        "w_up": norm(keys[5], (L, D, F), D),
-        "w_down": norm(keys[6], (L, F, D), F),
-    }
-    if cfg.num_experts > 0:
-        E = cfg.num_experts
-        ek = jr.split(keys[7], 4)
-        layer["moe_router"] = norm(ek[0], (L, D, E), D)
-        layer["moe_w1"] = norm(ek[1], (L, E, D, F), D)
-        layer["moe_w2"] = norm(ek[2], (L, E, F, D), F)
-    if cfg.pp > 1:
-        assert L % cfg.pp == 0, "n_layers must divide pp"
-        layer = {k: v.reshape((cfg.pp, L // cfg.pp) + v.shape[1:])
-                 for k, v in layer.items()}
-    emb_key, out_key = jr.split(jr.fold_in(key, 99))
-    return {
-        "embed": norm(emb_key, (cfg.vocab_size, D), D) * (D ** 0.5),
-        "layers": layer,
-        "ln_f": jnp.ones((D,), dt),
-        "w_out": norm(out_key, (D, cfg.vocab_size), D),
-    }
+def _ffn_kind(cfg, experts=True):
+    """The feed-forward of a scanned layer (``experts``) or of a leading
+    dense one: "share" (one chip's share of an expert layer), "gshard" (the
+    experts over the 'ep' axis) or "gated"."""
+    if not experts or cfg.num_experts <= 0:
+        return "gated"
+    return "gshard" if cfg.moe_hidden is None else "share"
 
 
-def _pattern_leaves(cfg, experts):
+def _layer_leaves(cfg, experts=True):
     """{leaf: (shape of one layer, fan_in or None for a norm's scale or 0
-    for the router's bias, spec of one layer)} of a pattern model's dense
-    (``experts`` False) or scanned layer."""
+    for the router's bias, spec of one layer)}: the one table of a layer's
+    leaves, scanned (``experts``) or leading dense. The plain decoder's
+    nine are the rows that no option adds."""
     D, H, G, Dh = cfg.dim, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     out = {"ln1": ((D,), None, (None,)),
            "wq": ((D, H, Dh), D, (None, "tp", None)),
@@ -235,14 +200,19 @@ def _pattern_leaves(cfg, experts):
         out["q_norm"] = out["k_norm"] = ((Dh,), None, (None,))
     if cfg.post_norms:
         out["ln1_post"] = out["ln2_post"] = ((D,), None, (None,))
-    share = cfg.expert_share if experts else None
-    if share is None:
+    ffn = _ffn_kind(cfg, experts)
+    if ffn != "share":
         F = cfg.ffn_hidden
         out.update({"w_gate": ((D, F), D, (None, "tp")),
                     "w_up": ((D, F), D, (None, "tp")),
                     "w_down": ((F, D), F, ("tp", None))})
+        if ffn == "gshard":   # beside the gated leaves, which it leaves idle
+            E = cfg.num_experts
+            out.update({"moe_router": ((D, E), D, (None, None)),
+                        "moe_w1": ((E, D, F), D, ("ep", None, "tp")),
+                        "moe_w2": ((E, F, D), F, ("ep", "tp", None))})
         return out
-    E, Fm, held = cfg.num_experts, cfg.moe_hidden, share[1]
+    E, Fm, held = cfg.num_experts, cfg.moe_hidden, cfg.expert_share[1]
     out.update({"moe_router": ((D, E), D, (None, None)),
                 "moe_bias": ((E,), 0, (None,)),
                 "moe_w_gate": ((held, D, Fm), D, (None, None, None)),
@@ -256,87 +226,88 @@ def _pattern_leaves(cfg, experts):
     return out
 
 
-def _init_pattern_params(key, cfg, norm):
-    """Params of a pattern model: ``dense`` stacked [n_dense, ...] (where
-    there are leading dense layers) and ``layers`` stacked [periods, P,
-    ...], one row a period and one column a place in the pattern."""
+def _stacks(cfg):
+    """{stack of layers in the param tree: (shape of its leading axes, their
+    spec, whether its layers are the scanned ones)}. ``layers`` is [L, ...]
+    for the plain decoder ([pp, L/pp, ...] in explicit pipeline mode) and
+    [periods, P, ...] for a pattern model, one row a period and one column
+    a place in the pattern, with its leading dense layers [n, ...] beside."""
+    if cfg.layer_pattern:
+        out = {"layers": ((cfg.periods, len(cfg.layer_pattern)),
+                          (None, None), True)}
+        if cfg.dense_layers:
+            out["dense"] = ((len(cfg.dense_layers),), (None,), False)
+        return out
+    if cfg.pp > 1:
+        assert cfg.n_layers % cfg.pp == 0, "n_layers must divide pp"
+        return {"layers": ((cfg.pp, cfg.n_layers // cfg.pp), ("pp", None),
+                           True)}
+    return {"layers": ((cfg.n_layers,), (None,), True)}
+
+
+def init_params(key, cfg: TransformerConfig):
+    """Param pytree. Layer params are STACKED on leading axes (``_stacks``);
+    the first is scanned (one compiled layer body) and, for pp, mesh-sharded.
+    Every leaf of a layer comes from ``_layer_leaves``."""
     dt = jnp.dtype(cfg.dtype)
     D = cfg.dim
 
-    def stack(lead, leaves, salt):
+    def norm(k, shape, fan_in):
+        return (jr.normal(k, shape) * (fan_in ** -0.5)).astype(dt)
+
+    def keys_of(leaves, salt):
+        keys = {n: jr.fold_in(key, salt + i) for i, n in enumerate(leaves)}
+        if not cfg.layer_pattern:
+            # the plain decoder's seeded states were made from split keys:
+            # one a matrix by its place, the GShard leaves' from the eighth
+            ks = jr.split(key, 8)
+            keys.update(zip(
+                ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                 "moe_router", "moe_w1", "moe_w2"),
+                [*ks[:7], *jr.split(ks[7], 4)]))
+        return keys
+
+    def stack(lead, experts, salt):
+        leaves = _layer_leaves(cfg, experts)
+        keys = keys_of(leaves, salt)
         out = {}
-        for i, (name, (shape, fan_in, _)) in enumerate(leaves.items()):
-            k = jr.fold_in(key, salt + i)
+        for name, (shape, fan_in, _) in leaves.items():
             if fan_in is None:
                 out[name] = jnp.ones(lead + shape, dt)
             elif fan_in == 0:   # the router's bias: a buffer, N(0, 0.01^2)
-                out[name] = (jr.normal(k, lead + shape) * 0.01).astype(dt)
+                out[name] = (jr.normal(keys[name], lead + shape)
+                             * 0.01).astype(dt)
             else:
-                out[name] = norm(k, lead + shape, fan_in)
+                out[name] = norm(keys[name], lead + shape, fan_in)
         return out
 
     emb_key, out_key = jr.split(jr.fold_in(key, 99))
     embed = norm(emb_key, (cfg.vocab_size, D), D)
     params = {
         "embed": embed if cfg.embed_scale else embed * (D ** 0.5),
-        "layers": stack((cfg.periods, len(cfg.layer_pattern)),
-                        _pattern_leaves(cfg, True), 1000),
         "ln_f": jnp.ones((D,), dt),
         "w_out": norm(out_key, (D, cfg.vocab_size), D),
     }
-    if cfg.dense_layers:
-        params["dense"] = stack((len(cfg.dense_layers),),
-                                _pattern_leaves(cfg, False), 2000)
+    for i, (name, (lead, _, experts)) in enumerate(_stacks(cfg).items()):
+        params[name] = stack(lead, experts, 1000 * (i + 1))  # layers, dense
     return params
 
 
 def param_specs(cfg: TransformerConfig):
-    """PartitionSpecs matching init_params structure (GSPMD mode).
-    Column-parallel on heads/ffn over 'tp'; fsdp composes by sharding the
-    layer-stack axis? No — fsdp shards the largest non-tp dim via
-    sharding.fsdp rules; here we give the Megatron TP layout."""
-    if cfg.layer_pattern:
-        of = lambda lead, experts: {  # noqa: E731
-            n: P(*(lead + spec))
-            for n, (_, _, spec) in _pattern_leaves(cfg, experts).items()}
-        specs = {"embed": P("tp", None), "layers": of((None, None), True),
-                 "ln_f": P(None), "w_out": P(None, "tp")}
-        if cfg.dense_layers:
-            specs["dense"] = of((None,), False)
-        return specs
-    lead = ("pp",) if cfg.pp > 1 else (None,)
-    lead = lead + ((None,) if cfg.pp > 1 else ())
-
-    def ls(*rest):  # layer-stacked spec
-        return P(*(lead + rest))
-
-    layer = {
-        "ln1": ls(None),
-        "wq": ls(None, "tp", None),
-        "wk": ls(None, "tp", None),
-        "wv": ls(None, "tp", None),
-        "wo": ls("tp", None, None),
-        "ln2": ls(None),
-        "w_gate": ls(None, "tp"),
-        "w_up": ls(None, "tp"),
-        "w_down": ls("tp", None),
-    }
-    if cfg.num_experts > 0:
-        layer["moe_router"] = ls(None, None)
-        layer["moe_w1"] = ls("ep", None, "tp")
-        layer["moe_w2"] = ls("ep", "tp", None)
+    """PartitionSpecs matching init_params structure: the Megatron TP
+    layout, column-parallel on heads/ffn over 'tp' (fsdp composes through
+    sharding.fsdp's rules, not here)."""
     if cfg.pp > 1:
         # explicit mode indexes embed/w_out with global token ids inside the
         # shard_map body, so they stay replicated across tp
-        embed_spec, out_spec = P(None, None), P(None, None)
+        specs = {"embed": P(None, None), "w_out": P(None, None)}
     else:
-        embed_spec, out_spec = P("tp", None), P(None, "tp")
-    return {
-        "embed": embed_spec,
-        "layers": layer,
-        "ln_f": P(None),
-        "w_out": out_spec,
-    }
+        specs = {"embed": P("tp", None), "w_out": P(None, "tp")}
+    specs["ln_f"] = P(None)
+    for name, (_, lead, experts) in _stacks(cfg).items():
+        specs[name] = {n: P(*(lead + spec)) for n, (_, _, spec)
+                       in _layer_leaves(cfg, experts).items()}
+    return specs
 
 
 # --------------------------------------------------------------------------
@@ -413,11 +384,33 @@ def _mesh_sizes(mesh):
             dict(getattr(mesh, "mesh", mesh).shape).items()}
 
 
-def _layer_body(cfg, mesh, positions, x, lp, kind="full"):
-    """One transformer layer. x: [B, S, D]; lp: this layer's params, which
-    say what its feed-forward is (dense, the GShard experts, or a share of
-    an expert layer); ``kind``: its attention, "full" or "sliding".
-    -> (x, aux): the GShard load-balance loss, or the share's counters."""
+def _ffn(cfg, lp, h, experts):
+    """The one place that chooses a layer's feed-forward (``_ffn_kind``).
+    h: [B, S, D] -> (y, the counters of an expert share (``MOE_STATS``) or
+    None, the GShard load-balance loss or None)."""
+    ffn = _ffn_kind(cfg, experts)
+    if ffn == "share":
+        shared = (lp["ws_gate"], lp["ws_up"], lp["ws_down"]) \
+            if cfg.moe_shared else None
+        y, stats = _expert.moe_share(
+            h, lp["moe_router"], lp["moe_bias"], lp["moe_w_gate"],
+            lp["moe_w_up"], lp["moe_w_down"], shared, k=cfg.moe_k,
+            first=cfg.expert_share[0], route_scale=cfg.route_scale)
+        return y, stats, None
+    if ffn == "gshard":
+        y, balance = moe_ffn(h, lp["moe_router"], lp["moe_w1"], lp["moe_w2"],
+                             k=cfg.moe_k)
+        return y, None, balance
+    g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
+    u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
+    prod = _ckpt_name(g * u, "ffn_prod")
+    return jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), None, None
+
+
+def _layer_body(cfg, mesh, positions, x, lp, kind="full", experts=True):
+    """One transformer layer. x: [B, S, D]; lp: this layer's params
+    (``_layer_leaves(cfg, experts)``); ``kind``: its attention, "full" or
+    "sliding". -> (x, counters or None, balance loss or None) as ``_ffn``."""
     eps, sliding = cfg.norm_eps, kind == "sliding"
     with jax.named_scope("mx.attn_proj"):
         h = _rms_norm(x, lp["ln1"], eps)
@@ -445,37 +438,23 @@ def _layer_body(cfg, mesh, positions, x, lp, kind="full"):
             a = _rms_norm(a, lp["ln1_post"], eps)
         x = x + a
     with jax.named_scope("mx.ffn"):
-        h = _rms_norm(x, lp["ln2"], eps)
-        if "moe_w_gate" in lp:
-            shared = (lp["ws_gate"], lp["ws_up"], lp["ws_down"]) \
-                if "ws_gate" in lp else None
-            y, aux = _expert.moe_share(
-                h, lp["moe_router"], lp["moe_bias"], lp["moe_w_gate"],
-                lp["moe_w_up"], lp["moe_w_down"], shared, k=cfg.moe_k,
-                first=cfg.expert_share[0], route_scale=cfg.route_scale)
-        elif cfg.num_experts > 0 and cfg.moe_hidden is None:
-            y, aux = moe_ffn(h, lp["moe_router"], lp["moe_w1"],
-                             lp["moe_w2"], k=cfg.moe_k)
-            return x + y, aux
-        else:
-            g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
-            u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
-            prod = _ckpt_name(g * u, "ffn_prod")
-            y, aux = jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), 0.0
+        y, stats, balance = _ffn(cfg, lp, _rms_norm(x, lp["ln2"], eps),
+                                 experts)
         if cfg.post_norms:
             y = _rms_norm(y, lp["ln2_post"], eps)
-        return x + y, aux
+        return x + y, stats, balance
 
 
 def apply(params, tokens, cfg: TransformerConfig, mesh=None,
           return_aux=False):
     """Forward: tokens [B, S] int32 -> logits [B, S, V]. GSPMD mode.
-    With return_aux, also returns the summed MoE load-balance loss."""
-    x, aux = _hidden(params, tokens, cfg, mesh)
+    With return_aux, also returns the summed MoE load-balance loss (0.0
+    where no layer has one)."""
+    x, _, balance = _hidden(params, tokens, cfg, mesh)
     with jax.named_scope("mx.head_ce"):
         logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
     if return_aux:
-        return logits, aux
+        return logits, 0.0 if balance is None else balance
     return logits
 
 
@@ -489,41 +468,24 @@ def _remat_policy(cfg):
 
 
 def _hidden(params, tokens, cfg, mesh):
-    """Trunk forward up to (but excluding) the output projection;
-    returns (x [B,S,D], summed aux)."""
+    """The trunk up to (but excluding) the output projection: embed, the
+    leading dense layers where the tree has them, a scan over
+    ``params["layers"]`` whose body runs one period's layers in turn, each
+    under the layer remat, and the final norm. The plain decoder is the
+    case of one "full" layer a period: the scanned slice is that layer's
+    leaves. -> (x [B, S, D], the expert shares' counters summed or None,
+    the GShard layers' balance loss summed or None)."""
     with jax.named_scope("mx.embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
         if cfg.embed_scale:
             x = (x * (cfg.dim ** 0.5)).astype(x.dtype)
     positions = jnp.arange(tokens.shape[1])
-    if cfg.layer_pattern:
-        return _hidden_pattern(params, x, positions, cfg, mesh)
+    zero = jnp.zeros(len(_expert.MOE_STATS), jnp.int32) \
+        if _ffn_kind(cfg) == "share" else None
 
-    def body(x, lp):
-        x, aux = _layer_body(cfg, mesh, positions, x, lp)
-        return x, aux
-
-    if cfg.remat:
-        body = jax.checkpoint(body, policy=_remat_policy(cfg))
-    with jax.named_scope("mx.layer"):
-        x, auxs = lax.scan(body, x, params["layers"])
-    with jax.named_scope("mx.head_ce"):
-        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-    return x, jnp.sum(auxs)
-
-
-def _hidden_pattern(params, x, positions, cfg, mesh):
-    """The trunk of a pattern model: the leading dense layers, then a scan
-    over whole periods whose body holds the period's layers in turn, each
-    under the layer remat. -> (x, the expert shares' counters summed, or
-    0.0 where no layer is a share)."""
-    counted = cfg.expert_share is not None
-    zero = jnp.zeros(len(_expert.MOE_STATS), jnp.int32)
-
-    def layer(kind):
-        def one(x, lp):
-            x, aux = _layer_body(cfg, mesh, positions, x, lp, kind)
-            return x, (aux if counted and "moe_w_gate" in lp else zero)
+    def layer(kind, experts=True):
+        one = functools.partial(_layer_body, cfg, mesh, positions, kind=kind,
+                                experts=experts)
         return jax.checkpoint(one, policy=_remat_policy(cfg)) \
             if cfg.remat else one
 
@@ -531,21 +493,24 @@ def _hidden_pattern(params, x, positions, cfg, mesh):
         lambda a: a[i], tree)
 
     def period(x, lp):
-        stats = zero
-        for j, kind in enumerate(cfg.layer_pattern):
-            x, one = layer(kind)(x, at(lp, j))
-            stats = _expert.merge_stats(stats, one)
-        return x, stats
+        stats, balance = zero, None
+        for j, kind in enumerate(cfg.layer_pattern or ("full",)):
+            x, one, b = layer(kind)(x, at(lp, j) if cfg.layer_pattern else lp)
+            if one is not None:
+                stats = _expert.merge_stats(stats, one)
+            if b is not None:
+                balance = b if balance is None else balance + b
+        return x, (stats, balance)
 
     with jax.named_scope("mx.layer"):
-        for i, kind in enumerate(cfg.dense_layers):
-            x, _ = layer(kind)(x, at(params["dense"], i))
-        x, stats = lax.scan(period, x, params["layers"])
+        if "dense" in params:
+            for i, kind in enumerate(cfg.dense_layers):
+                x, _, _ = layer(kind, False)(x, at(params["dense"], i))
+        x, (stats, balance) = lax.scan(period, x, params["layers"])
     with jax.named_scope("mx.head_ce"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
-    if not counted:
-        return x, 0.0
-    return x, _expert.sum_stats(stats)
+    return (x, None if stats is None else _expert.sum_stats(stats),
+            None if balance is None else jnp.sum(balance))
 
 
 def _chunked_ce(x, w_out, targets, n_chunks):
@@ -692,41 +657,36 @@ def ce_local_accum_active(cfg, mesh, batch, seq):
 
 
 def loss_fn(params, tokens, targets, cfg, mesh=None, aux_weight=0.01):
-    loss, aux = _loss_and_aux(params, tokens, targets, cfg, mesh)
-    if cfg.num_experts > 0 and cfg.moe_hidden is None:
-        loss = loss + aux_weight * aux  # GShard load-balance pressure
-    return loss
+    return _loss_and_stats(params, tokens, targets, cfg, mesh, aux_weight)[0]
 
 
-def _loss_and_aux(params, tokens, targets, cfg, mesh):
-    """-> (mean token NLL, what the trunk gave beside the hidden state: the
-    GShard layers' load-balance loss, or an expert share's counters)."""
-    if cfg.loss_chunks > 1:
-        if tokens.shape[1] % cfg.loss_chunks != 0:
-            # a silent full-logits fallback would re-materialize the
-            # [B,S,V] tensor loss_chunks exists to avoid (and OOM)
-            raise ValueError(
-                "loss_chunks=%d does not divide seq_len=%d; pick a "
-                "divisor or set loss_chunks=1"
-                % (cfg.loss_chunks, tokens.shape[1]))
-        x, aux = _hidden(params, tokens, cfg, mesh)
-        local = ce_local_accum_active(cfg, mesh, tokens.shape[0],
-                                      tokens.shape[1])
-        with jax.named_scope("mx.head_ce"):
-            if local:
-                loss = _chunked_ce_local(x, params["w_out"], targets,
-                                         cfg.loss_chunks, mesh)
-            else:
-                loss = _chunked_ce(x, params["w_out"], targets,
-                                   cfg.loss_chunks)
-    else:
-        logits, aux = apply(params, tokens, cfg, mesh, return_aux=True)
-        with jax.named_scope("mx.head_ce"):
+def _loss_and_stats(params, tokens, targets, cfg, mesh, aux_weight=0.01):
+    """-> (mean token NLL, plus ``aux_weight`` x the GShard layers' balance
+    loss where the model has them; the expert shares' counters or None)."""
+    if cfg.loss_chunks > 1 and tokens.shape[1] % cfg.loss_chunks != 0:
+        # a silent full-logits fallback would re-materialize the
+        # [B,S,V] tensor loss_chunks exists to avoid (and OOM)
+        raise ValueError(
+            "loss_chunks=%d does not divide seq_len=%d; pick a "
+            "divisor or set loss_chunks=1"
+            % (cfg.loss_chunks, tokens.shape[1]))
+    x, stats, balance = _hidden(params, tokens, cfg, mesh)
+    with jax.named_scope("mx.head_ce"):
+        if cfg.loss_chunks <= 1:
+            logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
             logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
             ll = jnp.take_along_axis(logp, targets[..., None],
                                      axis=-1)[..., 0]
             loss = -jnp.mean(ll)
-    return loss, aux
+        elif ce_local_accum_active(cfg, mesh, tokens.shape[0],
+                                   tokens.shape[1]):
+            loss = _chunked_ce_local(x, params["w_out"], targets,
+                                     cfg.loss_chunks, mesh)
+        else:
+            loss = _chunked_ce(x, params["w_out"], targets, cfg.loss_chunks)
+    if balance is not None:
+        loss = loss + aux_weight * balance  # GShard load-balance pressure
+    return loss, stats
 
 
 # --------------------------------------------------------------------------
@@ -830,13 +790,9 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
     """
     raw_mesh = getattr(mesh, "mesh", mesh)
     specs = param_specs(cfg)
-
-    def _sharding(spec_tree):
-        return jax.tree_util.tree_map(
-            lambda s: NamedSharding(raw_mesh, s), spec_tree,
-            is_leaf=lambda l: isinstance(l, P))
-
-    param_sh = _sharding(specs)
+    param_sh = jax.tree_util.tree_map(
+        lambda s: NamedSharding(raw_mesh, s), specs,
+        is_leaf=lambda l: isinstance(l, P))
 
     def init_fn(key):
         params = init_params(key, cfg)
@@ -845,28 +801,8 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
         momentum = jax.tree_util.tree_map(jnp.zeros_like, params)
         return params, momentum
 
-    if cfg.pp == 1 and cfg.expert_share is not None:
-        return init_fn, _profiler.instrument_step(
-            _CountedStep(cfg, mesh, param_sh, learning_rate),
-            "mx.train_step")
     if cfg.pp == 1:
-        def loss_of(params, tokens, targets):
-            return loss_fn(params, tokens, targets, cfg, mesh)
-
-        batch_sh = NamedSharding(raw_mesh, P("dp", "sp"))
-
-        @functools.partial(
-            jax.jit,  # mxlint: disable=MX022 (benchmark/verification harness: callers AOT-compile the step and account inventories explicitly via comm_model)
-            in_shardings=((param_sh, param_sh), batch_sh, batch_sh),
-            out_shardings=((param_sh, param_sh), None),
-            donate_argnums=(0,))
-        def step_fn(state, tokens, targets):
-            params, mom = state
-            loss, grads = jax.value_and_grad(loss_of)(params, tokens,
-                                                      targets)
-            new_params, new_mom = _sgd_momentum(params, mom, grads,
-                                                learning_rate)
-            return (new_params, new_mom), loss
+        step_fn = _Step(cfg, mesh, param_sh, learning_rate)
     else:
         from .compat import shard_map
         data_spec = P("dp", "sp")
@@ -913,63 +849,72 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
     return init_fn, _profiler.instrument_step(step_fn, "mx.train_step")
 
 
-class _CountedStep:
-    """The GSPMD step of a model whose scanned layers are expert shares:
-    the same jitted, donated SGD-momentum step, with the shares' counters
-    (``expert.MOE_STATS``) carried through it as one more donated array.
-    They stay on the device from step to step and are fetched only when
+class _Step:
+    """The GSPMD train step: the one jitted, donated SGD-momentum step,
+    and what a model's step keeps on the device between calls, carried
+    through it as trailing donated, replicated arrays: the expert shares'
+    counters (``expert.MOE_STATS``) for a model that has them, nothing for
+    one that has not. The counters are fetched only when
     ``profiler.metrics()['moe']`` is asked for. Callers see ``step(state,
-    tokens, targets) -> (state, loss)`` and ``lower`` of the same three."""
+    tokens, targets) -> (state, loss)`` and ``lower`` / ``trace`` of the
+    same three whatever is carried."""
 
     def __init__(self, cfg, mesh, param_sh, learning_rate):
         raw_mesh = getattr(mesh, "mesh", mesh)
         batch_sh = NamedSharding(raw_mesh, P("dp", "sp"))
-        everywhere = NamedSharding(raw_mesh, P())
-        self.moe_held = cfg.expert_share[1]
-        self.moe_counters = None
-        self.moe_rows = 0       # of a layer's slot buffer: set by a call
-        self._moe_k = cfg.moe_k
-        self._everywhere = everywhere
+        self._everywhere = NamedSharding(raw_mesh, P())
+        self._n_carried = 0
+        if _ffn_kind(cfg) == "share":
+            self._n_carried = 1
+            self.moe_held = cfg.expert_share[1]
+            self.moe_counters = None
+            self.moe_rows = 0   # of a layer's slot buffer: set by a call
+            self._moe_k = cfg.moe_k
+            _expert.track(self)
+        carried_sh = (self._everywhere,) * self._n_carried
 
         @functools.partial(
-            jax.jit,  # mxlint: disable=MX022 (benchmark/verification harness: callers AOT-compile the step and account inventories explicitly via comm_model)
-            in_shardings=((param_sh, param_sh), batch_sh, batch_sh,
-                          everywhere),
-            out_shardings=((param_sh, param_sh), None, everywhere),
-            donate_argnums=(0, 3))
-        def step_fn(state, tokens, targets, counters):
+            jax.jit,  # mxlint: disable=MX022 (the mesh-native train step: one per make_train_step call, AOT-compiled by its callers, which account its inventories through comm_model)
+            in_shardings=((param_sh, param_sh), batch_sh, batch_sh)
+            + carried_sh,
+            out_shardings=((param_sh, param_sh), None) + carried_sh,
+            donate_argnums=(0,) + tuple(range(3, 3 + self._n_carried)))
+        def step_fn(state, tokens, targets, *carried):
             params, mom = state
             (loss, stats), grads = jax.value_and_grad(
-                _loss_and_aux, has_aux=True)(params, tokens, targets, cfg,
-                                             mesh)
+                _loss_and_stats, has_aux=True)(params, tokens, targets, cfg,
+                                               mesh)
             new_params, new_mom = _sgd_momentum(params, mom, grads,
                                                 learning_rate)
-            return ((new_params, new_mom), loss,
-                    _expert.merge_stats(counters, stats))
+            return ((new_params, new_mom), loss) + tuple(
+                _expert.merge_stats(counters, stats) for counters in carried)
 
         self._jitted = step_fn
-        _expert.track(self)
 
-    def _counters(self):
+    def _carried(self):
+        if not self._n_carried:
+            return ()
         if self.moe_counters is None:
             self.moe_counters = jax.device_put(
                 jnp.zeros(len(_expert.MOE_STATS), jnp.int32),
                 self._everywhere)
-        return self.moe_counters
+        return (self.moe_counters,)
 
     def __call__(self, state, tokens, targets):
-        from ..pallas_kernels.grouped_matmul import TILE
-        self.moe_rows = _expert.buffer_rows(tokens.size, self._moe_k,
-                                            self.moe_held, TILE)
-        state, loss, self.moe_counters = self._jitted(
-            state, tokens, targets, self._counters())
+        state, loss, *carried = self._jitted(state, tokens, targets,
+                                             *self._carried())
+        if carried:
+            from ..pallas_kernels.grouped_matmul import TILE
+            self.moe_counters, = carried
+            self.moe_rows = _expert.buffer_rows(tokens.size, self._moe_k,
+                                                self.moe_held, TILE)
         return state, loss
 
     def lower(self, state, tokens, targets):
-        return self._jitted.lower(state, tokens, targets, self._counters())
+        return self._jitted.lower(state, tokens, targets, *self._carried())
 
     def trace(self, state, tokens, targets):
-        return self._jitted.trace(state, tokens, targets, self._counters())
+        return self._jitted.trace(state, tokens, targets, *self._carried())
 
 
 def _spec_mentions(spec, axis):

@@ -195,7 +195,7 @@ sub SyncBatchNorm { AI::MXTpu::op('SyncBatchNorm', @_) }
 # UpSampling(*data, scale=1, sample_type='nearest', num_args=1, num_filter=0, multi_input_mode='concat', workspace=512)
 sub UpSampling { AI::MXTpu::op('UpSampling', @_) }
 
-# abs(x: 'ArrayLike', /) -> 'Array'
+# abs(...)
 sub abs_ { AI::MXTpu::op('abs', @_) }
 
 # activation(x, act_type='relu')
@@ -210,7 +210,7 @@ sub adamw_update { AI::MXTpu::op('adamw_update', @_) }
 # adaptive_avg_pooling_2d(data, output_size=(1, 1))
 sub adaptive_avg_pooling_2d { AI::MXTpu::op('adaptive_avg_pooling_2d', @_) }
 
-# add(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# add(...)
 sub add { AI::MXTpu::op('add', @_) }
 
 # add_n(*xs)
@@ -228,25 +228,25 @@ sub amp_multicast { AI::MXTpu::op('amp_multicast', @_) }
 # arange(start=0.0, stop=None, step=1.0, repeat=1, infer_range=False, dtype='float32')
 sub arange { AI::MXTpu::op('arange', @_) }
 
-# arccos(x: 'ArrayLike', /) -> 'Array'
+# arccos(...)
 sub arccos { AI::MXTpu::op('arccos', @_) }
 
-# arccosh(x: 'ArrayLike', /) -> 'Array'
+# arccosh(...)
 sub arccosh { AI::MXTpu::op('arccosh', @_) }
 
-# arcsin(x: 'ArrayLike', /) -> 'Array'
+# arcsin(...)
 sub arcsin { AI::MXTpu::op('arcsin', @_) }
 
-# arcsinh(x: 'ArrayLike', /) -> 'Array'
+# arcsinh(...)
 sub arcsinh { AI::MXTpu::op('arcsinh', @_) }
 
-# arctan(x: 'ArrayLike', /) -> 'Array'
+# arctan(...)
 sub arctan { AI::MXTpu::op('arctan', @_) }
 
-# arctan2(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# arctan2(...)
 sub arctan2 { AI::MXTpu::op('arctan2', @_) }
 
-# arctanh(x: 'ArrayLike', /) -> 'Array'
+# arctanh(...)
 sub arctanh { AI::MXTpu::op('arctanh', @_) }
 
 # argmax(x, axis=None, keepdims=False)
@@ -294,10 +294,10 @@ sub box_nms { AI::MXTpu::op('box_nms', @_) }
 # box_non_maximum_suppression(data, overlap_thresh=0.5, valid_thresh=0.0, topk=-1, coord_start=2, score_index=1, id_index=-1, background_id=-1, force_suppress=False, in_format='corner', out_format='corner')
 sub box_non_maximum_suppression { AI::MXTpu::op('box_non_maximum_suppression', @_) }
 
-# broadcast_add(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# broadcast_add(...)
 sub broadcast_add { AI::MXTpu::op('broadcast_add', @_) }
 
-# broadcast_arctan2(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# broadcast_arctan2(...)
 sub broadcast_arctan2 { AI::MXTpu::op('broadcast_arctan2', @_) }
 
 # broadcast_axes(x, axis=(), size=())
@@ -306,10 +306,10 @@ sub broadcast_axes { AI::MXTpu::op('broadcast_axes', @_) }
 # broadcast_axis(x, axis=(), size=())
 sub broadcast_axis { AI::MXTpu::op('broadcast_axis', @_) }
 
-# broadcast_div(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# broadcast_div(...)
 sub broadcast_div { AI::MXTpu::op('broadcast_div', @_) }
 
-# broadcast_divide(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# broadcast_divide(...)
 sub broadcast_divide { AI::MXTpu::op('broadcast_divide', @_) }
 
 # broadcast_equal(a, b)
@@ -321,7 +321,7 @@ sub broadcast_greater { AI::MXTpu::op('broadcast_greater', @_) }
 # broadcast_greater_equal(a, b)
 sub broadcast_greater_equal { AI::MXTpu::op('broadcast_greater_equal', @_) }
 
-# broadcast_hypot(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# broadcast_hypot(...)
 sub broadcast_hypot { AI::MXTpu::op('broadcast_hypot', @_) }
 
 # broadcast_lesser(a, b)
@@ -342,34 +342,34 @@ sub broadcast_logical_or { AI::MXTpu::op('broadcast_logical_or', @_) }
 # broadcast_logical_xor(a, b)
 sub broadcast_logical_xor { AI::MXTpu::op('broadcast_logical_xor', @_) }
 
-# broadcast_maximum(x: 'ArrayLike', y: 'ArrayLike', /) -> 'Array'
+# broadcast_maximum(...)
 sub broadcast_maximum { AI::MXTpu::op('broadcast_maximum', @_) }
 
-# broadcast_minimum(x: 'ArrayLike', y: 'ArrayLike', /) -> 'Array'
+# broadcast_minimum(...)
 sub broadcast_minimum { AI::MXTpu::op('broadcast_minimum', @_) }
 
-# broadcast_mod(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# broadcast_mod(...)
 sub broadcast_mod { AI::MXTpu::op('broadcast_mod', @_) }
 
-# broadcast_mul(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# broadcast_mul(...)
 sub broadcast_mul { AI::MXTpu::op('broadcast_mul', @_) }
 
-# broadcast_multiply(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# broadcast_multiply(...)
 sub broadcast_multiply { AI::MXTpu::op('broadcast_multiply', @_) }
 
 # broadcast_not_equal(a, b)
 sub broadcast_not_equal { AI::MXTpu::op('broadcast_not_equal', @_) }
 
-# broadcast_pow(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# broadcast_pow(...)
 sub broadcast_pow { AI::MXTpu::op('broadcast_pow', @_) }
 
-# broadcast_power(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# broadcast_power(...)
 sub broadcast_power { AI::MXTpu::op('broadcast_power', @_) }
 
-# broadcast_sub(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# broadcast_sub(...)
 sub broadcast_sub { AI::MXTpu::op('broadcast_sub', @_) }
 
-# broadcast_subtract(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# broadcast_subtract(...)
 sub broadcast_subtract { AI::MXTpu::op('broadcast_subtract', @_) }
 
 # broadcast_to(x, shape=None)
@@ -384,10 +384,10 @@ sub cast { AI::MXTpu::op('cast', @_) }
 # cast_storage(data, stype='default')
 sub cast_storage { AI::MXTpu::op('cast_storage', @_) }
 
-# cbrt(x: 'ArrayLike', /) -> 'Array'
+# cbrt(...)
 sub cbrt { AI::MXTpu::op('cbrt', @_) }
 
-# ceil(x: 'ArrayLike', /) -> 'Array'
+# ceil(...)
 sub ceil { AI::MXTpu::op('ceil', @_) }
 
 # choose_element_0index(lhs, rhs)
@@ -411,10 +411,10 @@ sub convolution { AI::MXTpu::op('convolution', @_) }
 # correlation(data1, data2, kernel_size=1, max_displacement=1, stride1=1, stride2=1, pad_size=0, is_multiply=True)
 sub correlation { AI::MXTpu::op('correlation', @_) }
 
-# cos(x: 'ArrayLike', /) -> 'Array'
+# cos(...)
 sub cos_ { AI::MXTpu::op('cos', @_) }
 
-# cosh(x: 'ArrayLike', /) -> 'Array'
+# cosh(...)
 sub cosh { AI::MXTpu::op('cosh', @_) }
 
 # count_sketch(data, h, s, out_dim=1, processing_batch_size=32)
@@ -435,7 +435,7 @@ sub cumsum { AI::MXTpu::op('cumsum', @_) }
 # deconvolution(x, weight, bias=None, kernel=None, stride=None, dilate=None, pad=None, adj=None, target_shape=None, num_filter=None, num_group=1, no_bias=True, layout='NCHW', cudnn_tune=None, cudnn_off=False, workspace=512, precision=None)
 sub deconvolution { AI::MXTpu::op('deconvolution', @_) }
 
-# degrees(x: 'ArrayLike', /) -> 'Array'
+# degrees(...)
 sub degrees { AI::MXTpu::op('degrees', @_) }
 
 # depth_to_space(x, block_size=1)
@@ -444,7 +444,7 @@ sub depth_to_space { AI::MXTpu::op('depth_to_space', @_) }
 # diag(x, k=0, axis1=0, axis2=1)
 sub diag { AI::MXTpu::op('diag', @_) }
 
-# divide(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# divide(...)
 sub divide { AI::MXTpu::op('divide', @_) }
 
 # dot(a, b, transpose_a=False, transpose_b=False, precision=None)
@@ -453,25 +453,25 @@ sub dot_ { AI::MXTpu::op('dot', @_) }
 # dropout(x, key=None, p=0.5, mode='training', axes=(), _training=True, cudnn_off=False)
 sub dropout { AI::MXTpu::op('dropout', @_) }
 
-# elemwise_add(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# elemwise_add(...)
 sub elemwise_add { AI::MXTpu::op('elemwise_add', @_) }
 
-# elemwise_div(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# elemwise_div(...)
 sub elemwise_div { AI::MXTpu::op('elemwise_div', @_) }
 
-# elemwise_divide(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# elemwise_divide(...)
 sub elemwise_divide { AI::MXTpu::op('elemwise_divide', @_) }
 
-# elemwise_mul(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# elemwise_mul(...)
 sub elemwise_mul { AI::MXTpu::op('elemwise_mul', @_) }
 
-# elemwise_multiply(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# elemwise_multiply(...)
 sub elemwise_multiply { AI::MXTpu::op('elemwise_multiply', @_) }
 
-# elemwise_sub(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# elemwise_sub(...)
 sub elemwise_sub { AI::MXTpu::op('elemwise_sub', @_) }
 
-# elemwise_subtract(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# elemwise_subtract(...)
 sub elemwise_subtract { AI::MXTpu::op('elemwise_subtract', @_) }
 
 # elemwise_sum(*xs)
@@ -483,19 +483,19 @@ sub embedding { AI::MXTpu::op('embedding', @_) }
 # equal(a, b)
 sub equal { AI::MXTpu::op('equal', @_) }
 
-# erf(x: 'ArrayLike') -> 'Array'
+# erf(...)
 sub erf { AI::MXTpu::op('erf', @_) }
 
-# erfinv(x: 'ArrayLike') -> 'Array'
+# erfinv(...)
 sub erfinv { AI::MXTpu::op('erfinv', @_) }
 
-# exp(x: 'ArrayLike', /) -> 'Array'
+# exp(...)
 sub exp_ { AI::MXTpu::op('exp', @_) }
 
 # expand_dims(x, axis=0)
 sub expand_dims { AI::MXTpu::op('expand_dims', @_) }
 
-# expm1(x: 'ArrayLike', /) -> 'Array'
+# expm1(...)
 sub expm1 { AI::MXTpu::op('expm1', @_) }
 
 # extracttrian(a, offset=0, lower=True)
@@ -510,7 +510,7 @@ sub fft { AI::MXTpu::op('fft', @_) }
 # fill_element_0index(lhs, mhs, rhs)
 sub fill_element_0index { AI::MXTpu::op('fill_element_0index', @_) }
 
-# fix(x: 'ArrayLike') -> 'Array'
+# fix(...)
 sub fix { AI::MXTpu::op('fix', @_) }
 
 # flatten(x)
@@ -519,7 +519,7 @@ sub flatten { AI::MXTpu::op('flatten', @_) }
 # flip(x, axis=())
 sub flip_ { AI::MXTpu::op('flip', @_) }
 
-# floor(x: 'ArrayLike', /) -> 'Array'
+# floor(...)
 sub floor { AI::MXTpu::op('floor', @_) }
 
 # ftml_update(weight, grad, d, v, z, lr=None, t=1, beta1=0.6, beta2=0.999, epsilon=1e-08, wd=0.0, rescale_grad=1.0, clip_grad=-1.0)
@@ -534,10 +534,10 @@ sub full { AI::MXTpu::op('full', @_) }
 # fully_connected(x, weight, bias=None, num_hidden=None, no_bias=False, flatten=True, precision=None)
 sub fully_connected { AI::MXTpu::op('fully_connected', @_) }
 
-# gamma(x: 'ArrayLike') -> 'Array'
+# gamma(...)
 sub gamma { AI::MXTpu::op('gamma', @_) }
 
-# gammaln(x: 'ArrayLike') -> 'Array'
+# gammaln(...)
 sub gammaln { AI::MXTpu::op('gammaln', @_) }
 
 # gather_nd(data, indices)
@@ -573,7 +573,7 @@ sub hawkesll { AI::MXTpu::op('hawkesll', @_) }
 # histogram(data, bin_cnt=10, range=None)
 sub histogram { AI::MXTpu::op('histogram', @_) }
 
-# hypot(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# hypot(...)
 sub hypot { AI::MXTpu::op('hypot', @_) }
 
 # identity(x)
@@ -720,13 +720,13 @@ sub linspace { AI::MXTpu::op('linspace', @_) }
 # log(x)
 sub log_ { AI::MXTpu::op('log', @_) }
 
-# log10(x: 'ArrayLike', /) -> 'Array'
+# log10(...)
 sub log10 { AI::MXTpu::op('log10', @_) }
 
-# log1p(x: 'ArrayLike', /) -> 'Array'
+# log1p(...)
 sub log1p { AI::MXTpu::op('log1p', @_) }
 
-# log2(x: 'ArrayLike', /) -> 'Array'
+# log2(...)
 sub log2 { AI::MXTpu::op('log2', @_) }
 
 # log_softmax(x, axis=-1, temperature=None, dtype=None)
@@ -765,7 +765,7 @@ sub max_ { AI::MXTpu::op('max', @_) }
 # max_axis(x, axis=None, keepdims=False, exclude=False)
 sub max_axis { AI::MXTpu::op('max_axis', @_) }
 
-# maximum(x: 'ArrayLike', y: 'ArrayLike', /) -> 'Array'
+# maximum(...)
 sub maximum { AI::MXTpu::op('maximum', @_) }
 
 # mean(x, axis=None, keepdims=False, exclude=False)
@@ -777,10 +777,10 @@ sub min_ { AI::MXTpu::op('min', @_) }
 # min_axis(x, axis=None, keepdims=False, exclude=False)
 sub min_axis { AI::MXTpu::op('min_axis', @_) }
 
-# minimum(x: 'ArrayLike', y: 'ArrayLike', /) -> 'Array'
+# minimum(...)
 sub minimum { AI::MXTpu::op('minimum', @_) }
 
-# mod(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# mod(...)
 sub mod { AI::MXTpu::op('mod', @_) }
 
 # moments(data, axes=None, keepdims=False)
@@ -828,7 +828,7 @@ sub multibox_prior { AI::MXTpu::op('multibox_prior', @_) }
 # multinomial(data, key=None, shape=(), get_prob=False, dtype='int32')
 sub multinomial { AI::MXTpu::op('multinomial', @_) }
 
-# multiply(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# multiply(...)
 sub multiply { AI::MXTpu::op('multiply', @_) }
 
 # nag_mom_update(weight, grad, mom, lr=None, momentum=0.0, wd=0.0, rescale_grad=1.0, clip_gradient=-1.0)
@@ -840,7 +840,7 @@ sub nanprod { AI::MXTpu::op('nanprod', @_) }
 # nansum(x, axis=None, keepdims=False, exclude=False)
 sub nansum { AI::MXTpu::op('nansum', @_) }
 
-# negative(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# negative(...)
 sub negative { AI::MXTpu::op('negative', @_) }
 
 # norm(x, ord=2, axis=None, keepdims=False)
@@ -870,7 +870,7 @@ sub pick { AI::MXTpu::op('pick', @_) }
 # pooling(x, kernel=None, pool_type='max', stride=None, pad=None, global_pool=False, pooling_convention='valid', cudnn_off=False, p_value=2, count_include_pad=True, layout=None)
 sub pooling { AI::MXTpu::op('pooling', @_) }
 
-# power(x1: 'ArrayLike', x2: 'ArrayLike', /) -> 'Array'
+# power(...)
 sub power { AI::MXTpu::op('power', @_) }
 
 # preloaded_multi_mp_sgd_mom_update(*data, num_weights=1, momentum=0.0, rescale_grad=1.0, clip_gradient=-1.0)
@@ -921,7 +921,7 @@ sub quantized_fully_connected { AI::MXTpu::op('quantized_fully_connected', @_) }
 # quantized_pooling(data, min_data, max_data, kernel=(2, 2), pool_type='max', stride=(1, 1), pad=(0, 0), global_pool=False)
 sub quantized_pooling { AI::MXTpu::op('quantized_pooling', @_) }
 
-# radians(x: 'ArrayLike', /) -> 'Array'
+# radians(...)
 sub radians { AI::MXTpu::op('radians', @_) }
 
 # randint(key=None, low=0, high=1, shape=(), dtype='int32', ctx=None)
@@ -981,7 +981,7 @@ sub reshape_like { AI::MXTpu::op('reshape_like', @_) }
 # reverse(x, axis=())
 sub reverse_ { AI::MXTpu::op('reverse', @_) }
 
-# rint(x: 'ArrayLike', /) -> 'Array'
+# rint(...)
 sub rint { AI::MXTpu::op('rint', @_) }
 
 # rmsprop_update(weight, grad, n, lr=None, gamma1=0.95, epsilon=1e-08, wd=0.0, rescale_grad=1.0, clip_gradient=-1.0, clip_weights=-1.0)
@@ -999,7 +999,7 @@ sub roi_align { AI::MXTpu::op('roi_align', @_) }
 # roi_pooling(data, rois, pooled_size=(7, 7), spatial_scale=1.0)
 sub roi_pooling { AI::MXTpu::op('roi_pooling', @_) }
 
-# round(a: 'ArrayLike', decimals: 'int' = 0, out: 'None' = None) -> 'Array'
+# round(...)
 sub round { AI::MXTpu::op('round', @_) }
 
 # rsqrt(x)
@@ -1044,7 +1044,7 @@ sub shuffle { AI::MXTpu::op('shuffle', @_) }
 # sigmoid(x)
 sub sigmoid { AI::MXTpu::op('sigmoid', @_) }
 
-# sign(x: 'ArrayLike', /) -> 'Array'
+# sign(...)
 sub sign_ { AI::MXTpu::op('sign', @_) }
 
 # signsgd_update(weight, grad, lr=None, wd=0.0, rescale_grad=1.0, clip_gradient=-1.0)
@@ -1053,10 +1053,10 @@ sub signsgd_update { AI::MXTpu::op('signsgd_update', @_) }
 # signum_update(weight, grad, mom, lr=None, momentum=0.0, wd=0.0, rescale_grad=1.0, clip_gradient=-1.0, wd_lh=0.0)
 sub signum_update { AI::MXTpu::op('signum_update', @_) }
 
-# sin(x: 'ArrayLike', /) -> 'Array'
+# sin(...)
 sub sin_ { AI::MXTpu::op('sin', @_) }
 
-# sinh(x: 'ArrayLike', /) -> 'Array'
+# sinh(...)
 sub sinh { AI::MXTpu::op('sinh', @_) }
 
 # size_array(x)
@@ -1113,10 +1113,10 @@ sub split_ { AI::MXTpu::op('split', @_) }
 # split_v2(data, indices=(), axis=0, squeeze_axis=False, sections=0)
 sub split_v2 { AI::MXTpu::op('split_v2', @_) }
 
-# sqrt(x: 'ArrayLike', /) -> 'Array'
+# sqrt(...)
 sub sqrt_ { AI::MXTpu::op('sqrt', @_) }
 
-# square(x: 'ArrayLike', /) -> 'Array'
+# square(...)
 sub square { AI::MXTpu::op('square', @_) }
 
 # squeeze(x, axis=None)
@@ -1128,7 +1128,7 @@ sub stack { AI::MXTpu::op('stack', @_) }
 # stop_gradient(x)
 sub stop_gradient { AI::MXTpu::op('stop_gradient', @_) }
 
-# subtract(*args: 'ArrayLike', out: 'None' = None, where: 'None' = None) -> 'Any'
+# subtract(...)
 sub subtract { AI::MXTpu::op('subtract', @_) }
 
 # sum(x, axis=None, keepdims=False, exclude=False)
@@ -1149,7 +1149,7 @@ sub syevd { AI::MXTpu::op('syevd', @_) }
 # take(a, indices, axis=0, mode='clip')
 sub take { AI::MXTpu::op('take', @_) }
 
-# tan(x: 'ArrayLike', /) -> 'Array'
+# tan(...)
 sub tan { AI::MXTpu::op('tan', @_) }
 
 # tanh(x)
@@ -1164,7 +1164,7 @@ sub topk { AI::MXTpu::op('topk', @_) }
 # transpose(x, axes=None)
 sub transpose { AI::MXTpu::op('transpose', @_) }
 
-# trunc(x: 'ArrayLike') -> 'Array'
+# trunc(...)
 sub trunc { AI::MXTpu::op('trunc', @_) }
 
 # uniform(key=None, low=0.0, high=1.0, shape=(), dtype='float32', ctx=None)

@@ -79,11 +79,16 @@ def main(out_path=None):
         if pname is None or pname in emitted:
             continue
         emitted.add(pname)
-        opdef = registry.get_op(name)
-        try:
-            sig = str(inspect.signature(opdef.fn))
-        except (TypeError, ValueError):
-            sig = "(...)"
+        fn = registry.get_op(name).fn
+        # the signature of this package's own functions only: one that is
+        # jax.numpy's is JAX's to reword, and the file would go stale with
+        # a JAX release
+        sig = "(...)"
+        if (getattr(fn, "__module__", None) or "").startswith("mxnet_tpu"):
+            try:
+                sig = str(inspect.signature(fn))
+            except (TypeError, ValueError):
+                pass
         body.append("# %s%s\n" % (name, sig))
         body.append("sub %s { AI::MXTpu::op('%s', @_) }\n\n"
                     % (pname, name))

@@ -27,18 +27,26 @@ TINY = os.path.join(ROOT, "tests", "chipbench", "tiny_afmoe", "configs",
                     "tiny_afmoe.json")
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    """The tiny share in float32: configuration, weights, one batch."""
+def _tiny(**assumed):
+    """The tiny share as its file states it (``assumed`` overrides what the
+    file assumes): its file, what was assumed, configuration, seeded
+    weights, one batch."""
     from chipbench.models import afmoe_decoder as adapter
     with open(TINY) as f:
         m = json.load(f)
-    a = dict(m["assumed"], dtype="float32")
+    a = dict(m["assumed"], **assumed)
     cfg = adapter.transformer_config(m, a, 128)
     words = adapter.seed_words(3000000019)
-    weights = adapter.make_weights(m, words, jnp.float32)
+    weights = adapter.make_weights(m, words, jnp.dtype(a["dtype"]))
     (tokens, targets), = adapter.make_batches(
         m, {"n_batches": 1, "batch": 2, "seq_len": 128}, words)
+    return m, a, cfg, weights, tokens, targets
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """The tiny share in float32: configuration, weights, one batch."""
+    m, _, cfg, weights, tokens, targets = _tiny(dtype="float32")
     return m, cfg, weights, tokens, targets
 
 
@@ -129,17 +137,52 @@ def test_the_norms_eps_is_the_configurations():
     assert T.TransformerConfig().norm_eps == 1e-6     # Baichuan's stays
 
 
-# The dense decoder's step at the parent commit (8ac7738), CPU: three losses,
-# the sha256 of the state after them and of the lowered program's text
-PARENT = {"losses": ["0x1.7cb0ec0000000p+2", "0x1.41f6f80000000p+2",
-                     "0x1.daa90c0000000p+1"],
-          "state": "f9467e1e4620f3524bac36bf099bee21415e08177ceb2243743d9bc9"
-                   "fa27670d",
-          "lowered": "a2ff0f9a0b8723a96f9148572bf1205bcb1665ac3b648648204cec"
-                     "c35c982f24"}
+_PLAIN = dict(vocab_size=64, dim=32, n_layers=4, n_heads=4, ffn_hidden=48)
 
 
-def test_the_dense_decoders_step_is_the_parents_bit_for_bit():
+@pytest.mark.parametrize("make", [
+    lambda: T.TransformerConfig(**_PLAIN),
+    lambda: T.TransformerConfig(pp=2, **_PLAIN),
+    lambda: T.TransformerConfig(num_experts=4, **_PLAIN),
+    lambda: _tiny()[2]], ids=["plain", "plain_pp2", "gshard", "share"])
+def test_the_table_is_the_only_writer_of_a_layers_leaves(make):
+    cfg = make()
+    shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), jr.PRNGKey(0))
+    specs = T.param_specs(cfg)
+    is_spec = lambda l: isinstance(l, T.P)  # noqa: E731
+    assert jax.tree_util.tree_structure(shapes) == \
+        jax.tree_util.tree_structure(specs, is_leaf=is_spec)
+    for leaf, spec in zip(jax.tree_util.tree_leaves(shapes),
+                          jax.tree_util.tree_leaves(specs, is_leaf=is_spec)):
+        assert len(spec) == leaf.ndim
+    for stack, (lead, _, experts) in T._stacks(cfg).items():
+        table = T._layer_leaves(cfg, experts)
+        assert {n: lead + shape for n, (shape, _, _) in table.items()} == \
+            {n: v.shape for n, v in shapes[stack].items()}
+
+
+# The step at the parent commit, CPU, one device: three losses, the sha256 of
+# the state after them and of the lowered program's text. "dense": the plain
+# decoder at 8ac7738 (PR 30's parent), unmoved since. "pattern": the tiny
+# share in its own ``assumed`` type and learning rate, seeded weights, zero
+# momentum, at 97c2934 (PR 32's parent).
+PARENT = {
+    "dense": {"losses": ["0x1.7cb0ec0000000p+2", "0x1.41f6f80000000p+2",
+                         "0x1.daa90c0000000p+1"],
+              "state": "f9467e1e4620f3524bac36bf099bee21415e08177ceb2243743d"
+                       "9bc9fa27670d",
+              "lowered": "a2ff0f9a0b8723a96f9148572bf1205bcb1665ac3b64864820"
+                         "4cecc35c982f24"},
+    "pattern": {"losses": ["0x1.7e1c0a0000000p+2", "0x1.3256480000000p+2",
+                           "0x1.e07f7c0000000p+1"],
+                "state": "e711cab76d3d57be2a5ae0094902011627f360ba3552be6e90"
+                         "180ac8fbcaa80b",
+                "lowered": "effe8d453083c46d7077365ef215359c5c035dfd11a1d439"
+                           "d562ef875666fe59"},
+}
+
+
+def _dense_case():
     cfg = T.TransformerConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                               ffn_hidden=128, max_seq_len=128,
                               dtype="bfloat16", attn_mode="local", remat=True,
@@ -148,15 +191,31 @@ def test_the_dense_decoders_step_is_the_parents_bit_for_bit():
     init, step = T.make_train_step(cfg, mesh, learning_rate=1.0)
     with mesh.mesh:
         state = init(jr.PRNGKey(7))
-        ids = jr.randint(jr.PRNGKey(8), (2, 129), 0, 256, jnp.int32)
+    ids = jr.randint(jr.PRNGKey(8), (2, 129), 0, 256, jnp.int32)
+    return mesh, step, state, ids[:, :-1], ids[:, 1:]
+
+
+def _pattern_case():
+    _, a, cfg, weights, tokens, targets = _tiny()
+    mesh = create_mesh(devices=jax.devices()[:1], dp=1)
+    _, step = T.make_train_step(cfg, mesh, learning_rate=a["learning_rate"])
+    state = (weights, jax.tree_util.tree_map(jnp.zeros_like, weights))
+    return mesh, step, state, tokens, targets
+
+
+@pytest.mark.parametrize("case", ["dense", "pattern"])
+def test_the_step_is_the_parents_bit_for_bit(case):
+    mesh, step, state, tokens, targets = {
+        "dense": _dense_case, "pattern": _pattern_case}[case]()
+    with mesh.mesh:
         losses = []
         for _ in range(3):
-            state, loss = step(state, ids[:, :-1], ids[:, 1:])
+            state, loss = step(state, tokens, targets)
             losses.append(float(loss).hex())
-        text = step.lower(state, ids[:, :-1], ids[:, 1:]).as_text()
+        text = step.lower(state, tokens, targets).as_text()
     digest = hashlib.sha256()
     for leaf in jax.tree_util.tree_leaves(state):
         digest.update(onp.asarray(leaf.astype(jnp.float32)).tobytes())
-    assert losses == PARENT["losses"]
-    assert digest.hexdigest() == PARENT["state"]
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT["lowered"]
+    got = {"losses": losses, "state": digest.hexdigest(),
+           "lowered": hashlib.sha256(text.encode()).hexdigest()}
+    assert got == PARENT[case]

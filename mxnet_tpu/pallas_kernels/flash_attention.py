@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from .. import profiler as _profiler
 
@@ -837,8 +838,12 @@ def _flash_fwd(q, k, v, causal, scale, blocks, interpret, window):
                                    interpret, window)
         # keep one lane of the (bh, sq, 128) kernel output — the lane dim
         # exists only for Mosaic's block constraint, not worth 128x HBM
-        # across the fwd->bwd interval
-        return out, (q, k, v, out, lse[:, :, 0])
+        # across the fwd->bwd interval. The two residuals only this kernel
+        # can make are named, so that a caller's jax.checkpoint may keep
+        # them (parallel/transformer.py _remat_rows) and its backward not
+        # run the kernel again; outside a checkpoint a name is the identity
+        out = checkpoint_name(out, "flash_out")
+        return out, (q, k, v, out, checkpoint_name(lse[:, :, 0], "flash_lse"))
     out = attention_reference(q, k, v, causal=causal, scale=scale,
                               window=window)
     return out, (q, k, v, None, None)

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import warnings
 from typing import Optional
 
@@ -47,7 +48,8 @@ from . import expert as _expert
 from .expert import moe_ffn
 
 __all__ = ["TransformerConfig", "init_params", "apply", "loss_fn",
-           "make_train_step", "param_specs", "ce_local_accum_active"]
+           "make_train_step", "param_specs", "ce_local_accum_active",
+           "remat_choice"]
 
 
 @dataclasses.dataclass
@@ -71,13 +73,18 @@ class TransformerConfig:
     # ~1/3 more FLOPs for O(n_layers) less activation HBM, the standard
     # TPU trade (SURVEY §7: jax.checkpoint)
     remat: bool = True
-    # selective remat: names of intermediates the backward may KEEP
-    # instead of recomputing (jax save_only_these_names policy).
-    # "ffn_prod" saves the gated-FFN product [B,S,ffn_hidden] — skips
-    # recomputing the two up-projections (the biggest matmuls);
-    # "attn_o" saves the attention output [B,S,D] — skips re-running
-    # the flash forward kernel inside the backward. Empty = full remat.
-    remat_save: tuple = ()
+    # what the layer remat KEEPS beyond a layer's input (jax
+    # save_only_these_names). None: the step chooses from its shapes, the
+    # state it carries and the device's bytes_limit (``remat_choice``);
+    # (): full remat, pinned; a tuple of names: those, pinned. The names
+    # there are: "flash_out" and "flash_lse", the flash forward kernel's
+    # output and row sums, the residuals its backward kernels read (kept,
+    # the backward does not run the forward kernel again); "attn_o", the
+    # transposed attention output OUTSIDE the kernel's custom_vjp (it does
+    # not reach those residuals: the kernel still runs twice); "ffn_prod",
+    # the gated FFN's silu(a) * u (it skips no product: the backward of
+    # the gate needs a and u, which are recomputed).
+    remat_save: Optional[tuple] = None
     # >1: compute the final projection + cross-entropy in this many
     # sequence chunks (sequential lax.map + per-chunk remat), so the
     # [B, S, vocab] f32 logits tensor never materializes — at 32k vocab
@@ -459,12 +466,68 @@ def apply(params, tokens, cfg: TransformerConfig, mesh=None,
 
 
 def _remat_policy(cfg):
-    """None = recompute everything; with cfg.remat_save, keep the named
-    intermediates (save_only_these_names) so the backward skips their
-    producers — selective remat, the memory/recompute dial."""
+    """None = recompute everything (``remat_save`` empty, or None and no
+    step to choose for it); else keep the named intermediates
+    (save_only_these_names) so the backward skips their producers."""
     if not cfg.remat_save:
         return None
     return jax.checkpoint_policies.save_only_these_names(*cfg.remat_save)
+
+
+# The share of a device's room (bytes_limit less state, gradients and the
+# layers' inputs) that what the layer remat keeps may take. The rest is the
+# backward's working set, which grows with the tokens as the kept rows do.
+# Set from AOT compiles and the chip's readings (PERF.md section 6, PR 33).
+_REMAT_KEEP_SHARE = 1 / 8
+
+
+def _remat_rows(cfg, batch, seq, sizes):
+    """[(names, bytes they hold a layer on one device)]: what the layer
+    remat may keep, the recompute saved per byte falling down the table.
+    One row: the flash forward kernel's output [b, h, S, Dh] and float32
+    row sums [b h, S], on a device's share of batch and heads as
+    ``_attention`` cuts them."""
+    dp, tp = sizes.get("dp", 1), sizes.get("tp", 1)
+    b = batch // dp if batch % dp == 0 else batch
+    h = cfg.n_heads // tp if cfg.n_heads % tp == 0 \
+        and cfg.kv_heads % tp == 0 else cfg.n_heads
+    rows = b * h * seq
+    return [(("flash_out", "flash_lse"),
+             rows * (cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4))]
+
+
+def remat_choice(cfg, batch, seq, state_bytes, grad_bytes, sizes, limit):
+    """What the layer remat of a [batch, seq] step keeps where
+    ``cfg.remat_save`` is None -> (names, their bytes over all layers,
+    the budget or None). All bytes are one device's: ``state_bytes`` what
+    the step carries (weights and momentum), ``grad_bytes`` the gradients,
+    ``limit`` the device's ``bytes_limit`` or None where it reports none
+    (the CPU, a described topology), which keeps full remat. Rows of
+    ``_remat_rows`` are taken in order while they fit the budget."""
+    if cfg.remat_save is not None or not cfg.remat or not limit:
+        return tuple(cfg.remat_save or ()), 0, None
+    dp, sp = sizes.get("dp", 1), sizes.get("sp", 1)
+    inputs = cfg.n_layers * (batch * seq // (dp * sp)) * cfg.dim \
+        * jnp.dtype(cfg.dtype).itemsize
+    budget = max(0, int(_REMAT_KEEP_SHARE * (
+        limit - state_bytes - grad_bytes - inputs)))
+    names, kept = (), 0
+    for row, nbytes in _remat_rows(cfg, batch, seq, sizes):
+        if kept + cfg.n_layers * nbytes > budget:
+            break
+        names, kept = names + row, kept + cfg.n_layers * nbytes
+    return names, kept, budget
+
+
+def _mesh_bytes_limit(mesh):
+    """``bytes_limit`` of the first device the mesh names; None where the
+    backend reports none (the CPU) or the device is only described."""
+    device = getattr(mesh, "mesh", mesh).devices.flat[0]
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return None
+    return (stats or {}).get("bytes_limit")
 
 
 def _hidden(params, tokens, cfg, mesh):
@@ -849,6 +912,25 @@ def make_train_step(cfg: TransformerConfig, mesh, learning_rate=1e-3):
     return init_fn, _profiler.instrument_step(step_fn, "mx.train_step")
 
 
+def _with_remat_chosen(cfg, mesh, param_sh, state, shape):
+    """``cfg`` with ``remat_save`` settled for a step of ``state`` on
+    ``shape`` tokens (``remat_choice``; run while the step is traced, so
+    once a batch shape), and the choice noted in
+    ``profiler.metrics()['train_step']``."""
+    def on_device(tree):
+        return sum(math.prod(sh.shard_shape(a.shape)) * a.dtype.itemsize
+                   for a, sh in zip(jax.tree_util.tree_leaves(tree),
+                                    jax.tree_util.tree_leaves(param_sh)))
+
+    params, mom = state
+    weights = on_device(params)     # the gradients are as large again
+    names, kept, budget = remat_choice(
+        cfg, *shape, weights + on_device(mom), weights, _mesh_sizes(mesh),
+        _mesh_bytes_limit(mesh))
+    _profiler.note_remat(names, kept, budget)
+    return dataclasses.replace(cfg, remat_save=names)
+
+
 class _Step:
     """The GSPMD train step: the one jitted, donated SGD-momentum step,
     and what a model's step keeps on the device between calls, carried
@@ -882,8 +964,10 @@ class _Step:
         def step_fn(state, tokens, targets, *carried):
             params, mom = state
             (loss, stats), grads = jax.value_and_grad(
-                _loss_and_stats, has_aux=True)(params, tokens, targets, cfg,
-                                               mesh)
+                _loss_and_stats, has_aux=True)(
+                    params, tokens, targets,
+                    _with_remat_chosen(cfg, mesh, param_sh, state,
+                                       tokens.shape), mesh)
             new_params, new_mom = _sgd_momentum(params, mom, grads,
                                                 learning_rate)
             return ((new_params, new_mom), loss) + tuple(

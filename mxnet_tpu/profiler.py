@@ -69,6 +69,7 @@ __all__ = [
     "record_compile", "compile_stats", "ensure_lane",
     "record_program", "program_records",
     "span", "step_span", "instrument_step", "train_step_stats",
+    "note_remat",
     "jax_compile_stats", "device_table",
 ]
 
@@ -1036,6 +1037,18 @@ _TRAIN = {"steps": 0,      # calls started
                            # call: 0 in a sound run
 # mxlint: disable=MX003 (deque.append/clear are atomic under the GIL; one writer, the calling thread of the step)
 _TRAIN_RING = collections.deque(maxlen=_RING_CAP)  # (host us, compiled?)
+# mxlint: disable=MX003 (three GIL-atomic stores while a step is traced; one writer, the tracing thread)
+_REMAT = {"remat_kept": [],             # names the layer remat keeps
+          "remat_kept_bytes": 0,        # ... on one device, all layers
+          "remat_budget_bytes": None}   # None: the device gave no limit
+
+
+def note_remat(names, kept_bytes, budget_bytes):
+    """What the newest train step traced keeps across its layer remat
+    (``parallel/transformer.py remat_choice``): a fact of the program and
+    not a count, so no reset clears it."""
+    _REMAT.update(remat_kept=list(names), remat_kept_bytes=int(kept_bytes),
+                  remat_budget_bytes=budget_bytes)
 
 
 class instrument_step:
@@ -1086,10 +1099,12 @@ class instrument_step:
 
 
 def train_step_stats():
-    """``metrics()['train_step']``: ``steps``, ``compiles``, ``retraces``
-    and ``calls``, the last 4096 calls as [host us inside the call,
-    compiled?], oldest first."""
-    out = dict(_TRAIN)
+    """``metrics()['train_step']``: ``steps``, ``compiles``, ``retraces``,
+    ``calls``, the last 4096 calls as [host us inside the call,
+    compiled?], oldest first, and what the newest step keeps across its
+    layer remat (``note_remat``)."""
+    out = dict(_TRAIN, **_REMAT)
+    out["remat_kept"] = list(out["remat_kept"])
     out["calls"] = [[us, bool(c)] for us, c in list(_TRAIN_RING)]
     return out
 

@@ -71,16 +71,22 @@ def test_bn_row_tiles_under_512_fit_and_lower(as_on_tpu, R, C, tr):
 
 @pytest.mark.skipif(len(jax.devices()) < 4,
                     reason="needs four virtual devices")
-def test_flash_inside_the_sharded_transformer_step_lowers(as_on_tpu):
+@pytest.mark.parametrize("limit,kernels", [(None, 4), (2 ** 34, 3)],
+                         ids=["no_limit", "room"])
+def test_flash_inside_the_sharded_transformer_step_lowers(
+        as_on_tpu, monkeypatch, limit, kernels):
     """GSPMD cannot partition a Mosaic kernel: on a dp x tp mesh the
     TPU lowering of the transformer step refused ("wrap the call in a
     shard_map") — seen first on four real chips, because on the CPU mesh
     attention takes the jnp reference. The step now runs the kernel per
-    (batch, head) shard; forward, its remat re-run, dq and dk/dv lower."""
+    (batch, head) shard; forward, its remat re-run, dq and dk/dv lower.
+    On a device that reports a limit with room the layer remat keeps the
+    forward kernel's output and row sums, and the re-run is gone."""
     import jax.numpy as jnp
     import jax.random as jr
     from mxnet_tpu.parallel import create_mesh
     from mxnet_tpu.parallel import transformer as T
+    monkeypatch.setattr(T, "_mesh_bytes_limit", lambda mesh: limit)
     mesh = create_mesh(devices=jax.devices()[:4], dp=2, tp=2)
     cfg = T.TransformerConfig(
         vocab_size=256, dim=256, n_layers=1, n_heads=2, ffn_hidden=512,
@@ -92,7 +98,7 @@ def test_flash_inside_the_sharded_transformer_step_lowers(as_on_tpu):
         toks = jax.ShapeDtypeStruct((4, 128), jnp.int32)
         exported = jax.export.export(step_fn, platforms=["tpu"])(
             (params, params), toks, toks)
-    assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == 4
+    assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == kernels
 
 
 def test_flash_with_groups_and_a_window_lowers_at_the_trinity_cells_shape(

@@ -5,8 +5,11 @@ The analogue of the reference's per-operator aggregate table
 step: ``profiler.set_state('run')`` writes an ``.xplane.pb``;
 ``device_table`` reads it back (``jax.profiler.ProfileData``, nothing else)
 and splits the train step's device time by the ``jax.named_scope`` names
-the program carries (``mx.embed`` ... ``mx.optimizer``), into forward,
-backward and recompute, with the Pallas kernels by their ``name=`` and the
+the program carries (``mx.embed`` ... ``mx.optimizer``; inside a layer
+``mx.attn_proj``, ``mx.flash``, ``mx.attn_out`` or, for a state-space mixer,
+``mx.ssm_proj``, ``mx.ssm_conv``, ``mx.ssm_scan``, ``mx.ssm_gate``; then
+``mx.ffn`` and an expert share's ``mx.moe_*``), into forward, backward and
+recompute, with the Pallas kernels by their ``name=`` and the
 program's own host spans beside them.
 
 How an event finds its scope. An "XLA Ops" event is named by its HLO

@@ -11,7 +11,8 @@ requirement — with capacity_factor bounding per-expert load.
 ``moe_share`` is the other kind of expert layer: one chip's share of an
 expert-parallel layer. It is told which experts it holds (a contiguous
 range), routes over all of them (sigmoid scores, top-k of score + bias,
-weights normalised over the k chosen), sorts the token-slots by expert, runs
+weights normalised over the k chosen; or the k largest logits and a softmax
+over those), sorts the token-slots by expert, runs
 grouped products over the slots of the experts held
 (``pallas_kernels/grouped_matmul.py``) and gathers the weighted results
 back. No capacity and no drops: the slot buffer holds every slot there is,
@@ -31,7 +32,7 @@ from jax import lax
 from .. import profiler as _profiler
 
 __all__ = ["top_k_routing", "moe_ffn", "MoELayer", "route_sigmoid",
-           "moe_share", "MOE_STATS"]
+           "route_topk_softmax", "moe_share", "MOE_STATS"]
 
 
 def top_k_routing(logits, k=2, capacity=None):
@@ -133,6 +134,16 @@ def route_sigmoid(h, router_w, bias, k, route_scale=1.0):
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
     return experts, weights * route_scale
+
+
+def route_topk_softmax(h, router_w, k):
+    """Logits over ALL experts in float32, the k largest chosen, weights a
+    softmax over those k logits; no bias. h: [T, D]; router_w: [D, E].
+    -> (experts [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    chosen, experts = lax.top_k(logits, k)
+    return experts, jax.nn.softmax(chosen, axis=-1)
 
 
 def buffer_rows(tokens, k, n_held, tile):
@@ -294,11 +305,13 @@ def _gated(x, w_gate, w_up, w_down, product):
 
 
 def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
-              first=0, route_scale=1.0, interpret=False):
+              first=0, route_scale=1.0, route="sigmoid", interpret=False):
     """The share of an expert layer that holds experts ``first`` to
     ``first + w_gate.shape[0]`` of the ``router_w.shape[1]`` routed over.
 
-    x: [B, S, D]; router_w: [D, E]; bias: [E]; w_gate, w_up: [held, D, F];
+    x: [B, S, D]; router_w: [D, E]; bias: [E] (``route`` "sigmoid":
+    ``route_sigmoid``; "topk_softmax": ``route_topk_softmax``, which has
+    none and takes no ``route_scale``); w_gate, w_up: [held, D, F];
     w_down: [held, F, D]; ``shared``: (w_gate [D, Fs], w_up, w_down
     [Fs, D]) of the shared expert, computed for every token.
     -> (y [B, S, D]: shared(x) + sum over the chosen experts HELD of weight
@@ -322,7 +335,11 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     how = (_gmm.TILE, bool(interpret)) if (interpret or on_tpu) and \
         _rows.fits(D, x.dtype, on_tpu and not interpret) else None
     with jax.named_scope("mx.moe_route"):
-        experts, weights = route_sigmoid(xt, router_w, bias, k, route_scale)
+        if route == "topk_softmax":
+            experts, weights = route_topk_softmax(xt, router_w, k)
+        else:
+            experts, weights = route_sigmoid(xt, router_w, bias, k,
+                                             route_scale)
     with jax.named_scope("mx.moe_dispatch"):
         plan = _plan(experts, first, n_held, _gmm.TILE)
         if how is not None:

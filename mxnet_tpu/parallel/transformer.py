@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
 import warnings
 from typing import Optional
@@ -45,6 +46,7 @@ from .compat import NamedSharding, PartitionSpec as P
 from .ring_attention import ring_attention, blockwise_attention
 from .ulysses import ulysses_attention_local
 from . import expert as _expert
+from . import ssm as _ssm
 from .expert import moe_ffn
 
 __all__ = ["TransformerConfig", "init_params", "apply", "loss_fn",
@@ -109,17 +111,19 @@ class TransformerConfig:
     n_kv_heads: Optional[int] = None
     # a head's size where it is not dim // n_heads
     head_size: Optional[int] = None
-    # one period of attention kinds, "sliding" (a query sees the last
-    # ``window`` keys) or "full"; the trunk scans over whole periods, the
-    # body holding the period's layers in turn. () = every layer "full",
-    # scanned one layer a step.
+    # one period of layer kinds: attention "sliding" (a query sees the last
+    # ``window`` keys) or "full", or "mamba" (a state-space mixer in
+    # attention's place, ``parallel/ssm.py``; its leaves are a stack of
+    # their own); the trunk scans over whole periods, the body holding the
+    # period's layers in turn. () = every layer "full", scanned one layer a
+    # step.
     layer_pattern: tuple = ()
     window: Optional[int] = None
     # attention kinds of the leading dense layers (gated FFN of width
     # ffn_hidden) that come before the scanned periods; only with a
     # layer_pattern. n_layers = len(dense_layers) + periods * len(pattern)
     dense_layers: tuple = ()
-    rope_on: str = "all"               # 'all' | 'sliding': which kinds rotate
+    rope_on: str = "all"       # 'all' | 'sliding' | 'none': which kinds rotate
     norm_eps: float = 1e-6
     qk_norm: bool = False              # RMSNorm of q and k over a head
     attn_gate: bool = False            # attention output * sigmoid(h W_g)
@@ -134,6 +138,28 @@ class TransformerConfig:
     experts_held: Optional[tuple] = None
     moe_shared: int = 0
     route_scale: float = 1.0
+    # how the share scores its experts: "sigmoid" (expert.route_sigmoid:
+    # a selection bias, weights normalised over the k) or "topk_softmax"
+    # (the k largest logits, a softmax over those k; no bias leaf)
+    route: str = "sigmoid"
+    # -- what a hybrid of mixers and attention needs, each defaulting to
+    # the program there was ---------------------------------------------------
+    residual_mult: float = 1.0         # x + this * (what a layer's half adds)
+    embed_mult: float = 1.0            # embedding * this
+    logit_mult: float = 1.0            # logits * this
+    attn_scale: Optional[float] = None  # scores * this (None: head ** -0.5)
+    # the head reads the embedding's rows (no ``w_out`` in the tree); the
+    # embedding's gradient is the sum of both uses
+    tied_head: bool = False
+    # the "mamba" layers' mixer: heads of ssm_head_size with a state of
+    # ssm_state each, one group; a causal conv of ssm_conv taps; the scan in
+    # chunks of ssm_chunk positions, its heads in blocks chosen from shapes
+    # (``ssm.block_heads``)
+    ssm_heads: int = 0
+    ssm_head_size: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
 
     @property
     def head_dim(self):
@@ -160,6 +186,14 @@ class TransformerConfig:
                 "n_layers=%d is not %d dense layers and whole periods of %d"
                 % (self.n_layers, len(self.dense_layers), p))
         return n // p
+
+    @property
+    def attn_layers(self):
+        """Layers with attention: all but the "mamba" ones."""
+        if "mamba" not in self.layer_pattern:
+            return self.n_layers
+        return len(self.dense_layers) + self.periods * sum(
+            k != "mamba" for k in self.layer_pattern)
 
 
 def _rms_norm(x, scale, eps):
@@ -189,22 +223,28 @@ def _ffn_kind(cfg, experts=True):
     return "gshard" if cfg.moe_hidden is None else "share"
 
 
-def _layer_leaves(cfg, experts=True):
+def _layer_leaves(cfg, experts=True, kind="full"):
     """{leaf: (shape of one layer, fan_in or None for a norm's scale or 0
-    for the router's bias, spec of one layer)}: the one table of a layer's
-    leaves, scanned (``experts``) or leading dense. The plain decoder's
-    nine are the rows that no option adds."""
+    for the router's bias or a name ``ssm.init_leaf`` knows, spec of one
+    layer)}: the one table of a layer's leaves, scanned (``experts``) or
+    leading dense, of a ``kind``: attention's rows for "full" and "sliding",
+    the mixer's for "mamba", the norms' and the feed-forward's for both.
+    The plain decoder's nine are the rows that no option adds."""
     D, H, G, Dh = cfg.dim, cfg.n_heads, cfg.kv_heads, cfg.head_dim
-    out = {"ln1": ((D,), None, (None,)),
-           "wq": ((D, H, Dh), D, (None, "tp", None)),
-           "wk": ((D, G, Dh), D, (None, "tp", None)),
-           "wv": ((D, G, Dh), D, (None, "tp", None)),
-           "wo": ((H, Dh, D), H * Dh, ("tp", None, None)),
-           "ln2": ((D,), None, (None,))}
-    if cfg.attn_gate:
-        out["w_attn_gate"] = ((D, H, Dh), D, (None, "tp", None))
-    if cfg.qk_norm:
-        out["q_norm"] = out["k_norm"] = ((Dh,), None, (None,))
+    if kind == "mamba":
+        out = {"ln1": ((D,), None, (None,)), **_ssm.mixer_leaves(cfg),
+               "ln2": ((D,), None, (None,))}
+    else:
+        out = {"ln1": ((D,), None, (None,)),
+               "wq": ((D, H, Dh), D, (None, "tp", None)),
+               "wk": ((D, G, Dh), D, (None, "tp", None)),
+               "wv": ((D, G, Dh), D, (None, "tp", None)),
+               "wo": ((H, Dh, D), H * Dh, ("tp", None, None)),
+               "ln2": ((D,), None, (None,))}
+        if cfg.attn_gate:
+            out["w_attn_gate"] = ((D, H, Dh), D, (None, "tp", None))
+        if cfg.qk_norm:
+            out["q_norm"] = out["k_norm"] = ((Dh,), None, (None,))
     if cfg.post_norms:
         out["ln1_post"] = out["ln2_post"] = ((D,), None, (None,))
     ffn = _ffn_kind(cfg, experts)
@@ -220,9 +260,10 @@ def _layer_leaves(cfg, experts=True):
                         "moe_w2": ((E, F, D), F, ("ep", "tp", None))})
         return out
     E, Fm, held = cfg.num_experts, cfg.moe_hidden, cfg.expert_share[1]
-    out.update({"moe_router": ((D, E), D, (None, None)),
-                "moe_bias": ((E,), 0, (None,)),
-                "moe_w_gate": ((held, D, Fm), D, (None, None, None)),
+    out["moe_router"] = ((D, E), D, (None, None))
+    if cfg.route == "sigmoid":
+        out["moe_bias"] = ((E,), 0, (None,))
+    out.update({"moe_w_gate": ((held, D, Fm), D, (None, None, None)),
                 "moe_w_up": ((held, D, Fm), D, (None, None, None)),
                 "moe_w_down": ((held, Fm, D), Fm, (None, None, None))})
     if cfg.moe_shared:
@@ -233,23 +274,44 @@ def _layer_leaves(cfg, experts=True):
     return out
 
 
+def _runs(cfg):
+    """The period as runs of adjacent layers that share a set of leaves:
+    [(the run's stack in the param tree, [the kinds of its layers])], in
+    order. The attention kinds share their leaves, a mixer has its own, so
+    ("sliding", "sliding", "full") is one run, ``layers``, and ("mamba" x5,
+    "full", "mamba" x4) three: ``mamba``, ``layers``, ``mamba_1``. A run is
+    a stack and not a slice of one: a slice of stacked weights that feeds a
+    scan is a copy, of the weights on the way in and of their gradients on
+    the way out. The plain decoder is one run of one "full" layer."""
+    seen, out = {}, []
+    for leaves, kinds in itertools.groupby(
+            cfg.layer_pattern or ("full",),
+            lambda kind: "mamba" if kind == "mamba" else "layers"):
+        n = seen[leaves] = seen.get(leaves, -1) + 1
+        out.append(("%s_%d" % (leaves, n) if n else leaves, list(kinds)))
+    return out
+
+
 def _stacks(cfg):
     """{stack of layers in the param tree: (shape of its leading axes, their
-    spec, whether its layers are the scanned ones)}. ``layers`` is [L, ...]
-    for the plain decoder ([pp, L/pp, ...] in explicit pipeline mode) and
-    [periods, P, ...] for a pattern model, one row a period and one column
-    a place in the pattern, with its leading dense layers [n, ...] beside."""
+    spec, whether its layers are the scanned ones, the kind whose leaves it
+    holds)}. ``layers`` is [L, ...] for the plain decoder ([pp, L/pp, ...]
+    in explicit pipeline mode); a pattern model has a stack [periods, n,
+    ...] for each run of its period (``_runs``), one row a period and one
+    column each of the run's n layers, with its leading dense layers [n,
+    ...] beside."""
     if cfg.layer_pattern:
-        out = {"layers": ((cfg.periods, len(cfg.layer_pattern)),
-                          (None, None), True)}
+        out = {name: ((cfg.periods, len(kinds)), (None, None), True,
+                      "mamba" if kinds[0] == "mamba" else "full")
+               for name, kinds in _runs(cfg)}
         if cfg.dense_layers:
-            out["dense"] = ((len(cfg.dense_layers),), (None,), False)
+            out["dense"] = ((len(cfg.dense_layers),), (None,), False, "full")
         return out
     if cfg.pp > 1:
         assert cfg.n_layers % cfg.pp == 0, "n_layers must divide pp"
         return {"layers": ((cfg.pp, cfg.n_layers // cfg.pp), ("pp", None),
-                           True)}
-    return {"layers": ((cfg.n_layers,), (None,), True)}
+                           True, "full")}
+    return {"layers": ((cfg.n_layers,), (None,), True, "full")}
 
 
 def init_params(key, cfg: TransformerConfig):
@@ -274,13 +336,16 @@ def init_params(key, cfg: TransformerConfig):
                 [*ks[:7], *jr.split(ks[7], 4)]))
         return keys
 
-    def stack(lead, experts, salt):
-        leaves = _layer_leaves(cfg, experts)
+    def stack(lead, experts, kind, salt):
+        leaves = _layer_leaves(cfg, experts, kind)
         keys = keys_of(leaves, salt)
         out = {}
         for name, (shape, fan_in, _) in leaves.items():
             if fan_in is None:
                 out[name] = jnp.ones(lead + shape, dt)
+            elif isinstance(fan_in, str):
+                out[name] = _ssm.init_leaf(keys[name], fan_in,
+                                           lead + shape).astype(dt)
             elif fan_in == 0:   # the router's bias: a buffer, N(0, 0.01^2)
                 out[name] = (jr.normal(keys[name], lead + shape)
                              * 0.01).astype(dt)
@@ -290,13 +355,16 @@ def init_params(key, cfg: TransformerConfig):
 
     emb_key, out_key = jr.split(jr.fold_in(key, 99))
     embed = norm(emb_key, (cfg.vocab_size, D), D)
+    scaled = cfg.embed_scale or cfg.embed_mult != 1.0
     params = {
-        "embed": embed if cfg.embed_scale else embed * (D ** 0.5),
+        "embed": embed if scaled else embed * (D ** 0.5),
         "ln_f": jnp.ones((D,), dt),
-        "w_out": norm(out_key, (D, cfg.vocab_size), D),
     }
-    for i, (name, (lead, _, experts)) in enumerate(_stacks(cfg).items()):
-        params[name] = stack(lead, experts, 1000 * (i + 1))  # layers, dense
+    if not cfg.tied_head:
+        params["w_out"] = norm(out_key, (D, cfg.vocab_size), D)
+    for i, (name, (lead, _, experts, kind)) in enumerate(
+            _stacks(cfg).items()):
+        params[name] = stack(lead, experts, kind, 1000 * (i + 1))
     return params
 
 
@@ -311,9 +379,11 @@ def param_specs(cfg: TransformerConfig):
     else:
         specs = {"embed": P("tp", None), "w_out": P(None, "tp")}
     specs["ln_f"] = P(None)
-    for name, (_, lead, experts) in _stacks(cfg).items():
+    if cfg.tied_head:
+        del specs["w_out"]
+    for name, (_, lead, experts, kind) in _stacks(cfg).items():
         specs[name] = {n: P(*(lead + spec)) for n, (_, _, spec)
-                       in _layer_leaves(cfg, experts).items()}
+                       in _layer_leaves(cfg, experts, kind).items()}
     return specs
 
 
@@ -327,12 +397,12 @@ def _attention(cfg, mesh, q, k, v, positions, window=None):
     qt = jnp.transpose(q, (0, 2, 1, 3))  # [B, H, S, Dh]
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
-    if (window is not None or kt.shape[1] != qt.shape[1]) \
-            and cfg.attn_mode != "local":
+    if (window is not None or kt.shape[1] != qt.shape[1]
+            or cfg.attn_scale is not None) and cfg.attn_mode != "local":
         raise NotImplementedError(
-            "grouped key-value heads and windows run in attn_mode='local' "
-            "only (the flash kernels); attn_mode=%r has neither"
-            % cfg.attn_mode)
+            "grouped key-value heads, windows and a scale of the scores run "
+            "in attn_mode='local' only (the flash kernels); attn_mode=%r has "
+            "none of them" % cfg.attn_mode)
     if cfg.attn_mode == "ring_flash" and mesh is not None:
         # inter-chip ppermute ring x intra-chip Pallas flash blocks,
         # differentiable both directions (parallel/ring_flash.py)
@@ -358,6 +428,8 @@ def _attention(cfg, mesh, q, k, v, positions, window=None):
             attend = functools.partial(flash_attention, causal=cfg.causal)
             if window is not None:
                 attend = functools.partial(attend, window=window)
+            if cfg.attn_scale is not None:
+                attend = functools.partial(attend, scale=cfg.attn_scale)
             sizes = _mesh_sizes(mesh)
             if any(n > 1 for n in sizes.values()):
                 # GSPMD cannot partition a Mosaic kernel ("wrap the call
@@ -379,7 +451,7 @@ def _attention(cfg, mesh, q, k, v, positions, window=None):
         else:
             from ..pallas_kernels.flash_attention import attention_reference
             ot = attention_reference(qt, kt, vt, causal=cfg.causal,
-                                     window=window)
+                                     scale=cfg.attn_scale, window=window)
     return jnp.transpose(ot, (0, 2, 1, 3))
 
 
@@ -400,9 +472,10 @@ def _ffn(cfg, lp, h, experts):
         shared = (lp["ws_gate"], lp["ws_up"], lp["ws_down"]) \
             if cfg.moe_shared else None
         y, stats = _expert.moe_share(
-            h, lp["moe_router"], lp["moe_bias"], lp["moe_w_gate"],
+            h, lp["moe_router"], lp.get("moe_bias"), lp["moe_w_gate"],
             lp["moe_w_up"], lp["moe_w_down"], shared, k=cfg.moe_k,
-            first=cfg.expert_share[0], route_scale=cfg.route_scale)
+            first=cfg.expert_share[0], route_scale=cfg.route_scale,
+            route=cfg.route)
         return y, stats, None
     if ffn == "gshard":
         y, balance = moe_ffn(h, lp["moe_router"], lp["moe_w1"], lp["moe_w2"],
@@ -414,11 +487,39 @@ def _ffn(cfg, lp, h, experts):
     return jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), None, None
 
 
+def _scaled(a, mult):
+    """a * mult, the product in float32 (0.22 held in bfloat16 is 0.2197);
+    ``a`` itself where mult is 1."""
+    if mult == 1.0:
+        return a
+    return (a.astype(jnp.float32) * mult).astype(a.dtype)
+
+
 def _layer_body(cfg, mesh, positions, x, lp, kind="full", experts=True):
-    """One transformer layer. x: [B, S, D]; lp: this layer's params
-    (``_layer_leaves(cfg, experts)``); ``kind``: its attention, "full" or
-    "sliding". -> (x, counters or None, balance loss or None) as ``_ffn``."""
+    """One layer. x: [B, S, D]; lp: this layer's params
+    (``_layer_leaves(cfg, experts, kind)``); ``kind``: its attention, "full"
+    or "sliding", or "mamba" for a mixer in attention's place.
+    -> (x, counters or None, balance loss or None) as ``_ffn``."""
     eps, sliding = cfg.norm_eps, kind == "sliding"
+    if kind == "mamba":
+        with jax.named_scope("mx.ssm_proj"):
+            h = _rms_norm(x, lp["ln1"], eps)
+        a = _ssm.mixer(h, lp, cfg)
+        with jax.named_scope("mx.ssm_proj"):
+            x = x + _scaled(a, cfg.residual_mult)
+    else:
+        x = _attend(cfg, mesh, positions, x, lp, sliding)
+    with jax.named_scope("mx.ffn"):
+        y, stats, balance = _ffn(cfg, lp, _rms_norm(x, lp["ln2"], eps),
+                                 experts)
+        if cfg.post_norms:
+            y = _rms_norm(y, lp["ln2_post"], eps)
+        return x + _scaled(y, cfg.residual_mult), stats, balance
+
+
+def _attend(cfg, mesh, positions, x, lp, sliding):
+    """x with what the layer's attention adds to it."""
+    eps = cfg.norm_eps
     with jax.named_scope("mx.attn_proj"):
         h = _rms_norm(x, lp["ln1"], eps)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
@@ -429,7 +530,7 @@ def _layer_body(cfg, mesh, positions, x, lp, kind="full", experts=True):
         if cfg.qk_norm:
             q = _rms_norm(q, lp["q_norm"], eps)
             k = _rms_norm(k, lp["k_norm"], eps)
-        if cfg.rope_on == "all" or sliding:
+        if cfg.rope_on == "all" or (sliding and cfg.rope_on == "sliding"):
             q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)),
                                     positions), (0, 2, 1, 3))
             k = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)),
@@ -443,13 +544,7 @@ def _layer_body(cfg, mesh, positions, x, lp, kind="full", experts=True):
         a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
         if cfg.post_norms:
             a = _rms_norm(a, lp["ln1_post"], eps)
-        x = x + a
-    with jax.named_scope("mx.ffn"):
-        y, stats, balance = _ffn(cfg, lp, _rms_norm(x, lp["ln2"], eps),
-                                 experts)
-        if cfg.post_norms:
-            y = _rms_norm(y, lp["ln2_post"], eps)
-        return x + y, stats, balance
+        return x + _scaled(a, cfg.residual_mult)
 
 
 def apply(params, tokens, cfg: TransformerConfig, mesh=None,
@@ -459,10 +554,17 @@ def apply(params, tokens, cfg: TransformerConfig, mesh=None,
     where no layer has one)."""
     x, _, balance = _hidden(params, tokens, cfg, mesh)
     with jax.named_scope("mx.head_ce"):
-        logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
+        logits = _scaled(jnp.einsum("bsd,dv->bsv", x, _w_out(params, cfg)),
+                         cfg.logit_mult)
     if return_aux:
         return logits, 0.0 if balance is None else balance
     return logits
+
+
+def _w_out(params, cfg):
+    """The head's matrix [D, V]: ``w_out``, or the embedding's rows where
+    the head is tied to them."""
+    return params["embed"].T if cfg.tied_head else params["w_out"]
 
 
 def _remat_policy(cfg):
@@ -486,7 +588,7 @@ def _remat_rows(cfg, batch, seq, sizes):
     remat may keep, the recompute saved per byte falling down the table.
     One row: the flash forward kernel's output [b, h, S, Dh] and float32
     row sums [b h, S], on a device's share of batch and heads as
-    ``_attention`` cuts them."""
+    ``_attention`` cuts them, for each layer that has attention."""
     dp, tp = sizes.get("dp", 1), sizes.get("tp", 1)
     b = batch // dp if batch % dp == 0 else batch
     h = cfg.n_heads // tp if cfg.n_heads % tp == 0 \
@@ -513,9 +615,9 @@ def remat_choice(cfg, batch, seq, state_bytes, grad_bytes, sizes, limit):
         limit - state_bytes - grad_bytes - inputs)))
     names, kept = (), 0
     for row, nbytes in _remat_rows(cfg, batch, seq, sizes):
-        if kept + cfg.n_layers * nbytes > budget:
+        if kept + cfg.attn_layers * nbytes > budget:
             break
-        names, kept = names + row, kept + cfg.n_layers * nbytes
+        names, kept = names + row, kept + cfg.attn_layers * nbytes
     return names, kept, budget
 
 
@@ -532,16 +634,19 @@ def _mesh_bytes_limit(mesh):
 
 def _hidden(params, tokens, cfg, mesh):
     """The trunk up to (but excluding) the output projection: embed, the
-    leading dense layers where the tree has them, a scan over
-    ``params["layers"]`` whose body runs one period's layers in turn, each
-    under the layer remat, and the final norm. The plain decoder is the
-    case of one "full" layer a period: the scanned slice is that layer's
-    leaves. -> (x [B, S, D], the expert shares' counters summed or None,
-    the GShard layers' balance loss summed or None)."""
+    leading dense layers where the tree has them, a scan over the period's
+    stacks (``_runs``) whose body runs one period's layers in turn, each
+    under the layer remat (a run of attention layers one after the other, a
+    run of mixers as an inner scan: one body traced however long the run),
+    and the final norm. The plain decoder is the case of one "full" layer a
+    period: the scanned slice is that layer's leaves. -> (x [B, S, D], the
+    expert shares' counters summed or None, the GShard layers' balance loss
+    summed or None)."""
     with jax.named_scope("mx.embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
         if cfg.embed_scale:
             x = (x * (cfg.dim ** 0.5)).astype(x.dtype)
+        x = _scaled(x, cfg.embed_mult)
     positions = jnp.arange(tokens.shape[1])
     zero = jnp.zeros(len(_expert.MOE_STATS), jnp.int32) \
         if _ffn_kind(cfg) == "share" else None
@@ -555,28 +660,47 @@ def _hidden(params, tokens, cfg, mesh):
     at = lambda tree, i: jax.tree_util.tree_map(  # noqa: E731
         lambda a: a[i], tree)
 
-    def period(x, lp):
-        stats, balance = zero, None
-        for j, kind in enumerate(cfg.layer_pattern or ("full",)):
-            x, one, b = layer(kind)(x, at(lp, j) if cfg.layer_pattern else lp)
+    runs = _runs(cfg)
+
+    def mixers(x, lp):
+        x, one, b = layer("mamba")(x, lp)
+        return x, (one, b)
+
+    def period(x, lps):
+        found = [zero, None]    # the counters and the balance loss so far
+
+        def note(one, b):
             if one is not None:
-                stats = _expert.merge_stats(stats, one)
+                found[0] = _expert.merge_stats(found[0], one)
             if b is not None:
-                balance = b if balance is None else balance + b
-        return x, (stats, balance)
+                found[1] = b if found[1] is None else found[1] + b
+
+        for name, kinds in runs:
+            if kinds[0] == "mamba" and len(kinds) > 1:
+                # a run of mixers: one body traced, scanned over its stack
+                x, (one, b) = lax.scan(mixers, x, lps[name])
+                note(None if one is None else _expert.sum_stats(one),
+                     None if b is None else jnp.sum(b))
+                continue
+            for j, kind in enumerate(kinds):
+                x, one, b = layer(kind)(
+                    x, at(lps[name], j) if cfg.layer_pattern else lps[name])
+                note(one, b)
+        return x, tuple(found)
 
     with jax.named_scope("mx.layer"):
         if "dense" in params:
             for i, kind in enumerate(cfg.dense_layers):
                 x, _, _ = layer(kind, False)(x, at(params["dense"], i))
-        x, (stats, balance) = lax.scan(period, x, params["layers"])
+        x, (stats, balance) = lax.scan(
+            period, x, {name: params[name] for name, _ in runs})
     with jax.named_scope("mx.head_ce"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     return (x, None if stats is None else _expert.sum_stats(stats),
             None if balance is None else jnp.sum(balance))
 
 
-def _chunked_ce(x, w_out, targets, n_chunks):
+def _chunked_ce(x, w_out, targets, n_chunks, logit_mult=1.0):
     """Mean token NLL with the vocab projection done per sequence chunk.
 
     lax.map runs chunks sequentially, and jax.checkpoint makes the
@@ -591,8 +715,9 @@ def _chunked_ce(x, w_out, targets, n_chunks):
     @jax.checkpoint
     def chunk_nll(args):
         xi, ti = args
-        logits = jnp.einsum("bcd,dv->bcv", xi, w_out,
-                            preferred_element_type=jnp.float32)
+        logits = _scaled(jnp.einsum("bcd,dv->bcv", xi, w_out,
+                                    preferred_element_type=jnp.float32),
+                         logit_mult)
         lse = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, ti[..., None], axis=-1)[..., 0]
         return jnp.sum(lse - tgt)
@@ -600,7 +725,7 @@ def _chunked_ce(x, w_out, targets, n_chunks):
     return jnp.sum(lax.map(chunk_nll, (xc, tc))) / (B * S)
 
 
-def _chunked_ce_local(x, w_out, targets, n_chunks, mesh):
+def _chunked_ce_local(x, w_out, targets, n_chunks, mesh, logit_mult=1.0):
     """Chunked CE with LOCAL unembedding-gradient accumulation — the
     SCALING_r05 fix. The plain ``_chunked_ce`` under GSPMD keeps the
     ``dw_out`` all-reduce INSIDE the chunk loop (scan carries must hold
@@ -632,8 +757,9 @@ def _chunked_ce_local(x, w_out, targets, n_chunks, mesh):
         @jax.checkpoint
         def chunk_nll(args):
             xi, ti = args
-            logits = jnp.einsum("bcd,dv->bcv", xi, wl,
-                                preferred_element_type=jnp.float32)
+            logits = _scaled(jnp.einsum("bcd,dv->bcv", xi, wl,
+                                        preferred_element_type=jnp.float32),
+                             logit_mult)
             if tp > 1:
                 # distributed logsumexp over the tp-sharded vocab; the
                 # max shift is numerics-only (its gradient contribution
@@ -735,18 +861,21 @@ def _loss_and_stats(params, tokens, targets, cfg, mesh, aux_weight=0.01):
             % (cfg.loss_chunks, tokens.shape[1]))
     x, stats, balance = _hidden(params, tokens, cfg, mesh)
     with jax.named_scope("mx.head_ce"):
+        w_out = _w_out(params, cfg)
         if cfg.loss_chunks <= 1:
-            logits = jnp.einsum("bsd,dv->bsv", x, params["w_out"])
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            logits = jnp.einsum("bsd,dv->bsv", x, w_out)
+            logp = jax.nn.log_softmax(_scaled(logits.astype(jnp.float32),
+                                              cfg.logit_mult), axis=-1)
             ll = jnp.take_along_axis(logp, targets[..., None],
                                      axis=-1)[..., 0]
             loss = -jnp.mean(ll)
         elif ce_local_accum_active(cfg, mesh, tokens.shape[0],
                                    tokens.shape[1]):
-            loss = _chunked_ce_local(x, params["w_out"], targets,
-                                     cfg.loss_chunks, mesh)
+            loss = _chunked_ce_local(x, w_out, targets, cfg.loss_chunks,
+                                     mesh, cfg.logit_mult)
         else:
-            loss = _chunked_ce(x, params["w_out"], targets, cfg.loss_chunks)
+            loss = _chunked_ce(x, w_out, targets, cfg.loss_chunks,
+                               cfg.logit_mult)
     if balance is not None:
         loss = loss + aux_weight * balance  # GShard load-balance pressure
     return loss, stats
@@ -963,6 +1092,8 @@ class _Step:
             donate_argnums=(0,) + tuple(range(3, 3 + self._n_carried)))
         def step_fn(state, tokens, targets, *carried):
             params, mom = state
+            if "mamba" in cfg.layer_pattern:
+                _ssm.note(cfg, *tokens.shape)
             (loss, stats), grads = jax.value_and_grad(
                 _loss_and_stats, has_aux=True)(
                     params, tokens, targets,

@@ -140,11 +140,22 @@ def test_the_norms_eps_is_the_configurations():
 _PLAIN = dict(vocab_size=64, dim=32, n_layers=4, n_heads=4, ffn_hidden=48)
 
 
+def _granite():
+    """The tiny share of mixers and an attention layer, as its file states
+    it: three stacks, two sets of leaves, no ``w_out``."""
+    from chipbench.models import granite_hybrid as adapter
+    with open(os.path.join(ROOT, "tests", "chipbench", "tiny_granite",
+                           "configs", "tiny_granite.json")) as f:
+        m = json.load(f)
+    return adapter.transformer_config(m, m["assumed"], 128)
+
+
 @pytest.mark.parametrize("make", [
     lambda: T.TransformerConfig(**_PLAIN),
     lambda: T.TransformerConfig(pp=2, **_PLAIN),
     lambda: T.TransformerConfig(num_experts=4, **_PLAIN),
-    lambda: _tiny()[2]], ids=["plain", "plain_pp2", "gshard", "share"])
+    lambda: _tiny()[2], lambda: _granite()], ids=[
+        "plain", "plain_pp2", "gshard", "share", "mixers"])
 def test_the_table_is_the_only_writer_of_a_layers_leaves(make):
     cfg = make()
     shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), jr.PRNGKey(0))
@@ -155,8 +166,8 @@ def test_the_table_is_the_only_writer_of_a_layers_leaves(make):
     for leaf, spec in zip(jax.tree_util.tree_leaves(shapes),
                           jax.tree_util.tree_leaves(specs, is_leaf=is_spec)):
         assert len(spec) == leaf.ndim
-    for stack, (lead, _, experts) in T._stacks(cfg).items():
-        table = T._layer_leaves(cfg, experts)
+    for stack, (lead, _, experts, kind) in T._stacks(cfg).items():
+        table = T._layer_leaves(cfg, experts, kind)
         assert {n: lead + shape for n, (shape, _, _) in table.items()} == \
             {n: v.shape for n, v in shapes[stack].items()}
 

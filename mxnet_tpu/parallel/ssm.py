@@ -20,10 +20,20 @@ The decay of one chunk is a [chunk, chunk] float32 array a head and a chunk:
 the heads go in blocks (``block_heads``, from shapes), each block under a
 remat of its own: what the backward keeps of a block is its inputs.
 
+Between ``in_proj`` and ``out_proj`` every [tokens, channels] array crosses
+HBM in the activations' type; float32 lives inside a fusion. Two places
+need help to keep that rule. The conv (``conv_silu``) shifts x in its own
+type and has a backward of its own: JAX's derivative of the shifted sum
+keeps a padded float32 copy of x and four float32 products. And the scan's
+output is handed to the gate behind a ``lax.optimization_barrier``: XLA
+otherwise hoists the gate's cast to float32 above the change of layout from
+the scan's blocks to [tokens, channels], and moves the array three times at
+twice the width, in the forward and again in the layer's recompute.
+
 Scopes: ``mx.ssm_proj`` (the products in and out, the layer's first norm),
 ``mx.ssm_conv``, ``mx.ssm_scan`` (dt, decays, the chunked scan, the D skip),
 ``mx.ssm_gate`` (gate and norm). ``metrics()["ssm"]`` says how the newest
-step traced runs its scan.
+step traced runs its scan, and the least bytes its conv and gate must move.
 """
 from __future__ import annotations
 
@@ -33,8 +43,8 @@ from jax import lax
 
 from .. import profiler as _profiler
 
-__all__ = ["conv_taps", "chunked_scan", "block_heads", "scan_temp_bytes",
-           "mixer", "mixer_leaves"]
+__all__ = ["conv_taps", "conv_silu", "chunked_scan", "block_heads",
+           "scan_temp_bytes", "stage_bytes", "mixer", "mixer_leaves"]
 
 # the largest temporary a block of heads may make (its decays): the
 # backward holds a few of that size at once, 0.3 GB of them at 8192 tokens
@@ -73,23 +83,84 @@ def init_leaf(key, how, shape):
     return step + jnp.log(-jnp.expm1(-step))     # softplus(this) == step
 
 
+def _pre_activation(xp, w, b, seq):
+    """b + sum_k w[k] xp[t + k] for ``seq`` positions t, tap by tap in that
+    order. ``xp`` is x already padded, in whatever type: each shifted view is
+    cast to float32 inside the sum, and since a shift and a cast commute the
+    values do not depend on which came first. -> float32 [B, seq, C]."""
+    w = w.astype(jnp.float32)
+    u = b.astype(jnp.float32)
+    for k in range(w.shape[0]):
+        u = u + w[k] * xp[:, k:k + seq].astype(jnp.float32)
+    return u
+
+
 def conv_taps(x, w, b):
     """Causal depthwise convolution as shifted multiply-adds. x: [B, S, C];
     w: [K, C]; b: [C]. y[t] = b + sum_k w[k] x[t - (K - 1) + k], positions
-    before the sequence reading nought. -> float32 [B, S, C]."""
+    before the sequence reading nought. The plain form, x cast to float32
+    and then padded: what ``conv_silu`` is held to. -> float32 [B, S, C]."""
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (w.shape[0] - 1, 0), (0, 0)))
+    return _pre_activation(xp, w, b, x.shape[1])
+
+
+@jax.custom_vjp
+def conv_silu(x, w, b):
+    """``silu(conv_taps(x, w, b))`` in x's type, to the bit, with x padded
+    in ITS OWN type (no float32 copy of it is made) and a backward written
+    out: what crosses HBM is x, y, dy and dx in x's type and ONE float32
+    array (``ds``); the pre-activation is recomputed from x. JAX's own
+    derivative of ``conv_taps`` keeps five float32 [B, S, C] arrays."""
+    front = ((0, 0), (w.shape[0] - 1, 0), (0, 0))
+    return jax.nn.silu(_pre_activation(jnp.pad(x, front), w, b,
+                                       x.shape[1])).astype(x.dtype)
+
+
+def _conv_silu_fwd(x, w, b):
+    return conv_silu(x, w, b), (x, w, b)
+
+
+def _conv_silu_bwd(res, dy):
+    """With u the pre-activation and ds = dy silu'(u):
+    dx[t] = sum_k w[k] ds[t + (K-1) - k], dw[k] = sum_t ds[t] x[t - (K-1) + k],
+    db = sum_t ds[t]. x and dy are padded in their own type by K-1 positions
+    past the sequence's end too, where dy, and so ds, reads nought: every
+    shift is then a slice, none leaves its own sequence, and no float32 array
+    is padded."""
+    x, w, b = res
     taps, seq = w.shape[0], x.shape[1]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    y = b.astype(jnp.float32)
-    for k in range(taps):
-        y = y + w[k] * xp[:, k:k + seq]
-    return y
+    ext = seq + taps - 1
+    xp = jnp.pad(x, ((0, 0), (taps - 1, taps - 1), (0, 0)))
+    u = _pre_activation(xp, w, b, ext)
+    sig = jax.nn.sigmoid(u)
+    ds = jnp.pad(dy, ((0, 0), (0, taps - 1), (0, 0))).astype(jnp.float32) \
+        * (sig * (1.0 + u * (1.0 - sig)))
+    wf = w.astype(jnp.float32)
+    dx = sum(wf[k] * ds[:, taps - 1 - k:taps - 1 - k + seq]
+             for k in range(taps))
+    dw = jnp.stack([jnp.sum(ds * xp[:, k:k + ext].astype(jnp.float32),
+                            axis=(0, 1)) for k in range(taps)])
+    return (dx.astype(x.dtype), dw.astype(w.dtype),
+            jnp.sum(ds, axis=(0, 1)).astype(b.dtype))
+
+
+conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 def scan_temp_bytes(batch, seq, chunk, heads):
     """Bytes of the scan's largest temporary for ``heads`` heads at once:
     their decays, float32 [batch, chunks, heads, chunk, chunk]."""
     return 4 * batch * seq * min(chunk, seq) * heads
+
+
+def stage_bytes(batch, seq, channels, inner, itemsize):
+    """The least bytes one layer's conv and gate move through HBM, forward
+    and backward, with every array in the activations' type: the conv reads
+    x and writes y, its backward reads x and dy and writes dx (five arrays
+    of ``channels``); the gate reads y and z and writes g, its backward
+    reads y, z and dout and writes dy and dz (eight of ``inner``). What the
+    layer's recompute runs again (the five forward arrays) is not in it."""
+    return itemsize * batch * seq * (5 * channels + 8 * inner)
 
 
 def block_heads(batch, seq, chunk, heads):
@@ -199,8 +270,7 @@ def mixer(h, lp, cfg):
         xbc = zxbcdt[..., inner:2 * inner + 2 * N]
         dt = zxbcdt[..., 2 * inner + 2 * N:]
     with jax.named_scope("mx.ssm_conv"):
-        xbc = jax.nn.silu(conv_taps(xbc, lp["ssm_conv_w"],
-                                    lp["ssm_conv_b"])).astype(h.dtype)
+        xbc = conv_silu(xbc, lp["ssm_conv_w"], lp["ssm_conv_b"])
     with jax.named_scope("mx.ssm_scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32)
                              + lp["ssm_dt_bias"].astype(jnp.float32))
@@ -210,9 +280,11 @@ def mixer(h, lp, cfg):
             xbc[..., inner:inner + N], xbc[..., inner + N:], lp["ssm_d"],
             cfg.ssm_chunk)
     with jax.named_scope("mx.ssm_gate"):
+        # handed over as the array it is, or XLA hoists the cast below above
+        # the copies out of the scan's blocks (the module's docstring)
+        y = lax.optimization_barrier(y.reshape(B, S, inner))
         # gate first, then the norm over ALL channels (one group)
-        g = y.reshape(B, S, inner).astype(jnp.float32) \
-            * jax.nn.silu(z.astype(jnp.float32))
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
         var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
         g = (g * lax.rsqrt(var + cfg.norm_eps)).astype(h.dtype) \
             * lp["ssm_norm"]
@@ -222,23 +294,30 @@ def mixer(h, lp, cfg):
 
 # metrics()["ssm"]: how the newest step traced runs its mixers' scans
 # mxlint: disable=MX003 (GIL-atomic stores while a step is traced; one writer, the tracing thread)
-_SSM = {"layers": 0, "chunk": 0, "heads_at_once": 0, "scan_temp_bytes": 0}
+_SSM = {"layers": 0, "chunk": 0, "heads_at_once": 0, "scan_temp_bytes": 0,
+        "stage_bytes": 0}
 
 
 def note(cfg, batch, seq):
     """Called while a step of ``cfg`` on [batch, seq] tokens is traced: a
     fact of the program and not a count, so no reset clears it."""
     chunk, hb = _blocks(batch, seq, cfg.ssm_heads, cfg.ssm_chunk)
+    inner = cfg.ssm_heads * cfg.ssm_head_size
     _SSM.update(layers=sum(k == "mamba" for k in cfg.layer_pattern)
                 * cfg.periods, chunk=chunk, heads_at_once=hb,
-                scan_temp_bytes=scan_temp_bytes(batch, seq, chunk, hb))
+                scan_temp_bytes=scan_temp_bytes(batch, seq, chunk, hb),
+                stage_bytes=stage_bytes(batch, seq, inner + 2 * cfg.ssm_state,
+                                        inner, jnp.dtype(cfg.dtype).itemsize))
 
 
 def ssm_stats():
     """``metrics()['ssm']``: of the newest train step traced that has
     mixers: ``layers`` (mixer layers), ``chunk`` (positions a chunk),
     ``heads_at_once`` (heads a block of the scan), ``scan_temp_bytes`` (the
-    scan's largest temporary, by shapes). Noughts where no step has any."""
+    scan's largest temporary, by shapes), ``stage_bytes`` (the least bytes a
+    layer's conv and gate move, ``stage_bytes``: what a trace's
+    ``mx.ssm_conv`` and ``mx.ssm_gate`` milliseconds are read against).
+    Noughts where no step has any."""
     return dict(_SSM)
 
 

@@ -7,6 +7,12 @@ valid in interpret mode only, which is how the fused-BatchNorm stats
 kernel shipped: at ResNet-50's last stage its output block was (2, 256).
 What the Mosaic compiler itself says (VMEM, layouts) only the chip can
 tell; that is chip_smoke.py phase B, over this same table.
+
+The last test goes one step further and COMPILES, for a v5e that is
+described and not attached (``jax.experimental.topologies``): what XLA makes
+of the state-space mixer's gradient at the published widths, by the bytes
+its program moves. It is the only test that can see XLA undo what
+``parallel/ssm.py`` does about float32 arrays between the mixer's products.
 """
 import importlib
 import os
@@ -178,3 +184,74 @@ def test_the_expert_shares_row_movements_lower_at_the_trinity_cells_shape():
     assert text.count(chip_smoke.MOSAIC_CALL) == 8
     for name in ("mx_moe_pack", "mx_moe_gather", "mx_moe_sum"):
         assert name in text, name
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One chip of a described v5e as a sharding; the tests that ask for it
+    are skipped where no such topology can be described. Made here and not
+    at import: one process at a time may load the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the plugin raises where it cannot
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_mixers_gradient_moves_its_arrays_in_bfloat16_on_a_v5e(one_v5e):
+    """granite-4.0-h-small's mixer (4096 -> 128 heads of 64, state 128, four
+    taps) on 1 x 8192 tokens in bfloat16: its vjp under ``jax.checkpoint``,
+    compiled for the described chip. At the parent of PR 35 the program read
+    14.72 GB and held 2.07 GB of temporaries: the conv kept a padded float32
+    copy of x and four float32 products, and XLA hoisted the gate's cast
+    above the copies that take the scan's blocks to [tokens, channels]. Now
+    10.71 GB and 0.97 GB (of the bytes 0.83 GB are the cost analysis
+    counting ``ds``, the one float32 array the conv's backward writes, once
+    for each of its four shifted reads); either half undone reads 12.6 GB or
+    more."""
+    import re
+    import types
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.parallel import ssm
+    cfg = types.SimpleNamespace(dim=4096, ssm_heads=128, ssm_head_size=64,
+                                ssm_state=128, ssm_conv=4, ssm_chunk=256,
+                                norm_eps=1e-5)
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_v5e)
+
+    leaves = {n: spec(shape)
+              for n, (shape, _, _) in ssm.mixer_leaves(cfg).items()}
+    h = spec((1, 8192, 4096))
+
+    def grads(h, lp, dout):
+        return jax.vjp(jax.checkpoint(
+            lambda h, lp: ssm.mixer(h, lp, cfg)), h, lp)[1](dout)
+
+    # a program compiled for a described chip cannot be read back from the
+    # persistent cache without one: keep it out, and the warning with it
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(grads).lower(h, leaves, h).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert compiled.cost_analysis()["bytes accessed"] < 11.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+    # the entry computation's own instructions (a fusion's inside never
+    # reaches HBM) under the two scopes: none is float32 [tokens, channels],
+    # in the scan's blocks or out of them
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    wide = re.compile(r" = \(?f32\[1,8192,8448\]| = \(?f32\[1,8192,8192\]"
+                      r"| = \(?f32\[1,32,256,16,8,64\]")
+    under = [line for line in entry.splitlines()
+             if "mx.ssm_conv" in line or "mx.ssm_gate" in line]
+    assert len(under) > 10
+    assert [line.strip()[:120] for line in under if wide.search(line)] == []

@@ -1,7 +1,10 @@
 """The state-space mixer's parts (``parallel/ssm.py``): the chunked scan
 against the recurrence it stands for, position by position; the conv as
-shifted multiply-adds against its explicit sum; how the heads go in
-blocks."""
+shifted multiply-adds against its explicit sum, and ``conv_silu`` (shifts in
+the activations' type, a backward of its own) against that; how the heads go
+in blocks; what the mixer's gradient pads."""
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -95,6 +98,113 @@ def test_the_conv_is_its_explicit_sum_from_the_sequences_start():
     assert onp.max(onp.abs(got - want)) < 1e-5
     # position 0 sees itself alone, through the LAST tap
     assert onp.max(onp.abs(got[:, 0] - onp.asarray(b + w[3] * x[:, 0]))) < 1e-6
+
+
+def _conv_reference(x, w, b):
+    """What ``conv_silu`` replaced in the mixer, and JAX's derivative of it."""
+    return jax.nn.silu(ssm.conv_taps(x, w, b)).astype(x.dtype)
+
+
+def _conv_inputs(dtype, batch, seq, stack=None, channels=5, taps=4):
+    k = jax.random.split(jax.random.PRNGKey(seq), 4)
+    lead = () if stack is None else (stack,)
+    return (jax.random.normal(k[0], (batch, seq, channels)).astype(dtype),
+            jax.random.normal(k[1], lead + (taps, channels)).astype(dtype),
+            jax.random.normal(k[2], lead + (channels,)).astype(dtype),
+            jax.random.normal(k[3], (batch, seq, channels)).astype(dtype))
+
+
+def _under(how, conv):
+    """``conv`` as the step runs it: as it is, under a remat, or as the body
+    of a scan over stacked leaves (each layer's output the next one's x)."""
+    if how == "checkpoint":
+        return jax.checkpoint(conv)
+    if how != "scan":
+        return conv
+
+    def scanned(x, w, b):
+        return jax.lax.scan(lambda x, wb: (conv(x, *wb), None), x, (w, b))[0]
+    return scanned
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("how,batch,seq", [
+    ("two_sequences", 2, 9), ("shorter_than_the_taps", 1, 2),
+    ("one_chunk", 2, Q), ("checkpoint", 2, 9), ("scan", 2, 9)])
+def test_conv_silu_is_the_shifted_sum_and_its_derivative(how, batch, seq,
+                                                         dtype):
+    x, w, b, dy = _conv_inputs(dtype, batch, seq, 3 if how == "scan" else None)
+    got_fn, want_fn = _under(how, ssm.conv_silu), _under(how, _conv_reference)
+    got, back = jax.vjp(got_fn, x, w, b)
+    want, want_back = jax.vjp(want_fn, x, w, b)
+    # the forward to the bit: a shift and a cast commute
+    assert got.dtype == x.dtype
+    assert onp.array_equal(onp.asarray(got.astype(jnp.float32)),
+                           onp.asarray(want.astype(jnp.float32)))
+    # float32: the same derivative in another order; bfloat16: each side
+    # rounds its float32 result once
+    tol = 1e-5 if dtype == "float32" else 2 * 2.0 ** -8
+    for name, g, r in zip("xwb", back(dy), want_back(dy)):
+        assert g.dtype == r.dtype and g.shape == r.shape, name
+        g, r = g.astype(jnp.float32), r.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(g - r))) <= tol * float(
+            jnp.max(jnp.abs(r))), name
+    if batch == 2:
+        # nothing passes from one sequence of a batch to the other
+        other = x.at[1].set(-x[1])
+        assert bool(jnp.all(got_fn(other, w, b)[0] == got[0]))
+        dx = jax.grad(lambda x: jnp.sum(jnp.sin(
+            got_fn(x, w, b)[0].astype(jnp.float32))))(x)
+        assert float(jnp.max(jnp.abs(dx[0]))) > 0.0
+        assert not bool(jnp.any(dx[1]))
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_the_mixers_gradient_pads_nothing_in_float32():
+    """Between its products the mixer's [tokens, channels] arrays stay in
+    the activations' type: the shifts are pads of bfloat16 arrays, forward
+    and backward, and the scan's output reaches the gate behind a barrier."""
+    cfg = types.SimpleNamespace(dim=32, ssm_heads=4, ssm_head_size=8,
+                                ssm_state=16, ssm_conv=4, ssm_chunk=Q,
+                                norm_eps=1e-5)
+    keys = jax.random.split(jax.random.PRNGKey(0), 9)
+    lp = {n: jax.random.normal(k, shape).astype(jnp.bfloat16)
+          for k, (n, (shape, _, _)) in zip(keys,
+                                           ssm.mixer_leaves(cfg).items())}
+    h = jax.random.normal(keys[8], (2, 2 * Q, 32)).astype(jnp.bfloat16)
+
+    def loss(h, lp):
+        out = jax.checkpoint(lambda h, lp: ssm.mixer(h, lp, cfg))(h, lp)
+        return jnp.sum(out.astype(jnp.float32))
+
+    eqns = list(_equations(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1)))(h, lp).jaxpr))
+    pads = [e for e in eqns if e.primitive.name == "pad"]
+    # x in the forward, x again and dy in the backward, once more each in
+    # the remat's re-run of the forward
+    assert len(pads) >= 3
+    assert {str(e.outvars[0].aval.dtype) for e in pads} == {"bfloat16"}
+    barriers = [e for e in eqns
+                if e.primitive.name == "optimization_barrier"]
+    assert barriers and all(
+        v.aval.dtype == jnp.bfloat16 and v.aval.shape == (2, 2 * Q, 32)
+        for e in barriers for v in e.outvars)
+
+
+def test_the_least_bytes_of_conv_and_gate_at_the_published_widths():
+    # 8192 tokens, 8448 conv channels, 8192 gated: five and eight passes
+    assert ssm.stage_bytes(1, 8192, 8448, 8192, 2) \
+        == 2 * 8192 * (5 * 8448 + 8 * 8192) == 1_765_801_984
 
 
 def test_the_heads_go_in_blocks_chosen_from_shapes():

@@ -140,21 +140,52 @@ def test_the_references_planted_faults_move_the_loss(tiny, variant):
     assert abs(broken - sound) > 1e-6 * sound
 
 
-def test_three_steps_losses_and_the_scans_facts(tiny):
-    import mxnet_tpu as mx
-    from chipbench.models import granite_hybrid as adapter
-    from chipbench.reference import granite_hybrid as R
-    m, cfg, weights, tokens, targets = tiny
+# The tiny step's first three losses at the parent commit (40fff6e, before
+# ``ssm.conv_silu`` and the barrier before the gate; this machine's CPU, JAX
+# 0.9.0, learning rate 1.0, seed 3000000019, batch 2 x 128): the forward is
+# the parent's arithmetic in the parent's order, and at this size the
+# backward's other order of sums does not reach a loss's last bit either.
+PARENT_LOSSES = {
+    "float32": ["0x1.62e1880000000p+2", "0x1.62ba300000000p+2",
+                "0x1.626f820000000p+2"],
+    "bfloat16": ["0x1.62e0f80000000p+2", "0x1.62c03e0000000p+2",
+                 "0x1.627a9c0000000p+2"]}
+
+
+def _three_steps(cfg, weights, tokens, targets):
+    """-> (the first three losses of the step at learning rate 1, the step:
+    ``metrics()["moe"]`` counts the steps alive)."""
     mesh = create_mesh(devices=jax.devices()[:1], dp=1)
     _, step = T.make_train_step(cfg, mesh, learning_rate=1.0)
     state = (jax.tree_util.tree_map(jnp.copy, weights),     # the step donates
              jax.tree_util.tree_map(jnp.zeros_like, weights))
-    before = mx.profiler.metrics()["moe"]
     losses = []
     with mesh.mesh:
         for _ in range(3):
             state, loss = step(state, tokens, targets)
             losses.append(float(loss))
+    return losses, step
+
+
+def test_in_bfloat16_the_first_three_losses_are_the_parents(tiny):
+    from chipbench.models import granite_hybrid as adapter
+    m, _, _, tokens, targets = tiny
+    cfg = adapter.transformer_config(m, dict(m["assumed"], dtype="bfloat16"),
+                                     128)
+    weights = adapter.make_weights(m, adapter.seed_words(3000000019),
+                                   jnp.bfloat16)
+    losses, _ = _three_steps(cfg, weights, tokens, targets)
+    assert [x.hex() for x in losses] == PARENT_LOSSES["bfloat16"]
+
+
+def test_three_steps_losses_and_the_scans_facts(tiny):
+    import mxnet_tpu as mx
+    from chipbench.models import granite_hybrid as adapter
+    from chipbench.reference import granite_hybrid as R
+    m, cfg, weights, tokens, targets = tiny
+    before = mx.profiler.metrics()["moe"]
+    losses, step = _three_steps(cfg, weights, tokens, targets)
+    assert [x.hex() for x in losses] == PARENT_LOSSES["float32"]
     fresh = lambda: jax.tree_util.tree_map(jnp.copy, weights)  # noqa: E731
     want = R.train(fresh, [(tokens, targets)] * 3, 1.0, 3,
                    adapter.runs_of(m), _model(m), block=64)
@@ -164,7 +195,9 @@ def test_three_steps_losses_and_the_scans_facts(tiny):
     # the scan's facts, set while the step was traced
     assert mx.profiler.metrics()["ssm"] == {
         "layers": 3, "chunk": 32, "heads_at_once": 8,
-        "scan_temp_bytes": 4 * 2 * 128 * 32 * 8}
+        "scan_temp_bytes": 4 * 2 * 128 * 32 * 8,
+        # float32, 2 x 128 tokens, 8 heads of 16 and a state of 16
+        "stage_bytes": 4 * 2 * 128 * (5 * (128 + 2 * 16) + 8 * 128)}
     after = mx.profiler.metrics()["moe"]
     assert after["layers"] - before["layers"] == 3 * 4   # every layer routes
     assert after["slots_dropped"] == 0

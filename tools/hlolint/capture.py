@@ -94,9 +94,12 @@ def _dryrun_one(mesh, steps=4, seed=0):
     from mxnet_tpu.gluon import nn
 
     rs = onp.random.RandomState(seed)
-    net = nn.HybridSequential()
-    net.add(nn.Dense(16, activation="relu", in_units=12))
-    net.add(nn.Dense(4, in_units=16))
+    # named here and not by the process's running count of layers: the
+    # step's signature follows the parameters' sorted names, and "dense10_"
+    # sorts before "dense9_"
+    net = nn.HybridSequential(prefix="dryrun_")
+    net.add(nn.Dense(16, activation="relu", in_units=12, prefix="dryrun_0_"))
+    net.add(nn.Dense(4, in_units=16, prefix="dryrun_1_"))
     net.initialize()
     net.hybridize()
     for _, p in sorted(net.collect_params().items()):

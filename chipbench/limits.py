@@ -7,7 +7,14 @@ decided"); not part of a benchmark run.
 For each seed, in one process: the program's first steps through the timed
 entry (no measured window: training's readings need none) against the
 reference; then, for each variant, the reference computed that way and put
-in the program's place. One JSON line a seed.
+in the program's place. One JSON line a seed, every leaf's norms in it, so
+that a rule of check.py can be tried on readings already taken.
+
+The variants are the reference's own (chipbench/reference/<kind>.py says
+which a kind has): the control ``fp8``; the planted faults ``half_batch``
+and ``unchanged`` (every kind), ``no_window`` (afmoe), ``state_dropped`` and
+``leaf_unchanged`` (granite_hybrid: one leaf's update dropped, which only
+the worst leaf's change sees), ``expert_missing`` (both expert kinds).
 """
 import argparse
 import importlib
@@ -36,7 +43,8 @@ def readings(workload, seed, variants, devices, root=ROOT):
     t1 = time.perf_counter()
     reference = cell.reference(steps)
     t2 = time.perf_counter()
-    vals, worst = check.numbers(program, reference)
+    floor = spec["limits"].get("moved_floor", 0)
+    vals, worst = check.numbers(program, reference, floor)
     out = {"workload": workload, "seed": seed, "program": vals,
            "program_worst_leaf": worst, "program_s": t1 - t0,
            "reference_s": t2 - t1, "reference_loss": reference["loss"],
@@ -44,10 +52,12 @@ def readings(workload, seed, variants, devices, root=ROOT):
            "reference_grad_norm": reference["grad_norm"],
            "program_grad_norm": program["grad_norm"],
            "reference_delta_norm": reference["delta_norm"],
-           "program_delta_norm": program["delta_norm"]}
+           "program_delta_norm": program["delta_norm"],
+           "reference_moved": reference["moved"],
+           "left_out": check.left_out(reference, floor)}
     for v in variants:
         made = cell.reference(steps, v)
-        vals, worst = check.numbers(made, reference)
+        vals, worst = check.numbers(made, reference, floor)
         out[v] = vals
         out[v + "_worst_leaf"] = worst
         out[v + "_loss"] = made["loss"]

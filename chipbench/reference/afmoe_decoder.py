@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from chipbench.reference.dense_decoder import (
-    _f32, _mm, _rope, _sgd, _sq_diff, _zeros_like_f32)
+    _f32, _mm, _moved, _rope, _sgd, _sq_diff, _zeros_like_f32)
 
 Model = collections.namedtuple(
     "Model", "eps window k route_scale first theta")
@@ -298,11 +298,16 @@ def train(make_weights, batches, lr, steps, kinds, m, variant="exact",
     delta = {n: float(_sq_diff(top[n], jax.device_put(w0[n], home)))
              for n in TOP}
     delta.update(dict.fromkeys(names, 0.0))
+    moved = {n: int(_moved(top[n], jax.device_put(w0[n], home)))
+             for n in TOP}
+    moved.update(dict.fromkeys(names, 0))
     for (group, _, lp), (_, _, lp0) in zip(layers, split_layers(w0, kinds)):
         for n in lp:
             if n not in BUFFERS:
-                delta[leaf_name(group, n)] += float(
-                    _sq_diff(lp[n], jax.device_put(lp0[n], home)))
+                first = jax.device_put(lp0[n], home)
+                delta[leaf_name(group, n)] += float(_sq_diff(lp[n], first))
+                moved[leaf_name(group, n)] += int(_moved(lp[n], first))
     return {"loss": losses,
             "grad_norm": {n: v ** 0.5 for n, v in grad_sq.items()},
-            "delta_norm": {n: v ** 0.5 for n, v in delta.items()}}
+            "delta_norm": {n: v ** 0.5 for n, v in delta.items()},
+            "moved": moved}

@@ -162,6 +162,11 @@ def _sq_diff(a, b):
     return jnp.sum(jnp.square(a.astype(jnp.float32) - b.astype(jnp.float32)))
 
 
+@jax.jit
+def _moved(a, b):
+    return jnp.sum(a != b)
+
+
 def _zeros_like_f32(tree):
     return jax.tree_util.tree_map(
         lambda a: jnp.zeros(a.shape, jnp.float32), tree)
@@ -181,7 +186,9 @@ def train(make_weights, batches, lr, steps, variant="exact", block=512,
 
     -> {"loss": [one a step], "grad_norm": {leaf: norm of the FIRST step's
         gradient}, "delta_norm": {leaf: norm of the weights' change over all
-        the steps}} with a stacked leaf's norm taken over all its layers.
+        the steps}, "moved": {leaf: how many of its elements the steps
+        moved}} with a stacked leaf's norm and count taken over all its
+        layers.
     """
     devices = devices or [jax.devices()[0]]
     w = make_weights()
@@ -268,13 +275,14 @@ def train(make_weights, batches, lr, steps, variant="exact", block=512,
             grad_sq = sq
     del m_layers, m_top
     w0 = make_weights()
-    delta = {n: float(_sq_diff(top[n], jax.device_put(w0[n], home)))
-             for n in top}
-    for n in LAYER_LEAVES:
-        delta[n] = sum(
-            float(_sq_diff(layers[l][n],
-                           jax.device_put(w0["layers"][n][l], where[l])))
-            for l in range(n_layers))
+    delta, moved = {}, {}
+    for n in (*top, *LAYER_LEAVES):
+        pairs = [(top[n], jax.device_put(w0[n], home))] if n in top else [
+            (layers[l][n], jax.device_put(w0["layers"][n][l], where[l]))
+            for l in range(n_layers)]
+        delta[n] = sum(float(_sq_diff(a, b)) for a, b in pairs)
+        moved[n] = sum(int(_moved(a, b)) for a, b in pairs)
     return {"loss": losses,
             "grad_norm": {n: v ** 0.5 for n, v in grad_sq.items()},
-            "delta_norm": {n: v ** 0.5 for n, v in delta.items()}}
+            "delta_norm": {n: v ** 0.5 for n, v in delta.items()},
+            "moved": moved}

@@ -40,10 +40,13 @@ its uses, so that one chip holds the weights, the layer at work in float32
 and the layers' inputs, and no more.
 
 ``variant``: "exact"; the control "fp8"; the planted faults "half_batch" and
-"unchanged" as ``dense_decoder`` has them; and two of its own:
+"unchanged" as ``dense_decoder`` has them; and three of its own:
 "state_dropped" (every ``chunk`` positions the state starts from nought: a
-chunked scan that forgets to pass its states) and "expert_missing" (the
-first expert held is left out).
+chunked scan that forgets to pass its states), "expert_missing" (the first
+expert held is left out) and "leaf_unchanged" (ONE leaf's update is dropped:
+the first mixer layer's ``ssm_out`` never moves, every other leaf of every
+layer does; the one fault that the worst leaf's change sees and the median
+leaf's does not).
 """
 import collections
 import functools
@@ -53,7 +56,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from chipbench.reference.dense_decoder import (
-    _f32, _mm, _sgd, _sq_diff, _zeros_like_f32)
+    _f32, _mm, _moved, _sgd, _sq_diff, _zeros_like_f32)
 
 Model = collections.namedtuple(
     "Model", "eps k first residual embedding logits attention heads "
@@ -243,7 +246,8 @@ def train(make_weights, batches, lr, steps, runs, m, variant="exact",
 
     -> {"loss": [one a step], "grad_norm": {leaf: norm of the FIRST step's
         gradient}, "delta_norm": {leaf: norm of the weights' change over all
-        the steps}}, a stacked leaf's norm taken over all its layers.
+        the steps}, "moved": {leaf: how many of its elements the steps
+        moved}}, a stacked leaf's norm and count taken over all its layers.
     """
     home = (devices or [jax.devices()[0]])[0]
     up = lambda t: jax.device_put(t, home)   # noqa: E731
@@ -259,6 +263,8 @@ def train(make_weights, batches, lr, steps, runs, m, variant="exact",
     losses, grad_sq = [], None
     if variant == "unchanged":
         lr = 0.0
+    unmoved = (next(l for l, (_, kind, _) in enumerate(layers)
+                    if kind == "mamba"), "ssm_out")
 
     for step in range(steps):
         tokens, targets = batches[step]
@@ -312,7 +318,11 @@ def train(make_weights, batches, lr, steps, runs, m, variant="exact",
                                          block, variant)
                 xs[l][b] = None
             for n in list(lp):
-                lp[n], mom[n], s = _sgd(lp[n], mom[n], acc[n], lr)
+                # the planted fault: the gradient still reaches the
+                # momentum, this one leaf of this one layer never moves
+                still = variant == "leaf_unchanged" and (l, n) == unmoved
+                lp[n], mom[n], s = _sgd(lp[n], mom[n], acc[n],
+                                        0.0 if still else lr)
                 sq[leaf_name(stack, n)] += float(s)
             if step + 1 < steps:    # the last step's is read by no one
                 m_layers[l] = jax.device_get(mom)
@@ -331,9 +341,14 @@ def train(make_weights, batches, lr, steps, runs, m, variant="exact",
     w0 = make_weights()
     delta = {n: float(_sq_diff(top[n], up(w0[n]))) for n in TOP}
     delta.update(dict.fromkeys(names, 0.0))
+    moved = {n: int(_moved(top[n], up(w0[n]))) for n in TOP}
+    moved.update(dict.fromkeys(names, 0))
     for (stack, _, lp), (_, _, lp0) in zip(layers, split_layers(w0, runs)):
         for n in lp:
-            delta[leaf_name(stack, n)] += float(_sq_diff(lp[n], up(lp0[n])))
+            first = up(lp0[n])
+            delta[leaf_name(stack, n)] += float(_sq_diff(lp[n], first))
+            moved[leaf_name(stack, n)] += int(_moved(lp[n], first))
     return {"loss": losses,
             "grad_norm": {n: v ** 0.5 for n, v in grad_sq.items()},
-            "delta_norm": {n: v ** 0.5 for n, v in delta.items()}}
+            "delta_norm": {n: v ** 0.5 for n, v in delta.items()},
+            "moved": moved}

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chipbench.reference.dense_decoder import _f32, _fp8, _sq_diff
+from chipbench.reference.dense_decoder import _f32, _fp8, _moved, _sq_diff
 
 HIGHEST = lax.Precision.HIGHEST
 EPS = 1e-5
@@ -139,7 +139,8 @@ def train(make_weights, batches, lr, steps, variant="exact",
           layers=(3, 4, 6, 3), classes=1000):
     """make_weights: () -> {leaf: array} in the type the state is held in.
     batches: list of (images [B, 3, H, W], labels [B] int).
-    -> {"loss", "grad_norm", "delta_norm"} as dense_decoder.train gives."""
+    -> {"loss", "grad_norm", "delta_norm", "moved"} as dense_decoder.train
+    gives."""
     segs = segments(layers, classes)
 
     def local(seg):
@@ -189,4 +190,5 @@ def train(make_weights, batches, lr, steps, variant="exact",
     return {"loss": losses,
             "grad_norm": {n: v ** 0.5 for n, v in grad_sq.items()},
             "delta_norm": {n: float(_sq_diff(w[n], w0[n])) ** 0.5
-                           for n in w}}
+                           for n in w},
+            "moved": {n: int(_moved(w[n], w0[n])) for n in w}}

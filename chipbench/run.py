@@ -324,8 +324,15 @@ def run_cell(workload, seed, seconds, trace, devices=None, keep_trace=False,
     result["device"] = device
     result["reference_s"] = reference_s
     result["worst_leaf"] = worst
+    # the leaves the worst leaf's change was not taken over (check.py)
+    floor = spec["limits"].get("moved_floor", 0)
+    result["left_out"] = {"delta_norm_gap": check.left_out(reference, floor)}
     result["not_compared"] = not_compared
     result["compared"] = compared
+    say("chipbench: delta_norm_gap is %s's; left out, fewer than %d of "
+        "their elements moved: %s" % (worst["delta_norm_gap"], floor,
+                                      ", ".join(result["left_out"][
+                                          "delta_norm_gap"]) or "none"))
     for name, (v, lim) in compared.items():
         say("chipbench: compared %-16s %.6g  limit %.6g  %s"
             % (name, v, lim, "ok" if v <= lim else "OVER"))

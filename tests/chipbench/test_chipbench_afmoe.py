@@ -91,13 +91,18 @@ def test_the_configuration_is_the_catalogs_at_the_published_widths():
     assert len(held["layer_types"]) == held["num_hidden_layers"] == 5
     assert held["num_experts_held"] * 4 == held["num_experts"]
     assert held["vocab_rows_held"] * 4 == held["vocab_size"]
-    # the new per-layer metrics list this cell and no other
-    mine = [m for m in bench["per_layer"] if m["name"].startswith("moe.")]
-    assert [m["name"] for m in mine] == [
-        "moe.experts_share", "moe.route_share", "moe.dropped_slots"]
-    mine.append(bench["per_layer"][-1])
-    assert mine[-1]["name"] == "kernels.gmm_roofline"
-    assert all(m["workloads"] == [CELL] for m in mine)
+    # the expert share's metrics list this cell, and only cells of a
+    # configuration that holds a share of its experts (membership: nothing
+    # on which entry is last, how many there are, or where a cell stands)
+    lists = {m["name"]: m["workloads"] for m in bench["per_layer"]}
+    config_of = {w["name"]: w["config"] for w in bench["workloads"]}
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    for name in ("moe.experts_share", "moe.route_share", "moe.dropped_slots",
+                 "moe.slots_held", "kernels.gmm_roofline"):
+        assert CELL in lists[name]
+        for cell in lists[name]:
+            with open(os.path.join(ROOT, files[config_of[cell]])) as f:
+                assert "num_experts_held" in json.load(f), (name, cell)
 
 
 def test_the_adapters_configuration_is_the_share_the_file_states():

@@ -121,16 +121,17 @@ def test_the_configuration_is_the_published_one_key_by_key():
     assert "8 chips" in held["deployment"] and "32-chip" in held["deployment"]
     assert not [k for k in entry["reduced"]
                 if k.endswith(("_size", "_dim", "_rank"))]
-    # the cell: one chip, and in every per-layer list all three cells are in
+    # the cell: one chip; it reports whatever the first cell reports, and
+    # its mixers' two shares (membership: wherever it stands in a list, and
+    # whatever else it or a later cell reports)
     cell = spec["cell"]
     assert (cell["chips"], cell["traffic"]) == (1, "seq8192x1")
     assert (spec["traffic"]["batch"], spec["traffic"]["seq_len"]) == (1, 8192)
-    shared = [m["name"] for m in bench["per_layer"]
-              if "baichuan_7b.l5-seq2048" in m["workloads"]]
-    mine = [m["name"] for m in bench["per_layer"] if CELL in m["workloads"]]
-    assert mine == shared and len(mine) == 15
-    assert all(m["workloads"][-1] == CELL for m in bench["per_layer"]
-               if CELL in m["workloads"])
+    shared = {m["name"] for m in bench["per_layer"]
+              if "baichuan_7b.l5-seq2048" in m["workloads"]}
+    mine = {m["name"] for m in bench["per_layer"] if CELL in m["workloads"]}
+    assert shared and shared <= mine
+    assert {"ssm.scan_share", "ssm.mixer_share"} <= mine
 
 
 def test_the_adapters_configuration_is_the_share_the_file_states():
@@ -235,6 +236,38 @@ def test_a_share_without_an_expert_is_not_correct():
     assert r["correct"] is False
     over = [n for n, (v, lim) in r["compared"].items() if not v <= lim]
     assert "grad_norm_gap" in over
+
+
+def _leaf_unchanged(cell):
+    """One leaf's update dropped: the first mixer layer's ``ssm_out`` is
+    put back after every step; every other leaf of every layer moves."""
+    # a slice is an array of its own: the step's donation leaves it whole
+    real, first = cell.dispatch, cell.state[0]["mamba"]["ssm_out"][:, 0]
+
+    def dispatch(i):
+        loss = real(i)
+        params, mom = cell.state
+        mamba = dict(params["mamba"])
+        mamba["ssm_out"] = mamba["ssm_out"].at[:, 0].set(first)
+        cell.state = (dict(params, mamba=mamba), mom)
+        return loss
+
+    cell.dispatch = dispatch
+    return cell
+
+
+def test_a_leaf_that_one_layer_never_moves_is_not_correct():
+    """The one fault only the WORST leaf's change sees: the gradients are
+    sound, and the median leaf's change is."""
+    r = _run(wrap=_leaf_unchanged)
+    assert r["correct"] is False
+    over = [n for n, (v, lim) in r["compared"].items() if not v <= lim]
+    assert over == ["delta_norm_gap"]
+    assert r["worst_leaf"]["delta_norm_gap"] == "mamba.ssm_out"
+    # one mixer layer of three: 1 - sqrt(2/3) where they move alike
+    assert 0.05 < r["compared"]["delta_norm_gap"][0] < 0.5
+    assert list(r)[-3:] == ["left_out", "not_compared", "compared"]
+    assert isinstance(r["left_out"]["delta_norm_gap"], list)
 
 
 def test_a_scan_that_drops_its_states_is_not_correct(monkeypatch):

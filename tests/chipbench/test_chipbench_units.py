@@ -1,6 +1,7 @@
 """chipbench's own arithmetic, on the CPU: the trace reduction on a
 hand-made trace, the required-work counts against hand counts, and
 BENCHMARK.json against the rules its files are found by."""
+import copy
 import json
 import os
 import re
@@ -384,26 +385,102 @@ def test_leaf_gaps_are_gaps_of_norms_against_the_larger_of_leaf_and_median():
         check.judge(prog, refd, {"loss1": 0})
 
 
+def test_a_change_of_a_few_flipped_last_bits_is_left_out_of_the_worst_leaf():
+    """Two leaves of large values (a conv's bias and taps: values near 0.5
+    held in bfloat16) of which the float32 reference moved five elements by
+    their last bit, 2**-9, and the program three; three leaves of many
+    small steps. With no floor the conv's bias is the worst leaf by far:
+    rounding. One ordinary leaf's update dropped in one layer of nine is
+    what the worst leaf is kept for."""
+    bit = 2.0 ** -9
+    ref = {"conv_b": 5 ** 0.5 * bit, "conv_w": 0.005, "ssm_out": 0.0045,
+           "ssm_in": 0.0040, "embed": 0.0042}
+    moved = {"conv_b": 5, "conv_w": 5, "ssm_out": 40960, "ssm_in": 100000,
+             "embed": 4096}
+    sound = dict({n: v * (1 + 1e-4) for n, v in ref.items()},
+                 conv_b=3 ** 0.5 * bit, conv_w=ref["conv_w"])
+    grads = dict.fromkeys(ref, 1.0)
+    refd = {"loss": [2.0], "grad_norm": grads, "delta_norm": ref,
+            "moved": moved}
+    limits = {"loss1": 0, "grad_norm_gap": 0, "grad_norm_gap_med": 0,
+              "delta_norm_gap": 0.03, "delta_norm_gap_med": 5e-3,
+              "moved_floor": 1000}
+    assert check.left_out(refd, 1000) == ["conv_b", "conv_w"]
+    assert check.left_out(refd, 0) == [] == check.left_out(refd, 5)
+    prog = {"loss": [2.0], "grad_norm": grads, "delta_norm": sound}
+    vals, worst = check.numbers(prog, refd, 1000)
+    assert worst["delta_norm_gap"] in ("ssm_out", "ssm_in", "embed")
+    assert vals["delta_norm_gap"] == pytest.approx(1e-4, rel=0.1)
+    assert check.judge(prog, refd, limits)[0]
+    # a leaf left out still counts in the median leaf's change
+    gaps = check.leaf_gaps(sound, ref)
+    assert gaps["conv_b"] == pytest.approx(0.2254, rel=1e-3)
+    assert vals["delta_norm_gap_med"] == pytest.approx(1e-4, rel=0.1) \
+        == sorted(gaps.values())[2]
+    # a cell whose file gives no floor holds every leaf that moved
+    vals, worst = check.numbers(prog, refd)
+    assert worst["delta_norm_gap"] == "conv_b"
+    assert vals["delta_norm_gap"] == pytest.approx(0.2254, rel=1e-3)
+    no_floor = {n: v for n, v in limits.items() if n != "moved_floor"}
+    assert not check.judge(prog, refd, no_floor)[0]
+    # one layer of nine never moved ssm_out: the worst leaf alone sees it
+    faulty = dict(prog, delta_norm=dict(
+        sound, ssm_out=ref["ssm_out"] * (8 / 9) ** 0.5))
+    ok, compared, _, worst = check.judge(faulty, refd, limits)
+    over = [n for n, (v, lim) in compared.items() if not v <= lim]
+    assert not ok and over == ["delta_norm_gap"]
+    assert worst["delta_norm_gap"] == "ssm_out"
+    assert compared["delta_norm_gap"][0] == pytest.approx(
+        1 - (8 / 9) ** 0.5, rel=1e-6)
+    # a floor that leaves no leaf in is a mistake in the file, not a pass
+    with pytest.raises(ValueError):
+        check.numbers(prog, refd, 10 ** 9)
+
+
 def _bench():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
-def test_benchmark_json_names_units_and_keys_are_legal():
-    b = _bench()
+def _appended(b):
+    """What a later ``model_config`` PR brings, made in memory: a fifth cell
+    of a configuration that is there, appended to ``workloads`` and to the
+    END of every list the first cell is in, and one entry appended to the
+    END of ``per_layer`` (a reader the tree has and no cell reports)."""
+    b = copy.deepcopy(b)
+    first = b["workloads"][0]
+    fifth = dict(first, name=first["config"] + "-seq4096", traffic="seq4096",
+                 why="a later PR's cell: batch 4 x 4096")
+    b["workloads"].append(fifth)
+    for m in b["per_layer"]:
+        if first["name"] in m["workloads"]:
+            m["workloads"].append(fifth["name"])
+    b["per_layer"].append({
+        "name": "step.off_path", "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "train step",
+        "moves": "step_ms", "workloads": [fifth["name"]]})
+    return b
+
+
+def _invariants(b):
+    """What has to hold of BENCHMARK.json's lists wherever a cell or an
+    entry stands in them and however many there are: membership, never
+    position or count."""
     assert sorted(b) == ["command", "configs", "end_to_end", "paths",
                          "per_layer", "run_seconds", "workloads"]
-    assert b["command"] == ["python3", "chipbench/run.py"]
-    assert 1 <= b["run_seconds"] <= 51
-    names = []
+    configs = [c["name"] for c in b["configs"]]
+    cells = [w["name"] for w in b["workloads"]]
     for c in b["configs"]:
         assert sorted(c) == ["file", "name", "reduced", "source", "why"]
         assert all(NAME.match(k) for k in c["reduced"])
         assert any(c["file"].startswith(p + "/") for p in b["paths"])
+        assert c["name"] in [w["config"] for w in b["workloads"]]
     for w in b["workloads"]:
         assert sorted(w) == ["chips", "config", "name", "traffic", "why"]
         assert w["chips"] in (1, 4) and NAME.match(w["traffic"])
-        assert w["config"] in [c["name"] for c in b["configs"]]
+        assert w["config"] in configs
+    pairs = [(w["config"], w["traffic"]) for w in b["workloads"]]
+    assert len(set(pairs)) == len(pairs)
     for group, keys in (("end_to_end", {"bound"}), ("per_layer",
                                                     {"layer", "moves"})):
         for m in b[group]:
@@ -413,11 +490,12 @@ def test_benchmark_json_names_units_and_keys_are_legal():
                                                              "higher")
             assert m["source"] in ("device_trace", "program_span",
                                    "program_counter", "host_clock")
-    for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        names += [e["name"] for e in b[group]]
+    names = [e["name"] for group in ("configs", "workloads", "end_to_end",
+                                     "per_layer") for e in b[group]]
     assert all(NAME.match(n) for n in names)
     assert len(set(names)) == len(names)
-    assert [m["name"] for m in b["end_to_end"]] == ["step_ms", "setup_s"]
+    end_to_end = {m["name"] for m in b["end_to_end"]}
+    assert {"step_ms", "setup_s"} <= end_to_end
     assert all(0 < m["bound"] <= 0.1 for m in b["end_to_end"])
     for text in ([c["why"] for c in b["configs"] + b["workloads"]]
                  + [c["source"] for c in b["configs"]]
@@ -425,12 +503,55 @@ def test_benchmark_json_names_units_and_keys_are_legal():
         assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
     four = sum(1 for w in b["workloads"] if w["chips"] == 4)
     assert four <= max(1, len(b["workloads"]) // 4)
+    # every entry opts its cells in, and names only cells that are there
+    for m in b["per_layer"]:
+        assert m["moves"] in end_to_end
+        assert m["workloads"] and set(m["workloads"]) <= set(cells)
+        assert len(set(m["workloads"])) == len(m["workloads"])
+        assert callable(run.metric_reader(m["name"]))
+    # a layer has one spelling
+    layers = {m["layer"] for m in b["per_layer"]}
+    assert len({" ".join(l.lower().split()) for l in layers}) == len(layers)
+    # every cell reports something besides the end-to-end metrics
+    for name in cells:
+        assert run.metrics_of(b, name, "per_layer")
+        assert {m["name"] for m in run.metrics_of(b, name, "end_to_end")} \
+            >= {"step_ms", "setup_s"}
+
+
+@pytest.mark.parametrize("grown", [False, True],
+                         ids=["as_it_stands", "a_cell_and_an_entry_appended"])
+def test_the_lists_hold_wherever_a_cell_or_an_entry_is_appended(grown):
+    """The second case is the test a later PR's additions have to pass: a
+    fifth cell at the end of ``workloads`` and of every list it reports, an
+    entry at the end of ``per_layer``."""
+    b = _bench()
+    if grown:
+        before, b = b, _appended(b)
+        # appended: what was there stands where it stood, whole
+        assert b["workloads"][:-1] == before["workloads"]
+        for old, new in zip(before["per_layer"], b["per_layer"]):
+            assert new["workloads"][:len(old["workloads"])] \
+                == old["workloads"]
+            assert dict(new, workloads=old["workloads"]) == old
+        fifth = b["workloads"][-1]["name"]
+        assert {m["name"] for m in run.metrics_of(b, fifth, "per_layer")} \
+            == {m["name"] for m in run.metrics_of(
+                b, b["workloads"][0]["name"], "per_layer")} | {"step.off_path"}
+    _invariants(b)
+
+
+def test_benchmark_json_names_units_and_keys_are_legal():
+    b = _bench()
+    _invariants(b)
+    assert b["command"] == ["python3", "chipbench/run.py"]
+    assert 1 <= b["run_seconds"] <= 51
+    assert "chipbench" in b["paths"] and "tests/chipbench" in b["paths"]
 
 
 def test_every_cell_finds_its_files_by_name():
     b = _bench()
-    cells = [w["name"] for w in b["workloads"]]
-    for name in cells:
+    for name in [w["name"] for w in b["workloads"]]:
         spec = run.load_cell(name)
         assert os.path.exists(os.path.join(
             ROOT, "chipbench", "models", spec["config"]["adapter"] + ".py"))
@@ -445,13 +566,6 @@ def test_every_cell_finds_its_files_by_name():
                 "grad_norm_gap_med", "delta_norm_gap",
                 "delta_norm_gap_med"} <= listed
         assert {"grad_norm_gap", "delta_norm_gap"} & set(spec["limits"])
-        reported = [m for m in b["per_layer"]
-                    if name in m.get("workloads", cells)]
-        assert reported and all(callable(run.metric_reader(m["name"]))
-                                for m in reported)
-    for m in b["per_layer"]:
-        assert m["moves"] in ("step_ms", "setup_s")
-        assert set(m["workloads"]) <= set(cells)   # every one opts in
     entry = {c["name"]: c for c in b["configs"]}
     for c in entry.values():
         with open(os.path.join(ROOT, c["file"])) as f:

@@ -12,7 +12,9 @@ requirement — with capacity_factor bounding per-expert load.
 expert-parallel layer. It is told which experts it holds (a contiguous
 range), routes over all of them (sigmoid scores, top-k of score + bias,
 weights normalised over the k chosen; or the k largest logits and a softmax
-over those), sorts the token-slots by expert, runs
+over those; or an MLP with a state carried from layer to layer, a softmax
+over all experts, the chosen probabilities as they are), sorts the
+token-slots by expert, runs
 grouped products over the slots of the experts held
 (``pallas_kernels/grouped_matmul.py``) and gathers the weighted results
 back. No capacity and no drops: the slot buffer holds every slot there is,
@@ -32,7 +34,8 @@ from jax import lax
 from .. import profiler as _profiler
 
 __all__ = ["top_k_routing", "moe_ffn", "MoELayer", "route_sigmoid",
-           "route_topk_softmax", "moe_share", "MOE_STATS"]
+           "route_topk_softmax", "route_mlp_softmax", "moe_share",
+           "MOE_STATS", "ROUTER_MLP"]
 
 
 def top_k_routing(logits, k=2, capacity=None):
@@ -144,6 +147,33 @@ def route_topk_softmax(h, router_w, k):
                      precision=lax.Precision.HIGHEST)
     chosen, experts = lax.top_k(logits, k)
     return experts, jax.nn.softmax(chosen, axis=-1)
+
+
+# the leaves of ``route_mlp_softmax``'s router, as ``moe_share`` takes them
+ROUTER_MLP = ("down", "gamma", "norm", "w1", "w2", "out")
+
+
+def route_mlp_softmax(h, router, bias, k, state, eps):
+    """A router that is an MLP with a state: r = h W_down + gamma * (the r
+    of the layer before), in the activations' product and float32 from
+    there on; p = softmax(gelu(gelu(rmsnorm(r) * norm W1) W2) W_out) over
+    ALL experts; the k chosen by p + bias (``bias`` is a buffer: it selects
+    and carries no gradient); weights the chosen probabilities AS THEY ARE:
+    normalised over the k they would read 1 at k = 1 and the router would
+    get no gradient. h: [T, D]; router: {``ROUTER_MLP``: down [D, R], gamma
+    [], norm [R], w1, w2 [R, R], out [R, E]}; state: [T, R] float32.
+    -> (experts [T, k] int32, weights [T, k] float32, r [T, R] float32)."""
+    f32 = jnp.float32
+    dot = functools.partial(jnp.dot, precision=lax.Precision.HIGHEST)
+    r = jnp.dot(h, router["down"], preferred_element_type=f32) \
+        + router["gamma"].astype(f32) * state
+    a = r * lax.rsqrt(jnp.mean(jnp.square(r), -1, keepdims=True) + eps) \
+        * router["norm"].astype(f32)
+    for name in ("w1", "w2"):
+        a = jax.nn.gelu(dot(a, router[name].astype(f32)), approximate=False)
+    p = jax.nn.softmax(dot(a, router["out"].astype(f32)), axis=-1)
+    _, experts = lax.top_k(p + lax.stop_gradient(bias.astype(f32)), k)
+    return experts, jnp.take_along_axis(p, experts, axis=-1), r
 
 
 def buffer_rows(tokens, k, n_held, tile):
@@ -305,13 +335,18 @@ def _gated(x, w_gate, w_up, w_down, product):
 
 
 def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
-              first=0, route_scale=1.0, route="sigmoid", interpret=False):
+              first=0, route_scale=1.0, route="sigmoid", state=None,
+              eps=1e-5, interpret=False):
     """The share of an expert layer that holds experts ``first`` to
     ``first + w_gate.shape[0]`` of the ``router_w.shape[1]`` routed over.
 
     x: [B, S, D]; router_w: [D, E]; bias: [E] (``route`` "sigmoid":
     ``route_sigmoid``; "topk_softmax": ``route_topk_softmax``, which has
-    none and takes no ``route_scale``); w_gate, w_up: [held, D, F];
+    none and takes no ``route_scale``; "mlp_softmax":
+    ``route_mlp_softmax``, for which ``router_w`` is that router's leaves,
+    ``state`` [B, S, R] the layer before's r, ``eps`` its norm's, and the
+    layer's own r [B, S, R] is returned as a third result); w_gate, w_up:
+    [held, D, F];
     w_down: [held, F, D]; ``shared``: (w_gate [D, Fs], w_up, w_down
     [Fs, D]) of the shared expert, computed for every token.
     -> (y [B, S, D]: shared(x) + sum over the chosen experts HELD of weight
@@ -335,7 +370,10 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     how = (_gmm.TILE, bool(interpret)) if (interpret or on_tpu) and \
         _rows.fits(D, x.dtype, on_tpu and not interpret) else None
     with jax.named_scope("mx.moe_route"):
-        if route == "topk_softmax":
+        if route == "mlp_softmax":
+            experts, weights, r = route_mlp_softmax(
+                xt, router_w, bias, k, state.reshape(T, -1), eps)
+        elif route == "topk_softmax":
             experts, weights = route_topk_softmax(xt, router_w, k)
         else:
             experts, weights = route_sigmoid(xt, router_w, bias, k,
@@ -361,6 +399,8 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
                        n_held_slots - jnp.sum(plan.live, dtype=jnp.int32),
                        jnp.max(plan.counts),
                        jnp.sum(plan.sizes, dtype=jnp.int32)])
+    if route == "mlp_softmax":
+        return y.reshape(B, S, D), stats, r.reshape(B, S, -1)
     return y.reshape(B, S, D), stats
 
 

@@ -43,8 +43,9 @@ from jax import lax
 
 from .. import profiler as _profiler
 
-__all__ = ["conv_taps", "conv_silu", "chunked_scan", "block_heads",
-           "scan_temp_bytes", "stage_bytes", "mixer", "mixer_leaves"]
+__all__ = ["conv_taps", "shifted_sum", "conv_silu", "chunked_scan",
+           "block_heads", "scan_temp_bytes", "stage_bytes", "mixer",
+           "mixer_leaves"]
 
 # the largest temporary a block of heads may make (its decays): the
 # backward holds a few of that size at once, 0.3 GB of them at 8192 tokens
@@ -83,7 +84,7 @@ def init_leaf(key, how, shape):
     return step + jnp.log(-jnp.expm1(-step))     # softplus(this) == step
 
 
-def _pre_activation(xp, w, b, seq):
+def shifted_sum(xp, w, b, seq):
     """b + sum_k w[k] xp[t + k] for ``seq`` positions t, tap by tap in that
     order. ``xp`` is x already padded, in whatever type: each shifted view is
     cast to float32 inside the sum, and since a shift and a cast commute the
@@ -101,7 +102,7 @@ def conv_taps(x, w, b):
     before the sequence reading nought. The plain form, x cast to float32
     and then padded: what ``conv_silu`` is held to. -> float32 [B, S, C]."""
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (w.shape[0] - 1, 0), (0, 0)))
-    return _pre_activation(xp, w, b, x.shape[1])
+    return shifted_sum(xp, w, b, x.shape[1])
 
 
 @jax.custom_vjp
@@ -112,8 +113,8 @@ def conv_silu(x, w, b):
     array (``ds``); the pre-activation is recomputed from x. JAX's own
     derivative of ``conv_taps`` keeps five float32 [B, S, C] arrays."""
     front = ((0, 0), (w.shape[0] - 1, 0), (0, 0))
-    return jax.nn.silu(_pre_activation(jnp.pad(x, front), w, b,
-                                       x.shape[1])).astype(x.dtype)
+    return jax.nn.silu(shifted_sum(jnp.pad(x, front), w, b,
+                                   x.shape[1])).astype(x.dtype)
 
 
 def _conv_silu_fwd(x, w, b):
@@ -131,7 +132,7 @@ def _conv_silu_bwd(res, dy):
     taps, seq = w.shape[0], x.shape[1]
     ext = seq + taps - 1
     xp = jnp.pad(x, ((0, 0), (taps - 1, taps - 1), (0, 0)))
-    u = _pre_activation(xp, w, b, ext)
+    u = shifted_sum(xp, w, b, ext)
     sig = jax.nn.sigmoid(u)
     ds = jnp.pad(dy, ((0, 0), (0, taps - 1), (0, 0))).astype(jnp.float32) \
         * (sig * (1.0 + u * (1.0 - sig)))

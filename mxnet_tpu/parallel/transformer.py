@@ -45,6 +45,7 @@ from .compat import NamedSharding, PartitionSpec as P
 
 from .ring_attention import ring_attention, blockwise_attention
 from .ulysses import ulysses_attention_local
+from . import cca as _cca
 from . import expert as _expert
 from . import ssm as _ssm
 from .expert import moe_ffn
@@ -160,6 +161,27 @@ class TransformerConfig:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # -- what compressed convolutional attention, a router with a state and
+    # learned residual scaling need, each defaulting to the program there
+    # was ---------------------------------------------------------------------
+    # how q and k are mixed between projection and kernel: "none", or "cca"
+    # (``parallel/cca.py``: two causal convolutions over positions of
+    # ``mix_taps`` taps, the mean of the latents, unit length times sqrt(d)
+    # with a learned temperature on k)
+    qk_mix: str = "none"
+    mix_taps: tuple = (2, 2)
+    # the later half of the key-value heads read v from the position before
+    # (``wv_cur`` and ``wv_prev`` in ``wv``'s place)
+    v_shift: bool = False
+    rope_theta: float = 10000.0
+    # dims of a head that rotate, from the first (None: all); the rest pass
+    rope_dims: Optional[int] = None
+    # x' = (s * x + t) + (u * a + w) for what each half of a layer adds:
+    # four learned [dim] vectors a half, in ``residual_mult``'s place
+    residual_scaling: bool = False
+    # ``route`` "mlp_softmax" (expert.route_mlp_softmax): the router is an
+    # MLP of this width whose first state travels from layer to layer
+    router_hidden: int = 0
 
     @property
     def head_dim(self):
@@ -176,6 +198,12 @@ class TransformerConfig:
         if self.moe_hidden is None or self.num_experts <= 0:
             return None
         return tuple(self.experts_held or (0, self.num_experts))
+
+    @property
+    def router_state(self):
+        """Whether the scanned layers hand a router's state on, layer to
+        layer: the trunk then carries (x, r) and not x alone."""
+        return self.expert_share is not None and self.route == "mlp_softmax"
 
     @property
     def periods(self):
@@ -202,16 +230,30 @@ def _rms_norm(x, scale, eps):
     return (x * lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def _rope(x, positions):
-    """Rotary position embedding. x: [B, H, S, D_h], positions: [S]."""
+def _rope(x, positions, theta=10000.0):
+    """Rotary position embedding at base ``theta``: dim j of the first half
+    rotates with dim j + half. x: [B, H, S, D_h], positions: [S]."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., :half], x[..., half:]
     rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return rot.astype(x.dtype)
+
+
+def _rotated(cfg, a, positions):
+    """``_rope`` at the configuration's base on a: [B, S, heads, D_h]; where
+    it rotates ``rope_dims`` of a head only, the rest pass as they are."""
+    def turned(t):
+        return jnp.transpose(_rope(jnp.transpose(t, (0, 2, 1, 3)), positions,
+                                   cfg.rope_theta), (0, 2, 1, 3))
+
+    dims = cfg.rope_dims
+    if dims is None or dims == a.shape[-1]:
+        return turned(a)
+    return jnp.concatenate([turned(a[..., :dims]), a[..., dims:]], axis=-1)
 
 
 def _ffn_kind(cfg, experts=True):
@@ -224,10 +266,10 @@ def _ffn_kind(cfg, experts=True):
 
 
 def _layer_leaves(cfg, experts=True, kind="full"):
-    """{leaf: (shape of one layer, fan_in or None for a norm's scale or 0
-    for the router's bias or a name ``ssm.init_leaf`` knows, spec of one
-    layer)}: the one table of a layer's leaves, scanned (``experts``) or
-    leading dense, of a ``kind``: attention's rows for "full" and "sliding",
+    """{leaf: (shape of one layer, fan_in or None for a scale that starts
+    at one or 0 for the router's bias or "zeros" or a name ``ssm.init_leaf``
+    knows, spec of one layer)}: the one table of a layer's leaves, scanned
+    (``experts``) or leading dense, of a ``kind``: attention's rows for "full" and "sliding",
     the mixer's for "mamba", the norms' and the feed-forward's for both.
     The plain decoder's nine are the rows that no option adds."""
     D, H, G, Dh = cfg.dim, cfg.n_heads, cfg.kv_heads, cfg.head_dim
@@ -241,12 +283,22 @@ def _layer_leaves(cfg, experts=True, kind="full"):
                "wv": ((D, G, Dh), D, (None, "tp", None)),
                "wo": ((H, Dh, D), H * Dh, ("tp", None, None)),
                "ln2": ((D,), None, (None,))}
+        if cfg.v_shift:     # this position's heads, then the one before's
+            del out["wv"]
+            out["wv_cur"] = ((D, G // 2, Dh), D, (None, "tp", None))
+            out["wv_prev"] = ((D, G - G // 2, Dh), D, (None, "tp", None))
         if cfg.attn_gate:
             out["w_attn_gate"] = ((D, H, Dh), D, (None, "tp", None))
         if cfg.qk_norm:
             out["q_norm"] = out["k_norm"] = ((Dh,), None, (None,))
+        if cfg.qk_mix == "cca":
+            out.update(_cca.mix_leaves(cfg))
     if cfg.post_norms:
         out["ln1_post"] = out["ln2_post"] = ((D,), None, (None,))
+    if cfg.residual_scaling:
+        for half in "12":
+            for name, how in zip("stuw", (None, "zeros", None, "zeros")):
+                out["res%s_%s" % (half, name)] = ((D,), how, (None,))
     ffn = _ffn_kind(cfg, experts)
     if ffn != "share":
         F = cfg.ffn_hidden
@@ -260,8 +312,17 @@ def _layer_leaves(cfg, experts=True, kind="full"):
                         "moe_w2": ((E, F, D), F, ("ep", "tp", None))})
         return out
     E, Fm, held = cfg.num_experts, cfg.moe_hidden, cfg.expert_share[1]
-    out["moe_router"] = ((D, E), D, (None, None))
-    if cfg.route == "sigmoid":
+    if cfg.route == "mlp_softmax":
+        R = cfg.router_hidden
+        out.update({"moe_router_down": ((D, R), D, (None, None)),
+                    "moe_router_gamma": ((), None, ()),
+                    "moe_router_norm": ((R,), None, (None,)),
+                    "moe_router_w1": ((R, R), R, (None, None)),
+                    "moe_router_w2": ((R, R), R, (None, None)),
+                    "moe_router_out": ((R, E), R, (None, None))})
+    else:
+        out["moe_router"] = ((D, E), D, (None, None))
+    if cfg.route in ("sigmoid", "mlp_softmax"):
         out["moe_bias"] = ((E,), 0, (None,))
     out.update({"moe_w_gate": ((held, D, Fm), D, (None, None, None)),
                 "moe_w_up": ((held, D, Fm), D, (None, None, None)),
@@ -343,6 +404,8 @@ def init_params(key, cfg: TransformerConfig):
         for name, (shape, fan_in, _) in leaves.items():
             if fan_in is None:
                 out[name] = jnp.ones(lead + shape, dt)
+            elif fan_in == "zeros":
+                out[name] = jnp.zeros(lead + shape, dt)
             elif isinstance(fan_in, str):
                 out[name] = _ssm.init_leaf(keys[name], fan_in,
                                            lead + shape).astype(dt)
@@ -463,28 +526,38 @@ def _mesh_sizes(mesh):
             dict(getattr(mesh, "mesh", mesh).shape).items()}
 
 
-def _ffn(cfg, lp, h, experts):
+def _ffn(cfg, lp, h, experts, r=None):
     """The one place that chooses a layer's feed-forward (``_ffn_kind``).
-    h: [B, S, D] -> (y, the counters of an expert share (``MOE_STATS``) or
-    None, the GShard load-balance loss or None)."""
+    h: [B, S, D]; ``r``: the router's state from the layer before, where the
+    configuration has one (``router_state``). -> (y, the counters of an
+    expert share (``MOE_STATS``) or None, the GShard load-balance loss or
+    None, this layer's router state, or ``r`` as it came where the layer
+    has no router of that kind)."""
     ffn = _ffn_kind(cfg, experts)
     if ffn == "share":
         shared = (lp["ws_gate"], lp["ws_up"], lp["ws_down"]) \
             if cfg.moe_shared else None
-        y, stats = _expert.moe_share(
-            h, lp["moe_router"], lp.get("moe_bias"), lp["moe_w_gate"],
-            lp["moe_w_up"], lp["moe_w_down"], shared, k=cfg.moe_k,
+        share = functools.partial(
+            _expert.moe_share, h, bias=lp.get("moe_bias"),
+            w_gate=lp["moe_w_gate"], w_up=lp["moe_w_up"],
+            w_down=lp["moe_w_down"], shared=shared, k=cfg.moe_k,
             first=cfg.expert_share[0], route_scale=cfg.route_scale,
             route=cfg.route)
-        return y, stats, None
+        if cfg.router_state:
+            y, stats, r = share({n: lp["moe_router_" + n] for n in
+                                 _expert.ROUTER_MLP}, state=r,
+                                eps=cfg.norm_eps)
+            return y, stats, None, r
+        y, stats = share(lp["moe_router"])
+        return y, stats, None, r
     if ffn == "gshard":
         y, balance = moe_ffn(h, lp["moe_router"], lp["moe_w1"], lp["moe_w2"],
                              k=cfg.moe_k)
-        return y, None, balance
+        return y, None, balance, r
     g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w_gate"]))
     u = jnp.einsum("bsd,df->bsf", h, lp["w_up"])
     prod = _ckpt_name(g * u, "ffn_prod")
-    return jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), None, None
+    return jnp.einsum("bsf,fd->bsd", prod, lp["w_down"]), None, None, r
 
 
 def _scaled(a, mult):
@@ -495,26 +568,43 @@ def _scaled(a, mult):
     return (a.astype(jnp.float32) * mult).astype(a.dtype)
 
 
+def _added(cfg, lp, half, x, a):
+    """x with what a layer's ``half`` ("1": attention or mixer, "2": the
+    feed-forward) adds to it: x + residual_mult * a, or with learned
+    scaling (s * x + t) + (u * a + w), taken in float32."""
+    if not cfg.residual_scaling:
+        return x + _scaled(a, cfg.residual_mult)
+    s, t, u, w = (lp["res%s_%s" % (half, n)].astype(jnp.float32)
+                  for n in "stuw")
+    return ((s * x.astype(jnp.float32) + t)
+            + (u * a.astype(jnp.float32) + w)).astype(x.dtype)
+
+
 def _layer_body(cfg, mesh, positions, x, lp, kind="full", experts=True):
-    """One layer. x: [B, S, D]; lp: this layer's params
-    (``_layer_leaves(cfg, experts, kind)``); ``kind``: its attention, "full"
-    or "sliding", or "mamba" for a mixer in attention's place.
-    -> (x, counters or None, balance loss or None) as ``_ffn``."""
+    """One layer. x: [B, S, D], or (x, the router's state of the layer
+    before [B, S, router_hidden]) where the configuration has one
+    (``router_state``); lp: this layer's params (``_layer_leaves(cfg,
+    experts, kind)``); ``kind``: its attention, "full" or "sliding", or
+    "mamba" for a mixer in attention's place.
+    -> (x or (x, this layer's router state), counters or None, balance
+    loss or None) as ``_ffn``."""
     eps, sliding = cfg.norm_eps, kind == "sliding"
+    x, r = x if cfg.router_state else (x, None)
     if kind == "mamba":
         with jax.named_scope("mx.ssm_proj"):
             h = _rms_norm(x, lp["ln1"], eps)
         a = _ssm.mixer(h, lp, cfg)
         with jax.named_scope("mx.ssm_proj"):
-            x = x + _scaled(a, cfg.residual_mult)
+            x = _added(cfg, lp, "1", x, a)
     else:
         x = _attend(cfg, mesh, positions, x, lp, sliding)
     with jax.named_scope("mx.ffn"):
-        y, stats, balance = _ffn(cfg, lp, _rms_norm(x, lp["ln2"], eps),
-                                 experts)
+        y, stats, balance, r = _ffn(cfg, lp, _rms_norm(x, lp["ln2"], eps),
+                                    experts, r)
         if cfg.post_norms:
             y = _rms_norm(y, lp["ln2_post"], eps)
-        return x + _scaled(y, cfg.residual_mult), stats, balance
+        x = _added(cfg, lp, "2", x, y)
+        return (x, r) if cfg.router_state else x, stats, balance
 
 
 def _attend(cfg, mesh, positions, x, lp, sliding):
@@ -524,17 +614,28 @@ def _attend(cfg, mesh, positions, x, lp, sliding):
         h = _rms_norm(x, lp["ln1"], eps)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
         k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        if cfg.v_shift:
+            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv_cur"])
+            v_prev = jnp.einsum("bsd,dhk->bshk", h, lp["wv_prev"])
+        else:
+            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
         if cfg.attn_gate:
             gate = jnp.einsum("bsd,dhk->bshk", h, lp["w_attn_gate"])
         if cfg.qk_norm:
             q = _rms_norm(q, lp["q_norm"], eps)
             k = _rms_norm(k, lp["k_norm"], eps)
-        if cfg.rope_on == "all" or (sliding and cfg.rope_on == "sliding"):
-            q = jnp.transpose(_rope(jnp.transpose(q, (0, 2, 1, 3)),
-                                    positions), (0, 2, 1, 3))
-            k = jnp.transpose(_rope(jnp.transpose(k, (0, 2, 1, 3)),
-                                    positions), (0, 2, 1, 3))
+    if cfg.qk_mix != "none" or cfg.v_shift:
+        with jax.named_scope("mx.cca_mix"):
+            if cfg.qk_mix == "cca":
+                q, k = _cca.mix(q, k, lp)
+            if cfg.v_shift:
+                # the product of the position before: a shift and a product
+                # commute, and the product's rows are a sixteenth of h's
+                v = jnp.concatenate([v, _cca.shift(v_prev)], axis=2)
+    if cfg.rope_on == "all" or (sliding and cfg.rope_on == "sliding"):
+        with jax.named_scope("mx.attn_proj"):   # after the mixing stage
+            q = _rotated(cfg, q, positions)
+            k = _rotated(cfg, k, positions)
     with jax.named_scope("mx.flash"):
         o = _ckpt_name(_attention(cfg, mesh, q, k, v, positions,
                                   cfg.window if sliding else None), "attn_o")
@@ -544,7 +645,7 @@ def _attend(cfg, mesh, positions, x, lp, sliding):
         a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
         if cfg.post_norms:
             a = _rms_norm(a, lp["ln1_post"], eps)
-        return x + _scaled(a, cfg.residual_mult)
+        return _added(cfg, lp, "1", x, a)
 
 
 def apply(params, tokens, cfg: TransformerConfig, mesh=None,
@@ -609,8 +710,9 @@ def remat_choice(cfg, batch, seq, state_bytes, grad_bytes, sizes, limit):
     if cfg.remat_save is not None or not cfg.remat or not limit:
         return tuple(cfg.remat_save or ()), 0, None
     dp, sp = sizes.get("dp", 1), sizes.get("sp", 1)
-    inputs = cfg.n_layers * (batch * seq // (dp * sp)) * cfg.dim \
-        * jnp.dtype(cfg.dtype).itemsize
+    inputs = cfg.n_layers * (batch * seq // (dp * sp)) * (
+        cfg.dim * jnp.dtype(cfg.dtype).itemsize
+        + (4 * cfg.router_hidden if cfg.router_state else 0))
     budget = max(0, int(_REMAT_KEEP_SHARE * (
         limit - state_bytes - grad_bytes - inputs)))
     names, kept = (), 0
@@ -639,9 +741,11 @@ def _hidden(params, tokens, cfg, mesh):
     under the layer remat (a run of attention layers one after the other, a
     run of mixers as an inner scan: one body traced however long the run),
     and the final norm. The plain decoder is the case of one "full" layer a
-    period: the scanned slice is that layer's leaves. -> (x [B, S, D], the
-    expert shares' counters summed or None, the GShard layers' balance loss
-    summed or None)."""
+    period: the scanned slice is that layer's leaves. Where the layers hand
+    a router's state on (``router_state``) the carry is (x, r), r nought
+    before the first layer; else x alone. -> (x [B, S, D], the expert
+    shares' counters summed or None, the GShard layers' balance loss summed
+    or None)."""
     with jax.named_scope("mx.embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
         if cfg.embed_scale:
@@ -650,6 +754,8 @@ def _hidden(params, tokens, cfg, mesh):
     positions = jnp.arange(tokens.shape[1])
     zero = jnp.zeros(len(_expert.MOE_STATS), jnp.int32) \
         if _ffn_kind(cfg) == "share" else None
+    if cfg.router_state:
+        x = (x, jnp.zeros(tokens.shape + (cfg.router_hidden,), jnp.float32))
 
     def layer(kind, experts=True):
         one = functools.partial(_layer_body, cfg, mesh, positions, kind=kind,
@@ -694,6 +800,8 @@ def _hidden(params, tokens, cfg, mesh):
                 x, _, _ = layer(kind, False)(x, at(params["dense"], i))
         x, (stats, balance) = lax.scan(
             period, x, {name: params[name] for name, _ in runs})
+        if cfg.router_state:
+            x, _ = x
     with jax.named_scope("mx.head_ce"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
     return (x, None if stats is None else _expert.sum_stats(stats),
@@ -1094,6 +1202,8 @@ class _Step:
             params, mom = state
             if "mamba" in cfg.layer_pattern:
                 _ssm.note(cfg, *tokens.shape)
+            if cfg.qk_mix == "cca":
+                _cca.note(cfg, *tokens.shape)
             (loss, stats), grads = jax.value_and_grad(
                 _loss_and_stats, has_aux=True)(
                     params, tokens, targets,

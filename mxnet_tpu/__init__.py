@@ -14,6 +14,10 @@ C ABI + frontends → this Python package.
 """
 from __future__ import annotations
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()   # the import's first line: profiler.note_import
+
 __version__ = "1.6.0.tpu1"
 
 from .base import MXNetError
@@ -103,3 +107,8 @@ def __getattr__(name):
 
 def __dir__():
     return sorted(list(globals().keys()) + list(_LAZY.keys()))
+
+
+from . import profiler as _profiler  # noqa: E402
+
+_profiler.note_import(_T_IMPORT, _time.perf_counter())

@@ -70,7 +70,7 @@ __all__ = [
     "record_program", "program_records",
     "span", "step_span", "instrument_step", "train_step_stats",
     "note_remat",
-    "jax_compile_stats", "device_table",
+    "jax_compile_stats", "device_table", "note_import", "setup_stats",
 ]
 
 # chrome-trace pid of every event this process emits: the worker rank.
@@ -408,8 +408,9 @@ def resume(profile_process="worker"):
 
 
 def record_op(name, dur_us, category="operator", args=None,
-              lane="imperative"):
-    """Record one completed span into ``lane``. Always feeds the
+              lane="imperative", end_us=None):
+    """Record one completed span into ``lane``, ending now or at
+    ``end_us`` on the profiler's clock. Always feeds the
     flight-recorder ring (the post-mortem black box, ISSUE 8); the
     trace event + aggregate row are recorded only while a profile run
     is active. Call sites guard with the shared ``_HOOKS and _LIVE``
@@ -423,7 +424,7 @@ def record_op(name, dur_us, category="operator", args=None,
                                 time.perf_counter(), dur_us, args))
     if not _ACTIVE:
         return
-    end = _now_us()
+    end = _now_us() if end_us is None else end_us
     ev = {"name": name, "cat": category, "ph": "X",
           # mxlint: disable=MX014 (telemetry side channel: PID only tags the emitted event with the rank; no traced value depends on it)
           "ts": end - dur_us, "dur": dur_us, "pid": PID,
@@ -1119,64 +1120,62 @@ def reset_train_step_stats():
 register_stats_provider("train_step", train_step_stats,
                         reset_train_step_stats)
 
-# The compile ledger: JAX reports every trace, lowering, backend compile
-# and persistent-cache hit through jax.monitoring, with the function's
-# name. Listeners fire only when something compiles, so a steady step
-# pays nothing. In JAX 0.9.0 the cache read lies INSIDE
+# The compile ledger: JAX reports every trace, lowering and backend
+# compile through jax.monitoring, with the function's name and the phase's
+# start and end. Listeners fire only when something compiles, so a steady
+# step pays nothing. In JAX 0.9.0 the persistent cache is asked INSIDE
 # backend_compile_duration (pxla._cached_compilation times
-# compiler.compile_or_get_cached), so ``compile_s`` adds the three phases
-# and never cache_retrieval_time_sec on top.
+# compiler.compile_or_get_cached): its events, noted while that phase is
+# open, say whether the phase loaded the program or compiled and stored
+# it, and ``compile_s`` adds the three phases and never
+# cache_retrieval_time_sec on top.
 _JAX_EVENT_ROOTS = ("/jax/core/compile/", "/jax/compilation_cache/")
 _JAX_PHASES = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
                "backend_compile_duration")
-_JAX_FUN_CAP = 512
+# a top-level phase while the profiler runs: the span jax.<kind>:<fun>
+_JAX_SPAN_KIND = dict(zip(_JAX_PHASES, ("trace", "lower", "compile")))
 # mxlint: disable=MX003 (written by JAX's listeners on the compiling thread, read as a snapshot; best-effort like _TRAIN)
 _JAXC = {"in_step": 0,     # phase events that fired inside a step's call
          "open": 0,        # phases open right now (nesting depth)
          "nested": 0,      # phase events inside another phase: counted
                            # here, kept out of entries and totals
-         "dropped": 0}     # entries the bounded list let go
+         "dropped": 0,     # entries the bounded list let go
+         "cache_hits": 0,  # top-level backend compiles loaded from the
+         "cache_misses": 0,  # persistent cache / compiled and stored in it
+         "cache_load_s": 0.0,     # ... their seconds on hits
+         "fresh_compile_s": 0.0}  # ... on every other backend compile
+# the backend compile open now, as the persistent cache's events saw it
+# mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; three GIL-atomic stores a compile)
+_JAXC_CACHE = {"hit": False, "written": False, "read_s": 0.0}
 # mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; deque.append is atomic)
 _JAXC_ENTRIES = collections.deque(maxlen=_RING_CAP)
 # mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; best-effort totals like _TRAIN)
 _JAXC_SECONDS = {}   # event -> seconds
 # mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; best-effort totals like _TRAIN)
 _JAXC_COUNTS = {}    # event -> count
-# mxlint: disable=MX003 (written only by JAX's listeners on the compiling thread; best-effort totals like _TRAIN)
-_JAXC_BY_FUN = {}    # fun_name -> {event: seconds}
 
 
-def _jax_event(event, seconds=0.0, fun_name=None, **_):
-    """jax.monitoring's duration listener and its plain event listener."""
-    for root in _JAX_EVENT_ROOTS:
-        if event.startswith(root):
-            event = event[len(root):]
-            break
-    else:
-        return
-    in_step = _STEPS["open"] > 0
-    if event in _JAX_PHASES:
-        if in_step:
-            _JAXC["in_step"] += 1
-        _JAXC["open"] = max(0, _JAXC["open"] - 1)
-        if _JAXC["open"]:
-            # a jit traced while another is traced or lowered (every
-            # jnp op of the step's body): its time lies inside the
-            # outer phase's
-            _JAXC["nested"] += 1
-            return
-    if len(_JAXC_ENTRIES) == _RING_CAP:
-        _JAXC["dropped"] += 1
-    _JAXC_ENTRIES.append((event, fun_name, float(seconds),
-                          _STEPS["started"], in_step))
+def _count_event(event, seconds):
     _JAXC_COUNTS[event] = _JAXC_COUNTS.get(event, 0) + 1
     _JAXC_SECONDS[event] = _JAXC_SECONDS.get(event, 0.0) + seconds
-    if fun_name is not None:
-        if fun_name not in _JAXC_BY_FUN and \
-                len(_JAXC_BY_FUN) >= _JAX_FUN_CAP:
-            fun_name = "(other)"
-        by = _JAXC_BY_FUN.setdefault(fun_name, {})
-        by[event] = by.get(event, 0.0) + seconds
+
+
+def _jax_event(event, seconds=0.0, **_):
+    """jax.monitoring's duration listener and its plain event listener:
+    the persistent cache's events, noted for the backend compile they
+    fire in (``_jax_span`` closes it). A phase's own duration is read
+    from its span."""
+    root = _JAX_EVENT_ROOTS[1]
+    if not event.startswith(root):
+        return
+    event = event[len(root):]
+    _count_event(event, seconds)
+    if event == "cache_hits":
+        _JAXC_CACHE["hit"] = True
+    elif event == "cache_misses":      # JAX's name for a written entry
+        _JAXC_CACHE["written"] = True
+    elif event == "cache_retrieval_time_sec":
+        _JAXC_CACHE["read_s"] += seconds
 
 
 def _jax_phase_start(event, value, **_):
@@ -1185,35 +1184,100 @@ def _jax_phase_start(event, value, **_):
     inside another."""
     if event.startswith(_JAX_EVENT_ROOTS[0]):
         _JAXC["open"] += 1
+        if event.endswith("backend_compile_duration"):
+            _JAXC_CACHE.update(hit=False, written=False, read_s=0.0)
+
+
+def _jax_span(event, start_time, end_time, fun_name=None, **_):
+    """jax.monitoring's time-span listener: one phase, closed. A
+    top-level phase is an entry, with its start and end on the
+    profiler's clock, and while the profiler runs a span of the
+    ``compile`` lane."""
+    root = _JAX_EVENT_ROOTS[0]
+    if not event.startswith(root) or event[len(root):] not in _JAX_PHASES:
+        return
+    event = event[len(root):]
+    in_step = _STEPS["open"] > 0
+    if in_step:
+        _JAXC["in_step"] += 1
+    _JAXC["open"] = max(0, _JAXC["open"] - 1)
+    cache, read_s = None, 0.0
+    if event == "backend_compile_duration":
+        if _JAXC_CACHE["hit"]:
+            cache = "hit"
+        elif _JAXC_CACHE["written"]:
+            cache = "miss"
+        read_s = _JAXC_CACHE["read_s"]
+    if _JAXC["open"]:
+        # a jit traced while another is traced or lowered (every jnp op
+        # of the step's body): its time lies inside the outer phase's
+        _JAXC["nested"] += 1
+        return
+    seconds = float(end_time - start_time)
+    # JAX stamps a phase with time.time(): less that clock's reading at
+    # _t0, taken now (so a step or a slew of the wall clock since import
+    # moves nothing), a stamp is a time on the profiler's own clock
+    # mxlint: disable=MX007 (JAX's stamps are wall-clock: read it only to convert them, in the same instant as perf_counter)
+    t0_wall = time.time() - (time.perf_counter() - _t0)
+    start_us = (start_time - t0_wall) * 1e6
+    end_us = (end_time - t0_wall) * 1e6
+    if len(_JAXC_ENTRIES) == _RING_CAP:
+        _JAXC["dropped"] += 1
+    _JAXC_ENTRIES.append((event, fun_name, seconds, _STEPS["started"],
+                          in_step, start_us, end_us, cache, read_s))
+    _count_event(event, seconds)
+    if cache == "hit":
+        _JAXC["cache_hits"] += 1
+        _JAXC["cache_load_s"] += seconds
+    elif event == "backend_compile_duration":
+        _JAXC["fresh_compile_s"] += seconds
+        if cache == "miss":
+            _JAXC["cache_misses"] += 1
+    if _LIVE:
+        record_op("jax.%s:%s" % (_JAX_SPAN_KIND[event], fun_name),
+                  end_us - start_us, category="compile",
+                  args={"cache": cache}, lane="compile", end_us=end_us)
+
+
+_JAXC_KEYS = ("event", "fun_name", "seconds", "at_step", "in_step",
+              "start_us", "end_us", "cache", "cache_read_s")
+_JAXC_TOTALS = ("cache_hits", "cache_misses", "cache_load_s",
+                "fresh_compile_s", "nested", "dropped")
 
 
 def jax_compile_stats():
     """``metrics()['jax_compile']``: what JAX itself reported. ``entries``
-    (the last 4096, oldest first) are {event, fun_name, seconds, at_step,
-    in_step}: ``at_step`` is the number of step spans started when the
-    event fired, ``in_step`` whether one was open. ``seconds`` and
-    ``counts`` are totals by event, ``by_fun`` by function name, and
-    ``compile_s`` the three compile phases together (cache loads are
-    inside backend_compile_duration and counted once)."""
-    keys = ("event", "fun_name", "seconds", "at_step", "in_step")
+    (the last 4096 top-level phases, oldest first) are {event, fun_name,
+    seconds, at_step, in_step, start_us, end_us, cache, cache_read_s}:
+    ``at_step`` is the number of step spans started when the phase ended,
+    ``in_step`` whether one was open, ``start_us`` / ``end_us`` the phase
+    on the profiler's clock. A backend compile's ``cache`` is "hit" (loaded
+    from the persistent cache, ``cache_read_s`` of it the read), "miss"
+    (the cache did not hold it: compiled, and the entry written) or None
+    (nothing read or written: no cache in use, or a program JAX does not
+    store; every trace and lowering). ``seconds`` and ``counts``
+    are totals by event, the cache's own events too; ``compile_s`` the
+    three phases together, ``trace_s`` the first two; ``cache_load_s`` and
+    ``fresh_compile_s`` divide the third, ``cache_hits`` and
+    ``cache_misses`` count programs."""
     seconds = dict(_JAXC_SECONDS)
-    return {
-        "entries": [dict(zip(keys, e)) for e in list(_JAXC_ENTRIES)],
+    out = {
+        "entries": [dict(zip(_JAXC_KEYS, e)) for e in list(_JAXC_ENTRIES)],
         "seconds": seconds,
         "counts": dict(_JAXC_COUNTS),
-        "by_fun": {f: dict(v) for f, v in list(_JAXC_BY_FUN.items())},
         "compile_s": sum(seconds.get(p, 0.0) for p in _JAX_PHASES),
-        "nested": _JAXC["nested"],
-        "dropped": _JAXC["dropped"],
+        "trace_s": sum(seconds.get(p, 0.0) for p in _JAX_PHASES[:2]),
     }
+    out.update((k, _JAXC[k]) for k in _JAXC_TOTALS)
+    return out
 
 
 def reset_jax_compile_stats():
     _JAXC_ENTRIES.clear()
     _JAXC_SECONDS.clear()
     _JAXC_COUNTS.clear()
-    _JAXC_BY_FUN.clear()
-    _JAXC["dropped"] = _JAXC["nested"] = 0
+    _JAXC.update(nested=0, dropped=0, cache_hits=0, cache_misses=0,
+                 cache_load_s=0.0, fresh_compile_s=0.0)
 
 
 register_stats_provider("jax_compile", jax_compile_stats,
@@ -1222,6 +1286,56 @@ register_stats_provider("jax_compile", jax_compile_stats,
 jax.monitoring.register_event_duration_secs_listener(_jax_event)
 jax.monitoring.register_event_listener(_jax_event)
 jax.monitoring.register_scalar_listener(_jax_phase_start)
+jax.monitoring.register_event_time_span_listener(_jax_span)
+
+# -- start-up: what comes before the first compile ---------------------------
+# mxlint: disable=MX003 (set once, by the package's last line, and never reset)
+_SETUP = {"before_import_s": None, "import_s": None}
+
+
+def _process_age_s():
+    """Seconds since this process started, to 10 ms: /proc/self/stat's
+    start time against /proc/uptime, both in the boot clock. None where
+    there is no /proc."""
+    try:
+        with open("/proc/self/stat") as f:
+            # fields after the command's closing parenthesis start at the
+            # third; the start time is the 22nd, in clock ticks
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def note_import(t_first, t_last):
+    """The package's import, handed over by its last line:
+    ``perf_counter`` read at the first line of ``mxnet_tpu/__init__.py``
+    and at its last. Feeds ``metrics()['setup']`` once, and is an
+    ``mx.import`` span of the ``compile`` lane where profiling was
+    already on."""
+    if _SETUP["import_s"] is not None:
+        return
+    age = _process_age_s()
+    if age is not None:
+        before = age - (time.perf_counter() - t_first)
+        _SETUP["before_import_s"] = max(0.0, before)
+    _SETUP["import_s"] = t_last - t_first
+    if _LIVE:
+        record_op("mx.import", (t_last - t_first) * 1e6, category="compile",
+                  lane="compile", end_us=(t_last - _t0) * 1e6)
+
+
+def setup_stats():
+    """``metrics()['setup']``: ``before_import_s``, the process's start to
+    the package's first line (the interpreter, what was imported first,
+    a runtime started before it; None without /proc), and ``import_s``,
+    the package's own import. Set once, never reset."""
+    return dict(_SETUP)
+
+
+register_stats_provider("setup", setup_stats)
 
 
 def device_table(trace=None, hlo=None):

@@ -120,7 +120,7 @@ class MoELayer:
 # -- one chip's share of an expert-parallel layer ----------------------------
 
 MOE_STATS = ("layers", "slots_held", "slots_dropped", "max_load",
-             "rows_live")
+             "rows_live", "gate_in_kernel")
 _LARGEST = tuple(n == "max_load" for n in MOE_STATS)   # the rest are sums
 
 
@@ -350,17 +350,23 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     w_down: [held, F, D]; ``shared``: (w_gate [D, Fs], w_up, w_down
     [Fs, D]) of the shared expert, computed for every token.
     -> (y [B, S, D]: shared(x) + sum over the chosen experts HELD of weight
-    * expert(x), int32 [5] as ``MOE_STATS`` names them: 1, slots routed to
+    * expert(x), int32 [6] as ``MOE_STATS`` names them: 1, slots routed to
     experts held, those of them that no product covered (0: the buffer
     holds every slot), the largest load of an expert held, the buffer rows
-    in use (the slots held and their groups' padding to whole tiles)).
+    in use (the slots held and their groups' padding to whole tiles), 1
+    where the gate ran inside the grouped-product kernels and 0 where it
+    ran in XLA).
 
     The buffer is sized for every slot there is; a step touches the prefix
     its routing fills (``_plan``) and a token's held slots only, so the
     time around the products follows the slots held, as theirs does. On the
     TPU (and under ``interpret``) the rows move through the ``mx_moe_*``
     kernels; elsewhere, or where a row is not whole tiles
-    (``moe_rows.fits``), through ``jnp.take``."""
+    (``moe_rows.fits``), through ``jnp.take``. There too the gate between
+    the experts' first two products runs inside them
+    (``grouped_matmul.grouped_glu``) where its kernels fit VMEM
+    (``glu_fits``); elsewhere in XLA between separate products, over every
+    row of the buffer."""
     from ..pallas_kernels import grouped_matmul as _gmm
     from ..pallas_kernels import moe_rows as _rows
     B, S, D = x.shape
@@ -369,6 +375,8 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     on_tpu = jax.default_backend() == "tpu"
     how = (_gmm.TILE, bool(interpret)) if (interpret or on_tpu) and \
         _rows.fits(D, x.dtype, on_tpu and not interpret) else None
+    in_kernel = (interpret or on_tpu) and _gmm.glu_fits(
+        D, w_gate.shape[2], x.dtype)
     with jax.named_scope("mx.moe_route"):
         if route == "mlp_softmax":
             experts, weights, r = route_mlp_softmax(
@@ -386,9 +394,14 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
             plan = plan._replace(lists=lists, fetched=fetched)
         xs = _dispatch(xt, plan, how)
     with jax.named_scope("mx.moe_experts"):
-        ys = _gated(xs, w_gate, w_up, w_down,
-                    lambda a, w: _gmm.grouped_matmul(
-                        a, w, plan.sizes, plan.group_of, interpret))
+        def product(a, w):
+            return _gmm.grouped_matmul(a, w, plan.sizes, plan.group_of,
+                                       interpret)
+        if in_kernel:
+            ys = product(_gmm.grouped_glu(xs, w_gate, w_up, plan.sizes,
+                                          plan.group_of, interpret), w_down)
+        else:
+            ys = _gated(xs, w_gate, w_up, w_down, product)
     with jax.named_scope("mx.moe_combine"):
         y = _combine(ys, weights, plan, how, x.dtype)
     if shared is not None:
@@ -398,7 +411,8 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     stats = jnp.stack([jnp.int32(1), n_held_slots,
                        n_held_slots - jnp.sum(plan.live, dtype=jnp.int32),
                        jnp.max(plan.counts),
-                       jnp.sum(plan.sizes, dtype=jnp.int32)])
+                       jnp.sum(plan.sizes, dtype=jnp.int32),
+                       jnp.int32(in_kernel)])
     if route == "mlp_softmax":
         return y.reshape(B, S, D), stats, r.reshape(B, S, -1)
     return y.reshape(B, S, D), stats
@@ -410,7 +424,7 @@ def merge_stats(a, b):
 
 
 def sum_stats(stats):
-    """[n, 5] ``MOE_STATS`` of n calls as one: sums, and the largest load."""
+    """[n, 6] ``MOE_STATS`` of n calls as one: sums, and the largest load."""
     return jnp.where(jnp.array(_LARGEST), jnp.max(stats, axis=0),
                      jnp.sum(stats, axis=0))
 
@@ -436,7 +450,9 @@ def moe_stats():
     slots one held expert got in one call), ``rows_live`` (the buffer rows
     the steps' routing put in use: all that the row movements and the
     products touch), ``mean_load`` and ``live_share`` (``rows_live`` over
-    the rows the layers' buffers have)."""
+    the rows the layers' buffers have), ``gate_in_kernel`` and
+    ``gate_apart`` (the calls whose gate ran inside the grouped-product
+    kernels, and the rest: in XLA between separate products)."""
     import numpy as np
     total = np.zeros(len(MOE_STATS), np.int64)
     experts = rows = 0
@@ -453,6 +469,7 @@ def moe_stats():
     out["mean_load"] = (out["slots_held"] / (out["layers"] * experts)
                         if out["layers"] and experts else 0.0)
     out["live_share"] = out["rows_live"] / rows if rows else 0.0
+    out["gate_apart"] = out["layers"] - out["gate_in_kernel"]
     return out
 
 

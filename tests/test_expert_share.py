@@ -12,33 +12,51 @@ from mxnet_tpu.parallel import expert as X
 
 B, S, D, E, F, K = 2, 64, 32, 16, 24, 4
 SCALE = 2.5
+R = 16                              # the MLP router's width
 
 
 @pytest.fixture(scope="module")
 def weights():
-    ks = jr.split(jr.PRNGKey(0), 9)
+    ks = jr.split(jr.PRNGKey(0), 15)
     n = lambda k, shape, fan: jr.normal(k, shape) * fan ** -0.5  # noqa: E731
     return {"x": jr.normal(ks[0], (B, S, D)), "router": n(ks[1], (D, E), D),
             "bias": jr.normal(ks[2], (E,)) * 0.01,
             "w_gate": n(ks[3], (E, D, F), D), "w_up": n(ks[4], (E, D, F), D),
             "w_down": n(ks[5], (E, F, D), F),
             "shared": (n(ks[6], (D, F), D), n(ks[7], (D, F), D),
-                       n(ks[8], (F, D), F))}
+                       n(ks[8], (F, D), F)),
+            "mlp": {"down": n(ks[9], (D, R), D), "gamma": jnp.float32(0.5),
+                    "norm": jnp.ones(R), "w1": n(ks[10], (R, R), R),
+                    "w2": n(ks[11], (R, R), R), "out": n(ks[12], (R, E), R)},
+            "state": jr.normal(ks[13], (B, S, R))}
 
 
 def _gated(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
-def _loop(w, first, held, shared=True, bias=None):
+def _choice(w, bias, route):
+    """-> (the K experts [T, K] over ALL experts, their weights [T, K])."""
+    xt = w["x"].reshape(-1, D)
+    if route == "mlp_softmax":      # held to its reference in
+        chosen, weight, _ = X.route_mlp_softmax(
+            xt, w["mlp"], bias, K, w["state"].reshape(-1, R), 1e-5)
+        return chosen, weight       # test_transformer_zaya.py
+    logits = jnp.dot(xt, w["router"], precision="highest")
+    if route == "topk_softmax":
+        top, chosen = jax.lax.top_k(logits, K)
+        return chosen, jax.nn.softmax(top, axis=-1)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores + bias, K)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, picked / (picked.sum(-1, keepdims=True) + 1e-20) * SCALE
+
+
+def _loop(w, first, held, shared=True, bias=None, route="sigmoid"):
     """The layer by its equations: a loop over the experts held, a mask an
     expert; scores, choice and weights over ALL experts."""
     xt = w["x"].reshape(-1, D)
-    scores = jax.nn.sigmoid(jnp.dot(xt, w["router"], precision="highest"))
-    _, chosen = jax.lax.top_k(scores + (w["bias"] if bias is None else bias),
-                              K)
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weight = picked / (picked.sum(-1, keepdims=True) + 1e-20) * SCALE
+    chosen, weight = _choice(w, w["bias"] if bias is None else bias, route)
     y = _gated(xt, *w["shared"]) if shared else jnp.zeros_like(xt)
     for e in range(first, first + held):
         w_e = jnp.sum(jnp.where(chosen == e, weight, 0.0), axis=-1)
@@ -59,8 +77,9 @@ def _share(w, first, held, shared=True, bias=None):
 def test_a_share_is_the_loop_over_the_experts_it_holds(weights, first, held):
     y, stats = _share(weights, first, held)
     assert float(jnp.max(jnp.abs(y - _loop(weights, first, held)))) < 1e-5
-    layers, slots, dropped, most, live = (int(v) for v in stats)
-    assert (layers, dropped) == (1, 0) and most <= slots <= B * S * K
+    layers, slots, dropped, most, live, inside = (int(v) for v in stats)
+    assert (layers, dropped, inside) == (1, 0, 0)   # off the kernels
+    assert most <= slots <= B * S * K
     assert slots <= live <= slots + held * 256 and live % 256 == 0
     if held == E:
         assert slots == B * S * K          # every slot is of an expert held
@@ -91,13 +110,13 @@ def test_routing_as_uneven_as_it_can_be_drops_nothing(weights):
     slots to experts held elsewhere): that expert computes all B*S slots."""
     bias = jnp.zeros(E).at[5].set(10.0).at[jnp.array([0, 1, 2])].set(5.0)
     y, stats = _share(weights, 4, 4, shared=False, bias=bias)
-    assert [int(v) for v in stats] == [1, B * S, 0, B * S, 4 * 256]
+    assert [int(v) for v in stats] == [1, B * S, 0, B * S, 4 * 256, 0]
     want = _loop(weights, 4, 4, shared=False, bias=bias)
     assert float(jnp.max(jnp.abs(y - want))) < 1e-5
     # and every slot of every token to the four held: 4 x B*S slots
     bias = jnp.zeros(E).at[jnp.arange(4, 8)].set(10.0)
     y, stats = _share(weights, 4, 4, shared=False, bias=bias)
-    assert [int(v) for v in stats] == [1, 4 * B * S, 0, B * S, 4 * 256]
+    assert [int(v) for v in stats] == [1, 4 * B * S, 0, B * S, 4 * 256, 0]
     assert float(jnp.max(jnp.abs(
         y - _loop(weights, 4, 4, shared=False, bias=bias)))) < 1e-5
 
@@ -117,11 +136,12 @@ def test_the_shares_sum_to_the_model(weights):
 
 
 def test_counters_merge_by_sum_and_by_largest_load():
-    a, b = jnp.array([1, 10, 0, 7, 512]), jnp.array([2, 5, 1, 9, 768])
-    assert X.merge_stats(a, b).tolist() == [3, 15, 1, 9, 1280]
-    assert X.sum_stats(jnp.stack([a, b, b])).tolist() == [5, 20, 2, 9, 2048]
+    a, b = jnp.array([1, 10, 0, 7, 512, 1]), jnp.array([2, 5, 1, 9, 768, 2])
+    assert X.merge_stats(a, b).tolist() == [3, 15, 1, 9, 1280, 3]
+    assert X.sum_stats(jnp.stack([a, b, b])).tolist() == [
+        5, 20, 2, 9, 2048, 5]
     assert X.MOE_STATS == ("layers", "slots_held", "slots_dropped",
-                           "max_load", "rows_live")
+                           "max_load", "rows_live", "gate_in_kernel")
 
 
 def test_through_the_grouped_product_kernels_it_is_the_loop_too(weights,
@@ -148,6 +168,57 @@ def test_through_the_grouped_product_kernels_it_is_the_loop_too(weights,
     ref = jax.grad(lambda x: jnp.sum(jnp.sin(_loop(
         dict(weights, x=x), 4, 4, shared=False, bias=bias))))(weights["x"])
     assert float(jnp.max(jnp.abs(got - ref))) < 5e-5
+
+
+@pytest.mark.parametrize("route", ["sigmoid", "topk_softmax", "mlp_softmax"])
+def test_with_the_gate_inside_the_kernels_it_is_the_loop_for_each_router(
+        weights, monkeypatch, route):
+    """The share through ``grouped_glu`` (interpret mode, tiles of 32 rows,
+    most of the buffer's rows behind the tiles in use) against the loop:
+    the output and the gradients of x and of the three experts' matrices.
+    The call counts in ``gate_in_kernel``, the same call off the kernels in
+    ``gate_apart``."""
+    import importlib
+    import mxnet_tpu as mx
+    gmm = importlib.import_module("mxnet_tpu.pallas_kernels.grouped_matmul")
+    monkeypatch.setattr(gmm, "TILE", 32)
+    sl, names = slice(4, 8), ("x", "w_gate", "w_up", "w_down")
+    router = weights["mlp"] if route == "mlp_softmax" else weights["router"]
+
+    def share(x, wg, wu, wd, interpret=True):
+        return X.moe_share(x, router, weights["bias"], wg, wu, wd, None, k=K,
+                           first=4, route_scale=SCALE, route=route,
+                           state=weights["state"], interpret=interpret)
+
+    def loop(x, wg, wu, wd):
+        w = dict(weights, x=x, w_gate=weights["w_gate"].at[sl].set(wg),
+                 w_up=weights["w_up"].at[sl].set(wu),
+                 w_down=weights["w_down"].at[sl].set(wd))
+        return _loop(w, 4, 4, shared=False, route=route)
+
+    args = (weights["x"],) + tuple(weights[n][sl] for n in names[1:])
+    y, stats = share(*args)[:2]
+    rows = X.buffer_rows(B * S, K, 4, 32)
+    assert int(stats[4]) < 0.5 * rows
+    assert float(jnp.max(jnp.abs(y - loop(*args)))) < 1e-5
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(share(*a)[0])),
+                   argnums=(0, 1, 2, 3))(*args)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(loop(*a))),
+                    argnums=(0, 1, 2, 3))(*args)
+    for name, g, w in zip(names, got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 5e-5, name
+    apart = share(*args, interpret=False)[1]
+    assert (int(stats[5]), int(apart[5])) == (1, 0)
+    before = mx.profiler.metrics()["moe"]
+    holder = _Holder(X.merge_stats(stats, stats), 4, rows)
+    X.track(holder)
+    after = mx.profiler.metrics()["moe"]
+    assert after["gate_in_kernel"] - before["gate_in_kernel"] == 2
+    assert after["gate_apart"] == before["gate_apart"]
+    holder.moe_counters = apart
+    assert mx.profiler.metrics()["moe"]["gate_apart"] \
+        - before["gate_apart"] == 1
+    holder.moe_counters = None
 
 
 # -- the row movements: the mx_moe_* kernels against the plain gathers -------

@@ -152,6 +152,40 @@ def test_the_grouped_products_lower_at_the_trinity_cells_shape(monkeypatch):
     assert exported.mlir_module().count(chip_smoke.MOSAIC_CALL) == 3
 
 
+@pytest.mark.parametrize("rows,k,n,groups", [
+    (84224, 4096, 768, 9),      # granite-4.0-h-small's share: 8192 x 10
+    (36864, 2048, 2048, 16),    # ZAYA1-8B's whole layer: 32768 x 1
+])
+def test_the_gated_pair_lowers_at_the_expert_cells_widths(monkeypatch, rows,
+                                                           k, n, groups):
+    """``grouped_glu`` and its gradient: ``mx_gmm_glu_fwd`` (h, a and b),
+    ``mx_gmm_glu_dx`` and ``mx_gmm_dw`` twice, each asking for VMEM under
+    the cap."""
+    import jax.numpy as jnp
+    G = importlib.import_module("mxnet_tpu.pallas_kernels.grouped_matmul")
+    monkeypatch.setattr(G, "_use_pallas", lambda: True)
+    assert G.glu_fits(k, n, jnp.bfloat16)
+    assert G._glu_vmem(k, n, 2) + G._VMEM_SLACK < 100 << 20
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32)
+    group_of = jax.ShapeDtypeStruct((rows // G.TILE,), jnp.int32)
+
+    def grads(x, wg, wu, sizes, group_of):
+        h, vjp = jax.vjp(lambda x, wg, wu: G.grouped_glu(
+            x, wg, wu, sizes, group_of), x, wg, wu)
+        return (h,) + vjp(h)
+
+    exported = jax.export.export(jax.jit(grads), platforms=["tpu"])(
+        x, w, w, sizes, group_of)
+    assert [a.shape for a in exported.out_avals] == [
+        (rows, n), x.shape, w.shape, w.shape]
+    text = exported.mlir_module()
+    assert text.count(chip_smoke.MOSAIC_CALL) == 4
+    for name in ("mx_gmm_glu_fwd", "mx_gmm_glu_dx", "mx_gmm_dw"):
+        assert name in text, name
+
+
 def test_the_expert_shares_row_movements_lower_at_the_trinity_cells_shape():
     """16384 tokens x 8 slots of width 2048 in bfloat16, 32 experts held:
     dispatch, combine and both transposes through ``mx_moe_pack``,

@@ -201,6 +201,10 @@ def test_three_steps_losses_and_the_scans_facts(tiny):
     after = mx.profiler.metrics()["moe"]
     assert after["layers"] - before["layers"] == 3 * 4   # every layer routes
     assert after["slots_dropped"] == 0
+    # off the kernels every expert layer's gate runs in XLA, the scanned
+    # run of mixers' among them
+    assert after["gate_apart"] - before["gate_apart"] == 3 * 4
+    assert after["gate_in_kernel"] == before["gate_in_kernel"]
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer(tiny):

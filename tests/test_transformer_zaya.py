@@ -259,6 +259,9 @@ def test_three_steps_count_every_slot_and_note_the_stage(tiny):
     assert moe["layers"] - before["layers"] == 9
     assert moe["slots_held"] - before["slots_held"] == 9 * 256
     assert moe["slots_dropped"] == before["slots_dropped"]
+    # off the kernels every expert layer's gate runs in XLA
+    assert moe["gate_apart"] - before["gate_apart"] == 9
+    assert moe["gate_in_kernel"] == before["gate_in_kernel"]
     assert profiler.metrics()["cca"] == {
         "layers": 3, "q_latent": 64, "kv_latent": 32, "taps": [2, 2],
         "mix_bytes": 2 * 256 * (5 * 96 + 4 * 16)}
@@ -298,11 +301,12 @@ def test_the_moved_scopes_are_in_both_tables():
 # device): the plain decoder and the two tiny shares in their own
 # ``assumed`` type. The fields this model added default to those programs.
 # Re-taken at PR 38 (parent 3a185ac): its head takes the gradient in its
-# forward rule, which every one of these steps runs.
+# forward rule, which every one of these steps runs. The two shares' again
+# when their counters gained ``gate_in_kernel``, a sixth int32 carried.
 PARENT_LOWERED = {
     "dense": "0e5c5ceeaee5c46fed63c03b26df1cf0ce512645ed09a75e5ff765163345363a",
-    "afmoe": "6a6c2997eee193208e73f285ba5963d009123f04b2cdc5ad254ae313c126e921",
-    "granite": "bbad9a5a70843ff47bd53b913b352b2146ac3de4fa39e88db08d5998514520db",
+    "afmoe": "c775f70fdddec19aa5788203cb2c0e0d14ad9f2921ab0ed020f4bf2e528af9cd",
+    "granite": "95f0d303f63f79e928544fc3f46d638bde0b5dc144f29a0a3d2c644f40319179",
 }
 
 

@@ -7,7 +7,8 @@ step: ``profiler.set_state('run')`` writes an ``.xplane.pb``;
 and splits the train step's device time by the ``jax.named_scope`` names
 the program carries (``mx.embed`` ... ``mx.optimizer``; inside a layer
 ``mx.attn_proj``, ``mx.flash``, ``mx.attn_out`` (with ``mx.cca_mix`` between
-the first two where q and k are mixed) or, for a state-space mixer,
+the first two where q and k are mixed, ``mx.mla_latent`` where they come
+through latents) or, for a state-space mixer,
 ``mx.ssm_proj``, ``mx.ssm_conv``, ``mx.ssm_scan``, ``mx.ssm_gate``; then
 ``mx.ffn`` and an expert share's ``mx.moe_*``), into forward, backward and
 recompute, with the Pallas kernels by their ``name=`` and the

@@ -78,12 +78,12 @@ def _use_pallas():
     return jax.default_backend() == "tpu"
 
 
-def _params(interpret, vmem_bytes):
+def _params(interpret, vmem_bytes, axes=1):
     if interpret:
         return None
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",),
+        dimension_semantics=("arbitrary",) * axes,
         vmem_limit_bytes=int(min(vmem_bytes + _VMEM_SLACK, _VMEM_CAP)))
 
 
@@ -138,9 +138,10 @@ def _product(a, w, group_of, used, transposed, interpret):
     )(group_of, used, a, w)
 
 
-def _dw_kernel(group_of, used, x_ref, dy_ref, dw_ref, acc, *, n_tiles):
+def _dw_kernel(group_of, used, x_ref, dy_ref, dw_ref, acc, *, n_tiles,
+               axis=0):
     import jax.experimental.pallas as pl
-    i = pl.program_id(0)
+    i = pl.program_id(axis)
     mine = group_of[_tile_in_use(i, used)]
     before = group_of[jnp.maximum(i - 1, 0)]
     after = group_of[jnp.minimum(i + 1, n_tiles - 1)]
@@ -160,27 +161,55 @@ def _dw_kernel(group_of, used, x_ref, dy_ref, dw_ref, acc, *, n_tiles):
         dw_ref[0] = acc[...].astype(dw_ref.dtype)
 
 
+def _dw_cols(k, n, item):
+    """Columns of a group's dw [k, n] that one pass over its tiles
+    computes: all n where the block (twice, in ``item`` bytes), its float32
+    sum and the tiles fit under the cap; else the widest divisor of n in
+    whole lanes that fits, and x's tiles are read once a pass."""
+    def need(cols):
+        return 2 * item * (TILE * (k + cols) + k * cols) + 4 * k * cols
+
+    fits = [c for c in range(128, n + 1, 128)
+            if n % c == 0 and need(c) + _VMEM_SLACK <= _VMEM_CAP]
+    return n if n in fits or not fits else max(fits)
+
+
 def _dw(x, dy, group_of, used, n_groups, interpret):
     """dw[g] = sum over the tiles of group g of x[tile]^T @ dy[tile]. Every
     group has a tile (a group of no rows has one of padding, whose dy is
-    nought), so every block of the result is written."""
+    nought), so every block of the result is written. Where a group's
+    whole block does not fit VMEM (``_dw_cols``) the grid's outer axis
+    takes its columns a block at a time."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     rows, k = x.shape
     n = dy.shape[1]
     item = x.dtype.itemsize
-    vmem = 2 * item * (TILE * (k + n) + k * n) + 2 * 4 * k * n
+    cols = _dw_cols(k, n, item)
+    if cols == n:
+        vmem = 2 * item * (TILE * (k + n) + k * n) + 2 * 4 * k * n
+        grid, axis = (rows // TILE,), 0
+        specs = ([pl.BlockSpec((TILE, k), _row_tile),
+                  pl.BlockSpec((TILE, n), _row_tile)],
+                 pl.BlockSpec((1, k, n), _group_block))
+    else:
+        vmem = 2 * item * (TILE * (k + cols) + k * cols) + 4 * k * cols
+        grid, axis = (n // cols, rows // TILE), 1
+        specs = ([pl.BlockSpec((TILE, k), lambda j, i, g, u: (
+                      _tile_in_use(i, u), 0)),
+                  pl.BlockSpec((TILE, cols), lambda j, i, g, u: (
+                      _tile_in_use(i, u), j))],
+                 pl.BlockSpec((1, k, cols), lambda j, i, g, u: (
+                     g[_tile_in_use(i, u)], 0, j)))
     return pl.pallas_call(
-        functools.partial(_dw_kernel, n_tiles=rows // TILE),
+        functools.partial(_dw_kernel, n_tiles=rows // TILE, axis=axis),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(rows // TILE,),
-            in_specs=[pl.BlockSpec((TILE, k), _row_tile),
-                      pl.BlockSpec((TILE, n), _row_tile)],
-            out_specs=pl.BlockSpec((1, k, n), _group_block),
-            scratch_shapes=[pltpu.VMEM((k, n), jnp.float32)]),
+            num_scalar_prefetch=2, grid=grid, in_specs=specs[0],
+            out_specs=specs[1],
+            scratch_shapes=[pltpu.VMEM((k, cols), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((n_groups, k, n), x.dtype),
-        compiler_params=_params(interpret, vmem), interpret=interpret,
-        name="mx_gmm_dw",
+        compiler_params=_params(interpret, vmem, len(grid)),
+        interpret=interpret, name="mx_gmm_dw",
     )(group_of, used, x, dy)
 
 

@@ -11,9 +11,10 @@ requirement — with capacity_factor bounding per-expert load.
 ``moe_share`` is the other kind of expert layer: one chip's share of an
 expert-parallel layer. It is told which experts it holds (a contiguous
 range), routes over all of them (sigmoid scores, top-k of score + bias,
-weights normalised over the k chosen; or the k largest logits and a softmax
-over those; or an MLP with a state carried from layer to layer, a softmax
-over all experts, the chosen probabilities as they are), sorts the
+inside each token's best groups of experts where the routing is limited to
+groups, weights normalised over the k chosen; or the k largest logits and a
+softmax over those; or an MLP with a state carried from layer to layer, a
+softmax over all experts, the chosen probabilities as they are), sorts the
 token-slots by expert, runs
 grouped products over the slots of the experts held
 (``pallas_kernels/grouped_matmul.py``) and gathers the weighted results
@@ -120,23 +121,41 @@ class MoELayer:
 # -- one chip's share of an expert-parallel layer ----------------------------
 
 MOE_STATS = ("layers", "slots_held", "slots_dropped", "max_load",
-             "rows_live", "gate_in_kernel")
+             "rows_live", "gate_in_kernel", "tokens_to_held_groups")
 _LARGEST = tuple(n == "max_load" for n in MOE_STATS)   # the rest are sums
 
 
-def route_sigmoid(h, router_w, bias, k, route_scale=1.0):
+def kept_groups(biased, groups, kept):
+    """[T, groups] bool: each token's ``kept`` groups of its experts
+    [T, E] biased scores, the best by the sum of a group's two largest."""
+    T, E = biased.shape
+    top2 = lax.top_k(biased.reshape(T, groups, E // groups), 2)[0]
+    _, best = lax.top_k(jnp.sum(top2, axis=-1), kept)
+    return jnp.any(best[:, :, None] == jnp.arange(groups), axis=1)
+
+
+def route_sigmoid(h, router_w, bias, k, route_scale=1.0, groups=(1, 1)):
     """Scores over ALL experts in float32, the k chosen by score + bias
     (``bias`` is a buffer: it selects and carries no gradient), weights the
     chosen scores normalised over the k and scaled. h: [T, D]; router_w:
-    [D, E]; bias: [E]. -> (experts [T, k] int32, weights [T, k] float32)."""
+    [D, E]; bias: [E]. ``groups`` (n, kept): the E experts in n groups, the
+    k chosen inside each token's ``kept_groups`` only (an expert outside
+    them is never chosen); (1, 1) is every expert. -> (experts [T, k]
+    int32, weights [T, k] float32, the kept groups [T, n] bool or None
+    where n is 1)."""
     logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, experts = lax.top_k(scores + lax.stop_gradient(
-        bias.astype(jnp.float32)), k)
+    biased = scores + lax.stop_gradient(bias.astype(jnp.float32))
+    kept = None
+    if groups[0] > 1:
+        kept = kept_groups(biased, *groups)
+        biased = jnp.where(jnp.repeat(kept, biased.shape[1] // groups[0],
+                                      axis=1), biased, -jnp.inf)
+    _, experts = lax.top_k(biased, k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
-    return experts, weights * route_scale
+    return experts, weights * route_scale, kept
 
 
 def route_topk_softmax(h, router_w, k):
@@ -336,7 +355,7 @@ def _gated(x, w_gate, w_up, w_down, product):
 
 def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
               first=0, route_scale=1.0, route="sigmoid", state=None,
-              eps=1e-5, interpret=False):
+              eps=1e-5, groups=(1, 1), interpret=False):
     """The share of an expert layer that holds experts ``first`` to
     ``first + w_gate.shape[0]`` of the ``router_w.shape[1]`` routed over.
 
@@ -345,17 +364,19 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     none and takes no ``route_scale``; "mlp_softmax":
     ``route_mlp_softmax``, for which ``router_w`` is that router's leaves,
     ``state`` [B, S, R] the layer before's r, ``eps`` its norm's, and the
-    layer's own r [B, S, R] is returned as a third result); w_gate, w_up:
-    [held, D, F];
+    layer's own r [B, S, R] is returned as a third result); ``groups`` (n,
+    kept): "sigmoid"'s limit to groups, refused with another ``route``;
+    w_gate, w_up: [held, D, F];
     w_down: [held, F, D]; ``shared``: (w_gate [D, Fs], w_up, w_down
     [Fs, D]) of the shared expert, computed for every token.
     -> (y [B, S, D]: shared(x) + sum over the chosen experts HELD of weight
-    * expert(x), int32 [6] as ``MOE_STATS`` names them: 1, slots routed to
+    * expert(x), int32 [7] as ``MOE_STATS`` names them: 1, slots routed to
     experts held, those of them that no product covered (0: the buffer
     holds every slot), the largest load of an expert held, the buffer rows
     in use (the slots held and their groups' padding to whole tiles), 1
     where the gate ran inside the grouped-product kernels and 0 where it
-    ran in XLA).
+    ran in XLA, the tokens whose kept groups hold an expert held (0 where
+    the routing is not limited to groups)).
 
     The buffer is sized for every slot there is; a step touches the prefix
     its routing fills (``_plan``) and a token's held slots only, so the
@@ -369,6 +390,9 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     row of the buffer."""
     from ..pallas_kernels import grouped_matmul as _gmm
     from ..pallas_kernels import moe_rows as _rows
+    if groups[0] > 1 and route != "sigmoid":
+        raise ValueError("groups=%r: routing limited to groups is the "
+                         "sigmoid router's only, not %r's" % (groups, route))
     B, S, D = x.shape
     T, n_held = B * S, w_gate.shape[0]
     xt = x.reshape(T, D)
@@ -381,11 +405,13 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
         if route == "mlp_softmax":
             experts, weights, r = route_mlp_softmax(
                 xt, router_w, bias, k, state.reshape(T, -1), eps)
+            kept = None
         elif route == "topk_softmax":
             experts, weights = route_topk_softmax(xt, router_w, k)
+            kept = None
         else:
-            experts, weights = route_sigmoid(xt, router_w, bias, k,
-                                             route_scale)
+            experts, weights, kept = route_sigmoid(xt, router_w, bias, k,
+                                                   route_scale, groups)
     with jax.named_scope("mx.moe_dispatch"):
         plan = _plan(experts, first, n_held, _gmm.TILE)
         if how is not None:
@@ -408,11 +434,16 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
         with jax.named_scope("mx.moe_shared"):
             y = y + _gated(xt, *shared, jnp.dot)
     n_held_slots = jnp.sum(plan.held, dtype=jnp.int32)
+    to_held = jnp.int32(0)
+    if kept is not None:
+        size = router_w.shape[1] // groups[0]
+        mine = kept[:, first // size:(first + n_held - 1) // size + 1]
+        to_held = jnp.sum(jnp.any(mine, axis=1), dtype=jnp.int32)
     stats = jnp.stack([jnp.int32(1), n_held_slots,
                        n_held_slots - jnp.sum(plan.live, dtype=jnp.int32),
                        jnp.max(plan.counts),
                        jnp.sum(plan.sizes, dtype=jnp.int32),
-                       jnp.int32(in_kernel)])
+                       jnp.int32(in_kernel), to_held])
     if route == "mlp_softmax":
         return y.reshape(B, S, D), stats, r.reshape(B, S, -1)
     return y.reshape(B, S, D), stats
@@ -424,7 +455,7 @@ def merge_stats(a, b):
 
 
 def sum_stats(stats):
-    """[n, 6] ``MOE_STATS`` of n calls as one: sums, and the largest load."""
+    """[n, 7] ``MOE_STATS`` of n calls as one: sums, and the largest load."""
     return jnp.where(jnp.array(_LARGEST), jnp.max(stats, axis=0),
                      jnp.sum(stats, axis=0))
 
@@ -452,7 +483,10 @@ def moe_stats():
     products touch), ``mean_load`` and ``live_share`` (``rows_live`` over
     the rows the layers' buffers have), ``gate_in_kernel`` and
     ``gate_apart`` (the calls whose gate ran inside the grouped-product
-    kernels, and the rest: in XLA between separate products)."""
+    kernels, and the rest: in XLA between separate products),
+    ``tokens_to_held_groups`` (where the routing is limited to groups: the
+    tokens, a layer call each, whose kept groups hold an expert held; 0
+    where no step's routing is)."""
     import numpy as np
     total = np.zeros(len(MOE_STATS), np.int64)
     experts = rows = 0

@@ -47,6 +47,7 @@ from .ring_attention import ring_attention, blockwise_attention
 from .ulysses import ulysses_attention_local
 from . import cca as _cca
 from . import expert as _expert
+from . import mla as _mla
 from . import ssm as _ssm
 from .expert import moe_ffn
 
@@ -121,8 +122,9 @@ class TransformerConfig:
     layer_pattern: tuple = ()
     window: Optional[int] = None
     # attention kinds of the leading dense layers (gated FFN of width
-    # ffn_hidden) that come before the scanned periods; only with a
-    # layer_pattern. n_layers = len(dense_layers) + periods * len(pattern)
+    # ffn_hidden) that come before the scanned periods, or before the
+    # scanned layers where there is no layer_pattern. n_layers =
+    # len(dense_layers) + periods * len(pattern), or + the scanned layers
     dense_layers: tuple = ()
     rope_on: str = "all"       # 'all' | 'sliding' | 'none': which kinds rotate
     norm_eps: float = 1e-6
@@ -182,10 +184,69 @@ class TransformerConfig:
     # ``route`` "mlp_softmax" (expert.route_mlp_softmax): the router is an
     # MLP of this width whose first state travels from layer to layer
     router_hidden: int = 0
+    # -- what latent attention, a share of the heads, YaRN positions and
+    # group-limited routing need, each defaulting to the program there was --
+    # "qkv": q, k and v projected from the normed input; "mla" (``parallel/
+    # mla.py``): through a q latent of ``q_latent`` and a key-value latent of
+    # ``kv_latent``, each head's q and k [nope_head | rope_head] (one rope
+    # vector of k a position shared by every head), v of the same size
+    attention: str = "qkv"
+    q_latent: int = 0
+    kv_latent: int = 0
+    nope_head: int = 0
+    rope_head: int = 0
+    # "mla" attention as one chip's SHARE of the heads: how many of the
+    # n_heads it holds (0 = all); the up-projections, the kernel and the
+    # output rows are theirs, the weights handed in already cut to them, and
+    # the partial output goes on as it is
+    heads_held: int = 0
+    # the published ``rope_scaling`` group, or None: {"type" or "rope_type":
+    # "yarn", "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "mscale", "mscale_all_dim"} blends the frequencies
+    # (``yarn_freqs``) and scales the scores (``scores_scale``)
+    rope_scaling: Optional[dict] = None
+    # ``route`` "sigmoid" limited to groups: the experts in route_groups
+    # groups, the route_groups_kept best by the sum of their two largest
+    # biased scores kept, the k chosen inside those (expert.route_sigmoid)
+    route_groups: int = 1
+    route_groups_kept: int = 1
+
+    def __post_init__(self):
+        if self.heads_held and self.attention != "mla":
+            raise ValueError(
+                "heads_held=%d: a share of the heads is latent attention's "
+                "only; attention=%r projects and runs every head"
+                % (self.heads_held, self.attention))
+        if self.route_groups > 1 and (self.route != "sigmoid"
+                                      or self.moe_hidden is None):
+            raise ValueError(
+                "route_groups=%d: routing limited to groups is the expert "
+                "share's sigmoid router only (moe_hidden set, route="
+                "'sigmoid'), not route=%r with moe_hidden=%r"
+                % (self.route_groups, self.route, self.moe_hidden))
 
     @property
     def head_dim(self):
+        if self.attention == "mla":
+            return self.nope_head + self.rope_head
         return self.head_size or self.dim // self.n_heads
+
+    @property
+    def heads_here(self):
+        """The query heads this chip computes: a share's count, else all."""
+        return self.heads_held or self.n_heads
+
+    @property
+    def scores_scale(self):
+        """What the scores are scaled by where it is not head ** -0.5, else
+        None: ``attn_scale``, or YaRN's head ** -0.5 * mscale ** 2 where
+        the rope scaling gives an ``mscale_all_dim``."""
+        yarn = self.rope_scaling
+        if self.attn_scale is not None or not yarn \
+                or not yarn.get("mscale_all_dim"):
+            return self.attn_scale
+        m = yarn_mscale(yarn["factor"], yarn["mscale_all_dim"])
+        return self.head_dim ** -0.5 * m * m
 
     @property
     def kv_heads(self):
@@ -230,25 +291,71 @@ def _rms_norm(x, scale, eps):
     return (x * lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def _rope(x, positions, theta=10000.0):
+def yarn_mscale(factor, mscale):
+    """YaRN's attention factor 0.1 * mscale * ln(factor) + 1 (1 where the
+    factor does not stretch)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dims, theta, scaling):
+    """YaRN's inverse frequencies [dims / 2] (arXiv:2309.00071, as the
+    DeepSeek-V3 family computes them): theta ** (-j / half) for the dims
+    that turn fast, the same over ``factor`` for the slow ones, and a
+    linear ramp between the correction dims that ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position_embeddings`` give.
+    float32 numpy, a constant of the program."""
+    import numpy as np
+    half = dims // 2
+    orig = scaling["original_max_position_embeddings"]
+
+    def dim_of(rotations):
+        return dims * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(scaling["beta_fast"])), 0)
+    high = min(math.ceil(dim_of(scaling["beta_slow"])), dims - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / ((high - low) or 0.001), 0, 1)
+    extra = 1.0 / theta ** (np.arange(half, dtype=np.float32) / half)
+    return (extra / scaling["factor"] * ramp
+            + extra * (1 - ramp)).astype(np.float32)
+
+
+def _rope(x, positions, theta=10000.0, freqs=None, mult=1.0):
     """Rotary position embedding at base ``theta``: dim j of the first half
-    rotates with dim j + half. x: [B, H, S, D_h], positions: [S]."""
+    rotates with dim j + half. x: [B, H, S, D_h], positions: [S].
+    ``freqs``: inverse frequencies [D_h / 2] in the base's place;
+    ``mult``: cos and sin times this."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if freqs is None:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [S, half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if mult != 1.0:
+        cos, sin = cos * mult, sin * mult
     x1, x2 = x[..., :half], x[..., half:]
     rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return rot.astype(x.dtype)
 
 
 def _rotated(cfg, a, positions):
-    """``_rope`` at the configuration's base on a: [B, S, heads, D_h]; where
-    it rotates ``rope_dims`` of a head only, the rest pass as they are."""
+    """``_rope`` at the configuration's base (and its YaRN frequencies and
+    factor, where it has them) on a: [B, S, heads, D_h]; where it rotates
+    ``rope_dims`` of a head only, the rest pass as they are."""
+    yarn, freqs, mult = cfg.rope_scaling, None, 1.0
+    if yarn:
+        if yarn.get("rope_type", yarn.get("type")) != "yarn":
+            raise NotImplementedError("rope_scaling %r" % (yarn,))
+        freqs = jnp.asarray(yarn_freqs(cfg.rope_dims or a.shape[-1],
+                                       cfg.rope_theta, yarn))
+        mult = yarn_mscale(yarn["factor"], yarn.get("mscale", 1)) \
+            / yarn_mscale(yarn["factor"], yarn.get("mscale_all_dim", 0))
+
     def turned(t):
         return jnp.transpose(_rope(jnp.transpose(t, (0, 2, 1, 3)), positions,
-                                   cfg.rope_theta), (0, 2, 1, 3))
+                                   cfg.rope_theta, freqs, mult),
+                             (0, 2, 1, 3))
 
     dims = cfg.rope_dims
     if dims is None or dims == a.shape[-1]:
@@ -275,6 +382,9 @@ def _layer_leaves(cfg, experts=True, kind="full"):
     D, H, G, Dh = cfg.dim, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     if kind == "mamba":
         out = {"ln1": ((D,), None, (None,)), **_ssm.mixer_leaves(cfg),
+               "ln2": ((D,), None, (None,))}
+    elif cfg.attention == "mla":
+        out = {"ln1": ((D,), None, (None,)), **_mla.mla_leaves(cfg),
                "ln2": ((D,), None, (None,))}
     else:
         out = {"ln1": ((D,), None, (None,)),
@@ -372,7 +482,12 @@ def _stacks(cfg):
         assert cfg.n_layers % cfg.pp == 0, "n_layers must divide pp"
         return {"layers": ((cfg.pp, cfg.n_layers // cfg.pp), ("pp", None),
                            True, "full")}
-    return {"layers": ((cfg.n_layers,), (None,), True, "full")}
+    n = len(cfg.dense_layers)
+    out = {"layers": ((cfg.n_layers - n,), (None,), True, "full")}
+    if n:   # a plain stack after them: its scan reads each layer's leaves
+        #     as they are, where a pattern's slice of them is a copy
+        out["dense"] = ((n,), (None,), False, "full")
+    return out
 
 
 def init_params(key, cfg: TransformerConfig):
@@ -387,7 +502,7 @@ def init_params(key, cfg: TransformerConfig):
 
     def keys_of(leaves, salt):
         keys = {n: jr.fold_in(key, salt + i) for i, n in enumerate(leaves)}
-        if not cfg.layer_pattern:
+        if not cfg.layer_pattern and not cfg.dense_layers:
             # the plain decoder's seeded states were made from split keys:
             # one a matrix by its place, the GShard leaves' from the eighth
             ks = jr.split(key, 8)
@@ -461,7 +576,7 @@ def _attention(cfg, mesh, q, k, v, positions, window=None):
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
     if (window is not None or kt.shape[1] != qt.shape[1]
-            or cfg.attn_scale is not None) and cfg.attn_mode != "local":
+            or cfg.scores_scale is not None) and cfg.attn_mode != "local":
         raise NotImplementedError(
             "grouped key-value heads, windows and a scale of the scores run "
             "in attn_mode='local' only (the flash kernels); attn_mode=%r has "
@@ -491,8 +606,8 @@ def _attention(cfg, mesh, q, k, v, positions, window=None):
             attend = functools.partial(flash_attention, causal=cfg.causal)
             if window is not None:
                 attend = functools.partial(attend, window=window)
-            if cfg.attn_scale is not None:
-                attend = functools.partial(attend, scale=cfg.attn_scale)
+            if cfg.scores_scale is not None:
+                attend = functools.partial(attend, scale=cfg.scores_scale)
             sizes = _mesh_sizes(mesh)
             if any(n > 1 for n in sizes.values()):
                 # GSPMD cannot partition a Mosaic kernel ("wrap the call
@@ -514,7 +629,7 @@ def _attention(cfg, mesh, q, k, v, positions, window=None):
         else:
             from ..pallas_kernels.flash_attention import attention_reference
             ot = attention_reference(qt, kt, vt, causal=cfg.causal,
-                                     scale=cfg.attn_scale, window=window)
+                                     scale=cfg.scores_scale, window=window)
     return jnp.transpose(ot, (0, 2, 1, 3))
 
 
@@ -542,7 +657,7 @@ def _ffn(cfg, lp, h, experts, r=None):
             w_gate=lp["moe_w_gate"], w_up=lp["moe_w_up"],
             w_down=lp["moe_w_down"], shared=shared, k=cfg.moe_k,
             first=cfg.expert_share[0], route_scale=cfg.route_scale,
-            route=cfg.route)
+            route=cfg.route, groups=(cfg.route_groups, cfg.route_groups_kept))
         if cfg.router_state:
             y, stats, r = share({n: lp["moe_router_" + n] for n in
                                  _expert.ROUTER_MLP}, state=r,
@@ -607,9 +722,32 @@ def _layer_body(cfg, mesh, positions, x, lp, kind="full", experts=True):
         return (x, r) if cfg.router_state else x, stats, balance
 
 
+def _latent_qkv(cfg, positions, x, lp):
+    """q, k, v [B, S, heads held, d] of latent attention (``parallel/
+    mla.py``): the input norm under ``mx.attn_proj``, the latents and the
+    nope and rope parts laid together under ``mx.mla_latent``, the rotary
+    positions of the rope parts under ``mx.attn_proj``."""
+    def norm(a, scale):
+        return _rms_norm(a, scale, cfg.norm_eps)
+
+    with jax.named_scope("mx.attn_proj"):
+        h = norm(x, lp["ln1"])
+    with jax.named_scope("mx.mla_latent"):
+        q_nope, q_pe, k_nope, k_pe, v = _mla.latents(h, lp, cfg, norm)
+    with jax.named_scope("mx.attn_proj"):
+        q_pe = _rotated(cfg, q_pe, positions)
+        k_pe = _rotated(cfg, k_pe, positions)
+    with jax.named_scope("mx.mla_latent"):
+        q, k = _mla.joined(q_nope, q_pe, k_nope, k_pe)
+    return q, k, v
+
+
 def _attend(cfg, mesh, positions, x, lp, sliding):
     """x with what the layer's attention adds to it."""
     eps = cfg.norm_eps
+    if cfg.attention == "mla":
+        q, k, v = _latent_qkv(cfg, positions, x, lp)
+        return _attended(cfg, mesh, positions, x, lp, sliding, q, k, v)
     with jax.named_scope("mx.attn_proj"):
         h = _rms_norm(x, lp["ln1"], eps)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
@@ -636,11 +774,20 @@ def _attend(cfg, mesh, positions, x, lp, sliding):
         with jax.named_scope("mx.attn_proj"):   # after the mixing stage
             q = _rotated(cfg, q, positions)
             k = _rotated(cfg, k, positions)
+    return _attended(cfg, mesh, positions, x, lp, sliding, q, k, v,
+                     gate if cfg.attn_gate else None)
+
+
+def _attended(cfg, mesh, positions, x, lp, sliding, q, k, v, gate=None):
+    """x with the attention of q, k and v added: the kernel under
+    ``mx.flash``, the gate, output projection and norm under
+    ``mx.attn_out``."""
+    eps = cfg.norm_eps
     with jax.named_scope("mx.flash"):
         o = _ckpt_name(_attention(cfg, mesh, q, k, v, positions,
                                   cfg.window if sliding else None), "attn_o")
     with jax.named_scope("mx.attn_out"):
-        if cfg.attn_gate:
+        if gate is not None:
             o = o * jax.nn.sigmoid(gate)
         a = jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
         if cfg.post_norms:
@@ -692,8 +839,8 @@ def _remat_rows(cfg, batch, seq, sizes):
     ``_attention`` cuts them, for each layer that has attention."""
     dp, tp = sizes.get("dp", 1), sizes.get("tp", 1)
     b = batch // dp if batch % dp == 0 else batch
-    h = cfg.n_heads // tp if cfg.n_heads % tp == 0 \
-        and cfg.kv_heads % tp == 0 else cfg.n_heads
+    h = cfg.heads_here // tp if cfg.heads_here % tp == 0 \
+        and cfg.kv_heads % tp == 0 else cfg.heads_here
     rows = b * h * seq
     return [(("flash_out", "flash_lse"),
              rows * (cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4))]
@@ -1244,6 +1391,8 @@ class _Step:
                 _ssm.note(cfg, *tokens.shape)
             if cfg.qk_mix == "cca":
                 _cca.note(cfg, *tokens.shape)
+            if cfg.attention == "mla":
+                _mla.note(cfg, *tokens.shape)
             (loss, stats), grads = jax.value_and_grad(
                 _loss_and_stats, has_aux=True)(
                     params, tokens, targets,

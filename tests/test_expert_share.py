@@ -77,8 +77,10 @@ def _share(w, first, held, shared=True, bias=None):
 def test_a_share_is_the_loop_over_the_experts_it_holds(weights, first, held):
     y, stats = _share(weights, first, held)
     assert float(jnp.max(jnp.abs(y - _loop(weights, first, held)))) < 1e-5
-    layers, slots, dropped, most, live, inside = (int(v) for v in stats)
+    layers, slots, dropped, most, live, inside, to_held = (
+        int(v) for v in stats)
     assert (layers, dropped, inside) == (1, 0, 0)   # off the kernels
+    assert to_held == 0                              # routed without groups
     assert most <= slots <= B * S * K
     assert slots <= live <= slots + held * 256 and live % 256 == 0
     if held == E:
@@ -110,13 +112,14 @@ def test_routing_as_uneven_as_it_can_be_drops_nothing(weights):
     slots to experts held elsewhere): that expert computes all B*S slots."""
     bias = jnp.zeros(E).at[5].set(10.0).at[jnp.array([0, 1, 2])].set(5.0)
     y, stats = _share(weights, 4, 4, shared=False, bias=bias)
-    assert [int(v) for v in stats] == [1, B * S, 0, B * S, 4 * 256, 0]
+    assert [int(v) for v in stats] == [1, B * S, 0, B * S, 4 * 256, 0, 0]
     want = _loop(weights, 4, 4, shared=False, bias=bias)
     assert float(jnp.max(jnp.abs(y - want))) < 1e-5
     # and every slot of every token to the four held: 4 x B*S slots
     bias = jnp.zeros(E).at[jnp.arange(4, 8)].set(10.0)
     y, stats = _share(weights, 4, 4, shared=False, bias=bias)
-    assert [int(v) for v in stats] == [1, 4 * B * S, 0, B * S, 4 * 256, 0]
+    assert [int(v) for v in stats] == [1, 4 * B * S, 0, B * S, 4 * 256, 0,
+                                       0]
     assert float(jnp.max(jnp.abs(
         y - _loop(weights, 4, 4, shared=False, bias=bias)))) < 1e-5
 
@@ -136,12 +139,14 @@ def test_the_shares_sum_to_the_model(weights):
 
 
 def test_counters_merge_by_sum_and_by_largest_load():
-    a, b = jnp.array([1, 10, 0, 7, 512, 1]), jnp.array([2, 5, 1, 9, 768, 2])
-    assert X.merge_stats(a, b).tolist() == [3, 15, 1, 9, 1280, 3]
+    a = jnp.array([1, 10, 0, 7, 512, 1, 0])
+    b = jnp.array([2, 5, 1, 9, 768, 2, 30])
+    assert X.merge_stats(a, b).tolist() == [3, 15, 1, 9, 1280, 3, 30]
     assert X.sum_stats(jnp.stack([a, b, b])).tolist() == [
-        5, 20, 2, 9, 2048, 5]
+        5, 20, 2, 9, 2048, 5, 60]
     assert X.MOE_STATS == ("layers", "slots_held", "slots_dropped",
-                           "max_load", "rows_live", "gate_in_kernel")
+                           "max_load", "rows_live", "gate_in_kernel",
+                           "tokens_to_held_groups")
 
 
 def test_through_the_grouped_product_kernels_it_is_the_loop_too(weights,

@@ -151,3 +151,47 @@ def test_the_kernels_keep_their_names():
         argnums=(0, 1, 2)))(x, w, w))
     for name in ("mx_gmm_glu_fwd", "mx_gmm_glu_dx", "mx_gmm_dw"):
         assert name in text
+
+
+def test_a_dw_block_too_large_for_vmem_is_taken_in_column_blocks(
+        small_tiles, monkeypatch):
+    """Where a group's whole dw block, its float32 sum and the tiles miss
+    the VMEM cap, ``mx_gmm_dw`` takes the block's columns in passes (here
+    two of 128 under a cap set low); the gradients are ragged_dot's all the
+    same. At the benchmark's widths only the 7168-wide hidden state splits:
+    every other expert cell keeps its one pass."""
+    import json
+    import os
+    monkeypatch.setattr(G, "_VMEM_CAP", G._VMEM_SLACK + 200000)
+    assert G._dw_cols(64, 256, 4) == 128
+    tile, (k, n), counts = small_tiles, (64, 256), [40, 0, 70, 5]
+    rows = 11 * tile
+    sizes, group_of = _layout(counts, rows, tile)
+    x = jr.normal(jr.PRNGKey(0), (rows, k))
+    w = jr.normal(jr.PRNGKey(1), (len(counts), k, n))
+    dy = jr.normal(jr.PRNGKey(2), (rows, n))
+    laid = (jnp.arange(rows) < int(sizes.sum()))[:, None]
+
+    def loss(product):
+        return lambda x, w: jnp.sum(jnp.where(laid, product(x, w), 0) * dy)
+
+    got = jax.grad(loss(lambda x, w: G.grouped_matmul(
+        x, w, sizes, group_of, interpret=True)), argnums=1)(x, w)
+    want = jax.grad(loss(lambda x, w: lax.ragged_dot(x, w, sizes)),
+                    argnums=1)(x, w)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-4
+    monkeypatch.undo()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    configs = os.path.join(root, "chipbench", "configs")
+    for name in sorted(os.listdir(configs)):
+        with open(os.path.join(configs, name)) as f:
+            m = json.load(f)
+        if "num_experts_held" not in m:
+            continue
+        d = m["hidden_size"]
+        f = m.get("moe_intermediate_size") or m["intermediate_size"]
+        split = (d == 7168)
+        assert (G._dw_cols(d, f, 2) < f, G._dw_cols(f, d, 2) < d) == (
+            split, split), name
+    assert (G._dw_cols(7168, 2048, 2), G._dw_cols(2048, 7168, 2)) == (
+        1024, 3584)

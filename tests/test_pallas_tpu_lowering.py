@@ -289,3 +289,60 @@ def test_the_mixers_gradient_moves_its_arrays_in_bfloat16_on_a_v5e(one_v5e):
              if "mx.ssm_conv" in line or "mx.ssm_gate" in line]
     assert len(under) > 10
     assert [line.strip()[:120] for line in under if wide.search(line)] == []
+
+
+def _compiled_for(one_v5e, fn, *args):
+    """fn compiled for the described chip, kept out of the persistent
+    cache (a program for a described chip cannot be read back from it)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+
+
+def test_the_latent_attention_cells_kernels_compile_on_a_v5e(one_v5e,
+                                                             monkeypatch):
+    """GigaChat3.1's share, compiled for the described chip: the flash
+    kernels at one head size of 192 lanes (q and k 128 + 64, v 192; 8 heads
+    held, 4096 positions, causal, YaRN's scale), unpadded; and the expert
+    products' gradient at hidden 7168 against 8 experts of 2048, whose
+    ``mx_gmm_dw`` blocks ([7168, 2048] and [2048, 7168]) miss VMEM whole and
+    go in column blocks of 1024 and 3584."""
+    import jax.numpy as jnp
+    FA = importlib.import_module("mxnet_tpu.pallas_kernels.flash_attention")
+    G = importlib.import_module("mxnet_tpu.pallas_kernels.grouped_matmul")
+    monkeypatch.setattr(FA, "_use_pallas", lambda: True)
+    monkeypatch.setattr(G, "_use_pallas", lambda: True)
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    def attention(q, k, v, g):
+        out, vjp = jax.vjp(lambda a, b, c: FA.flash_attention(
+            a, b, c, causal=True, scale=0.1446796), q, k, v)
+        return (out,) + vjp(g)
+
+    q = spec(1, 8, 4096, 192)
+    text = _compiled_for(one_v5e, attention, q, q, q, q).as_text()
+    for name in ("mx_flash_fwd", "mx_flash_dq", "mx_flash_dkv"):
+        assert name in text, name
+    assert "bf16[1,8,4096,256]" not in text     # no padding to 256 lanes
+    assert (G._dw_cols(7168, 2048, 2), G._dw_cols(2048, 7168, 2)) == (
+        1024, 3584)
+    rows = 4096 * 8 + 8 * G.TILE
+
+    def dws(x, h, dy, dh, group_of, used):
+        # w_gate's and w_down's gradients, as the share's backward takes them
+        return (G._dw(x, dh, group_of, used, 8, False),
+                G._dw(h, dy, group_of, used, 8, False))
+
+    text = _compiled_for(
+        one_v5e, dws, spec(rows, 7168), spec(rows, 2048), spec(rows, 7168),
+        spec(rows, 2048), spec(rows // G.TILE, dtype=jnp.int32),
+        spec(1, dtype=jnp.int32)).as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2

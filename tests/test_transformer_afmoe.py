@@ -183,7 +183,8 @@ def test_the_table_is_the_only_writer_of_a_layers_leaves(make):
 # chunk's exp otherwise once the rule's gradient also reads it. The loss
 # feeds nothing, so the later losses and the state are the parent's.
 # "pattern"'s lowered program re-taken when the expert shares' counters
-# gained ``gate_in_kernel``, a sixth int32 carried; losses and state held.
+# gained ``gate_in_kernel``, a sixth int32 carried, and again for
+# ``tokens_to_held_groups``, a seventh (0 here); losses and state held.
 PARENT = {
     "dense": {"losses": ["0x1.7cb0ee0000000p+2", "0x1.41f6f80000000p+2",
                          "0x1.daa90c0000000p+1"],
@@ -195,8 +196,8 @@ PARENT = {
                            "0x1.e07f7c0000000p+1"],
                 "state": "e711cab76d3d57be2a5ae0094902011627f360ba3552be6e90"
                          "180ac8fbcaa80b",
-                "lowered": "f685c0fc9566750e0b486ae733f099728264e82a38d236c0"
-                           "1b56fbfc8f79e945"},
+                "lowered": "b13e239f9cfd83867c9ff6ffca9c66f94171f3aefb1cce73"
+                           "d03251d4ea83189f"},
 }
 
 

@@ -284,7 +284,8 @@ def test_the_moved_scopes_are_in_both_tables():
         assert scopes.classify(base % inner) == ("mx.cca_mix", phase) \
             == devicetable.classify(base % inner)
     for name in ("mx.embed", "mx.layer", "mx.attn_proj", "mx.cca_mix",
-                 "mx.flash", "mx.attn_out", "mx.ffn", "mx.head_ce",
+                 "mx.mla_latent", "mx.flash", "mx.attn_out", "mx.ffn",
+                 "mx.head_ce",
                  "mx.optimizer", "mx.moe_route", "mx.moe_dispatch",
                  "mx.moe_experts", "mx.moe_combine", "mx.moe_shared",
                  "mx.ssm_proj", "mx.ssm_conv", "mx.ssm_scan", "mx.ssm_gate"):
@@ -302,11 +303,12 @@ def test_the_moved_scopes_are_in_both_tables():
 # ``assumed`` type. The fields this model added default to those programs.
 # Re-taken at PR 38 (parent 3a185ac): its head takes the gradient in its
 # forward rule, which every one of these steps runs. The two shares' again
-# when their counters gained ``gate_in_kernel``, a sixth int32 carried.
+# when their counters gained ``gate_in_kernel``, a sixth int32 carried, and
+# ``tokens_to_held_groups``, a seventh (0 where routing has no groups).
 PARENT_LOWERED = {
     "dense": "0e5c5ceeaee5c46fed63c03b26df1cf0ce512645ed09a75e5ff765163345363a",
-    "afmoe": "c775f70fdddec19aa5788203cb2c0e0d14ad9f2921ab0ed020f4bf2e528af9cd",
-    "granite": "95f0d303f63f79e928544fc3f46d638bde0b5dc144f29a0a3d2c644f40319179",
+    "afmoe": "3f33b97f39a18694743f93ae173cfbd5eca67289d27a40d53393bc4594408367",
+    "granite": "9ef25c21d6a52c72c7ddd89e098689281ad909e42d39e58483277862488159f5",
 }
 
 

@@ -11,10 +11,14 @@ only some of its k slots. These kernels do work for those alone.
 A row moves as ONE DMA of whole (8, 128) tiles: Mosaic slices a ref in HBM by
 whole tiles only, and a row of a ``[N, D]`` array is an eighth (a sixteenth,
 in 16 bits) of each of D / 128 tiles. So the source of a gather is first
-*packed*: ``[N * P, L]`` uint32, row n the P = words / L sublanes from
-``n * P``, contiguous in HBM (L = 128 lanes; 4-byte elements one a word,
+*packed*: ``[N * P8, L]`` uint32, row n the P = words / L sublanes from
+``n * P8``, contiguous in HBM (L = 128 lanes; 4-byte elements one a word,
 2-byte elements two a word: column c beside column c + D / 2, so both halves
-come apart again with a shift and a mask and no lane moves).
+come apart again with a shift and a mask and no lane moves). The stride P8
+is P rounded up to whole tiles of 8 sublanes where L is 128 (P itself
+below 128 lanes, which only the interpreter runs): a row of 28 sublanes
+(hidden 7168 in 16 bits) moves as 32, whose last four nothing writes and
+no arithmetic reads.
 
 - ``mx_moe_pack``:   [N, D] -> packed, tiles < ``used`` only
 - ``mx_moe_gather``: out[r] = row idx[r] of a packed source (x scale[r]),
@@ -28,8 +32,8 @@ Tiles behind ``used`` are grid steps that do nothing: their index maps point
 at the last tile in use, they move no data, and their rows of the output are
 never written (``grouped_matmul``'s contract). The caller keeps the plain
 ``jnp.take`` forms (``*_reference`` here) off the TPU and for widths whose
-rows are not whole tiles (``fits``); under ``interpret`` any width that packs
-into whole words runs.
+rows are not whole words of 128 lanes (``fits``); under ``interpret`` any
+width that packs into whole words runs.
 """
 from __future__ import annotations
 
@@ -52,23 +56,26 @@ _U32 = jnp.uint32
 
 
 def _words(width, dtype):
-    """(uint32 words a row, lanes L, sublanes P) of a packed row."""
+    """(uint32 words a row, lanes L, live sublanes P, stride P8) of a
+    packed row: row n lies at sublanes [n * P8, n * P8 + P)."""
     per = 4 // jnp.dtype(dtype).itemsize
     words = width // per
     lanes = min(128, words)
-    return words, lanes, words // lanes
+    sublanes = words // lanes
+    stride = -(-sublanes // 8) * 8 if lanes == 128 else sublanes
+    return words, lanes, sublanes, stride
 
 
 def fits(width, dtype, on_tpu):
     """Whether rows of ``width`` elements of ``dtype`` pack into whole
-    words, and on the TPU into whole (8, 128) tiles."""
+    words, and on the TPU into whole words of 128 lanes."""
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
-    words, lanes, sublanes = _words(width, dtype)
+    words, lanes, _, _ = _words(width, dtype)
     if width % (4 // dtype.itemsize) or words % lanes:
         return False
-    return not on_tpu or (lanes == 128 and sublanes % 8 == 0)
+    return not on_tpu or lanes == 128
 
 
 def gather_rows_reference(x, idx, scale=None, other=None):
@@ -115,7 +122,8 @@ def _from_words(w, halves):
 
 # -- mx_moe_pack --------------------------------------------------------------
 
-def _pack_kernel(used, x_ref, o_ref, *, tile, lanes, sublanes, halves):
+def _pack_kernel(used, x_ref, o_ref, *, tile, lanes, sublanes, stride,
+                 halves):
     import jax.experimental.pallas as pl
     half = lanes * sublanes
 
@@ -127,54 +135,58 @@ def _pack_kernel(used, x_ref, o_ref, *, tile, lanes, sublanes, halves):
             col = pl.multiple_of(s * lanes, lanes)
             lo = x_ref[:, pl.ds(col, lanes)]
             hi = x_ref[:, pl.ds(half + col, lanes)] if halves else None
-            o_ref[pl.ds(s, tile, stride=sublanes), :] = _to_words(lo, hi)
+            o_ref[pl.ds(s, tile, stride=stride), :] = _to_words(lo, hi)
             return c
         lax.fori_loop(0, sublanes, sublane, 0)
 
 
 def pack_rows(x, used, tile, interpret=False):
-    """x [N, D] -> [N * P, L] uint32 for tiles of ``tile`` rows < ``used``
-    [1] (the rest is never written)."""
+    """x [N, D] -> [N * P8, L] uint32 for tiles of ``tile`` rows < ``used``
+    [1] (the rest, and each row's sublanes behind its P, is never
+    written)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     n, width = x.shape
-    words, lanes, sublanes = _words(width, x.dtype)
+    words, lanes, sublanes, stride = _words(width, x.dtype)
     in_use = lambda i, used: (_tile_in_use(i, used), 0)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_pack_kernel, tile=tile, lanes=lanes,
-                          sublanes=sublanes, halves=x.dtype.itemsize == 2),
+                          sublanes=sublanes, stride=stride,
+                          halves=x.dtype.itemsize == 2),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(-(-n // tile),),
             in_specs=[pl.BlockSpec((tile, width), in_use)],
-            out_specs=pl.BlockSpec((tile * sublanes, lanes), in_use)),
-        out_shape=jax.ShapeDtypeStruct((n * sublanes, lanes), _U32),
-        compiler_params=_params(interpret, 4 * tile * words * 4),
+            out_specs=pl.BlockSpec((tile * stride, lanes), in_use)),
+        out_shape=jax.ShapeDtypeStruct((n * stride, lanes), _U32),
+        compiler_params=_params(interpret,
+                                2 * tile * (words + stride * lanes) * 4),
         interpret=interpret, name="mx_moe_pack",
     )(used, x)
 
 
 # -- mx_moe_gather ------------------------------------------------------------
 
-def _row_copy(src, buf, sem, row, place, sublanes):
+def _row_copy(src, buf, sem, row, place, stride):
+    """One packed row, all ``stride`` sublanes of it: whole tiles."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.make_async_copy(
-        src.at[pl.ds(pl.multiple_of(row * sublanes, sublanes), sublanes)],
-        buf.at[pl.ds(pl.multiple_of(place * sublanes, sublanes), sublanes)],
+        src.at[pl.ds(pl.multiple_of(row * stride, stride), stride)],
+        buf.at[pl.ds(pl.multiple_of(place * stride, stride), stride)],
         sem)
 
 
-def _await_rows(src, buf, sem, n, sublanes):
+def _await_rows(src, buf, sem, n, stride):
     """Wait for ``n`` (a multiple of ``_ISSUE``) row copies into ``buf``."""
     def wait(_, c):
         for _ in range(_ISSUE):
-            _row_copy(src, buf, sem, 0, 0, sublanes).wait()
+            _row_copy(src, buf, sem, 0, 0, stride).wait()
         return c
     lax.fori_loop(0, n // _ISSUE, wait, 0)
 
 
-def _gather_kernel(used, idx, src, *rest, tile, lanes, sublanes, halves,
-                   scaled, dotted):
+def _gather_kernel(used, idx, src, *rest, tile, lanes, sublanes, stride,
+                   halves, scaled, dotted):
     import jax.experimental.pallas as pl
     rest = list(rest)
     scale = rest.pop(0) if scaled else None
@@ -193,10 +205,10 @@ def _gather_kernel(used, idx, src, *rest, tile, lanes, sublanes, halves,
         def issue(b, c):
             for u in range(_ISSUE):
                 r = b * _ISSUE + u
-                _row_copy(src, buf, sem, idx[first + r], r, sublanes).start()
+                _row_copy(src, buf, sem, idx[first + r], r, stride).start()
             return c
         lax.fori_loop(0, tile // _ISSUE, issue, 0)
-        _await_rows(src, buf, sem, tile, sublanes)
+        _await_rows(src, buf, sem, tile, stride)
 
         def rows(c, carry):
             at = pl.multiple_of(c * chunk, chunk)
@@ -206,7 +218,7 @@ def _gather_kernel(used, idx, src, *rest, tile, lanes, sublanes, halves,
             def sublane(s, acc):
                 col = pl.multiple_of(s * lanes, lanes)
                 parts = _from_words(
-                    buf[pl.ds(at * sublanes + s, chunk, stride=sublanes), :],
+                    buf[pl.ds(at * stride + s, chunk, stride=stride), :],
                     halves)
                 for part, at_col in zip(parts, (col, half + col)):
                     if part is None:
@@ -238,7 +250,7 @@ def gather_rows(packed, idx, used, tile, width, dtype, scale=None,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     rows = idx.shape[0]
-    words, lanes, sublanes = _words(width, dtype)
+    words, lanes, sublanes, stride = _words(width, dtype)
     in_use = lambda i, used: (_tile_in_use(i, used), 0)  # noqa: E731
     # a 1-D block in SMEM is whole tiles of 1024 words: several row tiles
     listed = max(tile, _SMEM_WORDS)
@@ -259,16 +271,16 @@ def gather_rows(packed, idx, used, tile, width, dtype, scale=None,
         out_specs.append(pl.BlockSpec((tile, 1), in_use))
         out_shape.append(jax.ShapeDtypeStruct((rows, 1), jnp.float32))
     item = jnp.dtype(dtype).itemsize
-    vmem = tile * words * 4 + 2 * tile * width * item * (
+    vmem = tile * stride * lanes * 4 + 2 * tile * width * item * (
         2 if other is not None else 1) + 4 * tile * 512
     got = pl.pallas_call(
         functools.partial(_gather_kernel, tile=tile, lanes=lanes,
-                          sublanes=sublanes, halves=item == 2,
+                          sublanes=sublanes, stride=stride, halves=item == 2,
                           scaled=scale is not None, dotted=other is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(rows // tile,),
             in_specs=in_specs, out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((tile * sublanes, lanes), _U32),
+            scratch_shapes=[pltpu.VMEM((tile * stride, lanes), _U32),
                             pltpu.SemaphoreType.DMA(())]),
         out_shape=out_shape, compiler_params=_params(interpret, vmem),
         interpret=interpret, name="mx_moe_gather",
@@ -313,7 +325,7 @@ def tile_lists(rows, held, buffer_rows):
 
 
 def _sum_kernel(counts, lists, src, w_ref, out, buf, sem, *, tokens, k, bits,
-                lanes, sublanes, halves):
+                lanes, sublanes, stride, halves):
     import jax.experimental.pallas as pl
     i = pl.program_id(0)
     half = lanes * sublanes
@@ -331,10 +343,10 @@ def _sum_kernel(counts, lists, src, w_ref, out, buf, sem, *, tokens, k, bits,
         for u in range(_ISSUE):
             v = lists[b * _ISSUE + u]
             _row_copy(src, buf, sem, v & ((1 << bits) - 1), v >> bits,
-                      sublanes).start()
+                      stride).start()
         return c
     lax.fori_loop(0, n // _ISSUE, issue, 0)
-    _await_rows(src, buf, sem, n, sublanes)
+    _await_rows(src, buf, sem, n, stride)
 
     def rows(c, carry):
         at = pl.multiple_of(c * chunk, chunk)
@@ -347,8 +359,8 @@ def _sum_kernel(counts, lists, src, w_ref, out, buf, sem, *, tokens, k, bits,
             hi = lo if halves else None
             for j in range(k):
                 a, b = _from_words(
-                    buf[pl.ds((j * tokens + at) * sublanes + s, chunk,
-                              stride=sublanes), :], halves)
+                    buf[pl.ds((j * tokens + at) * stride + s, chunk,
+                              stride=stride), :], halves)
                 lo = lo + a * by[j]
                 if halves:
                     hi = hi + b * by[j]
@@ -372,14 +384,15 @@ def sum_rows(packed, lists, counts, weights, width, dtype, interpret=False):
     from jax.experimental.pallas import tpu as pltpu
     T, k = weights.shape
     tokens = tokens_of(k)
-    words, lanes, sublanes = _words(width, dtype)
+    words, lanes, sublanes, stride = _words(width, dtype)
     item = jnp.dtype(dtype).itemsize
-    bits = _row_bits(packed.shape[0] // sublanes)
-    block = (tokens * k + 1) * sublanes        # and the spare block
+    bits = _row_bits(packed.shape[0] // stride)
+    block = (tokens * k + 1) * stride          # and the spare block
     vmem = block * lanes * 4 + 2 * tokens * width * item + 4 * tokens * 512
     return pl.pallas_call(
         functools.partial(_sum_kernel, tokens=tokens, k=k, bits=bits,
-                          lanes=lanes, sublanes=sublanes, halves=item == 2),
+                          lanes=lanes, sublanes=sublanes, stride=stride,
+                          halves=item == 2),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(-(-T // tokens),),
             in_specs=[pl.BlockSpec((tokens * k,), lambda i, counts: (i,),

@@ -121,7 +121,8 @@ class MoELayer:
 # -- one chip's share of an expert-parallel layer ----------------------------
 
 MOE_STATS = ("layers", "slots_held", "slots_dropped", "max_load",
-             "rows_live", "gate_in_kernel", "tokens_to_held_groups")
+             "rows_live", "gate_in_kernel", "tokens_to_held_groups",
+             "rows_in_kernel")
 _LARGEST = tuple(n == "max_load" for n in MOE_STATS)   # the rest are sums
 
 
@@ -370,19 +371,20 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
     w_down: [held, F, D]; ``shared``: (w_gate [D, Fs], w_up, w_down
     [Fs, D]) of the shared expert, computed for every token.
     -> (y [B, S, D]: shared(x) + sum over the chosen experts HELD of weight
-    * expert(x), int32 [7] as ``MOE_STATS`` names them: 1, slots routed to
+    * expert(x), int32 [8] as ``MOE_STATS`` names them: 1, slots routed to
     experts held, those of them that no product covered (0: the buffer
     holds every slot), the largest load of an expert held, the buffer rows
     in use (the slots held and their groups' padding to whole tiles), 1
     where the gate ran inside the grouped-product kernels and 0 where it
     ran in XLA, the tokens whose kept groups hold an expert held (0 where
-    the routing is not limited to groups)).
+    the routing is not limited to groups), 1 where the rows moved through
+    the ``mx_moe_*`` kernels and 0 where through ``jnp.take``).
 
     The buffer is sized for every slot there is; a step touches the prefix
     its routing fills (``_plan``) and a token's held slots only, so the
     time around the products follows the slots held, as theirs does. On the
     TPU (and under ``interpret``) the rows move through the ``mx_moe_*``
-    kernels; elsewhere, or where a row is not whole tiles
+    kernels; elsewhere, or where a row is not whole words of 128 lanes
     (``moe_rows.fits``), through ``jnp.take``. There too the gate between
     the experts' first two products runs inside them
     (``grouped_matmul.grouped_glu``) where its kernels fit VMEM
@@ -443,7 +445,8 @@ def moe_share(x, router_w, bias, w_gate, w_up, w_down, shared=None, *, k,
                        n_held_slots - jnp.sum(plan.live, dtype=jnp.int32),
                        jnp.max(plan.counts),
                        jnp.sum(plan.sizes, dtype=jnp.int32),
-                       jnp.int32(in_kernel), to_held])
+                       jnp.int32(in_kernel), to_held,
+                       jnp.int32(how is not None)])
     if route == "mlp_softmax":
         return y.reshape(B, S, D), stats, r.reshape(B, S, -1)
     return y.reshape(B, S, D), stats
@@ -455,7 +458,7 @@ def merge_stats(a, b):
 
 
 def sum_stats(stats):
-    """[n, 7] ``MOE_STATS`` of n calls as one: sums, and the largest load."""
+    """[n, 8] ``MOE_STATS`` of n calls as one: sums, and the largest load."""
     return jnp.where(jnp.array(_LARGEST), jnp.max(stats, axis=0),
                      jnp.sum(stats, axis=0))
 
@@ -486,7 +489,9 @@ def moe_stats():
     kernels, and the rest: in XLA between separate products),
     ``tokens_to_held_groups`` (where the routing is limited to groups: the
     tokens, a layer call each, whose kept groups hold an expert held; 0
-    where no step's routing is)."""
+    where no step's routing is), ``rows_in_kernel`` and ``rows_apart``
+    (the calls whose rows moved through the ``mx_moe_*`` kernels, and the
+    rest: through ``jnp.take`` over every buffer row)."""
     import numpy as np
     total = np.zeros(len(MOE_STATS), np.int64)
     experts = rows = 0
@@ -504,6 +509,7 @@ def moe_stats():
                         if out["layers"] and experts else 0.0)
     out["live_share"] = out["rows_live"] / rows if rows else 0.0
     out["gate_apart"] = out["layers"] - out["gate_in_kernel"]
+    out["rows_apart"] = out["layers"] - out["rows_in_kernel"]
     return out
 
 

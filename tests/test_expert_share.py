@@ -77,9 +77,9 @@ def _share(w, first, held, shared=True, bias=None):
 def test_a_share_is_the_loop_over_the_experts_it_holds(weights, first, held):
     y, stats = _share(weights, first, held)
     assert float(jnp.max(jnp.abs(y - _loop(weights, first, held)))) < 1e-5
-    layers, slots, dropped, most, live, inside, to_held = (
+    layers, slots, dropped, most, live, inside, to_held, moved = (
         int(v) for v in stats)
-    assert (layers, dropped, inside) == (1, 0, 0)   # off the kernels
+    assert (layers, dropped, inside, moved) == (1, 0, 0, 0)  # off the kernels
     assert to_held == 0                              # routed without groups
     assert most <= slots <= B * S * K
     assert slots <= live <= slots + held * 256 and live % 256 == 0
@@ -112,14 +112,15 @@ def test_routing_as_uneven_as_it_can_be_drops_nothing(weights):
     slots to experts held elsewhere): that expert computes all B*S slots."""
     bias = jnp.zeros(E).at[5].set(10.0).at[jnp.array([0, 1, 2])].set(5.0)
     y, stats = _share(weights, 4, 4, shared=False, bias=bias)
-    assert [int(v) for v in stats] == [1, B * S, 0, B * S, 4 * 256, 0, 0]
+    assert [int(v) for v in stats] == [1, B * S, 0, B * S, 4 * 256, 0, 0,
+                                       0]
     want = _loop(weights, 4, 4, shared=False, bias=bias)
     assert float(jnp.max(jnp.abs(y - want))) < 1e-5
     # and every slot of every token to the four held: 4 x B*S slots
     bias = jnp.zeros(E).at[jnp.arange(4, 8)].set(10.0)
     y, stats = _share(weights, 4, 4, shared=False, bias=bias)
     assert [int(v) for v in stats] == [1, 4 * B * S, 0, B * S, 4 * 256, 0,
-                                       0]
+                                       0, 0]
     assert float(jnp.max(jnp.abs(
         y - _loop(weights, 4, 4, shared=False, bias=bias)))) < 1e-5
 
@@ -139,14 +140,14 @@ def test_the_shares_sum_to_the_model(weights):
 
 
 def test_counters_merge_by_sum_and_by_largest_load():
-    a = jnp.array([1, 10, 0, 7, 512, 1, 0])
-    b = jnp.array([2, 5, 1, 9, 768, 2, 30])
-    assert X.merge_stats(a, b).tolist() == [3, 15, 1, 9, 1280, 3, 30]
+    a = jnp.array([1, 10, 0, 7, 512, 1, 0, 1])
+    b = jnp.array([2, 5, 1, 9, 768, 2, 30, 0])
+    assert X.merge_stats(a, b).tolist() == [3, 15, 1, 9, 1280, 3, 30, 1]
     assert X.sum_stats(jnp.stack([a, b, b])).tolist() == [
-        5, 20, 2, 9, 2048, 5, 60]
+        5, 20, 2, 9, 2048, 5, 60, 1]
     assert X.MOE_STATS == ("layers", "slots_held", "slots_dropped",
                            "max_load", "rows_live", "gate_in_kernel",
-                           "tokens_to_held_groups")
+                           "tokens_to_held_groups", "rows_in_kernel")
 
 
 def test_through_the_grouped_product_kernels_it_is_the_loop_too(weights,
@@ -265,14 +266,20 @@ def _planned(case, T, kernels):
     return plan, ((TILE_T, True) if kernels else None)
 
 
-def _operands(plan, T, dtype):
+def _operands(plan, T, dtype, width=DM):
+    """The movements' operands. Past ``DM`` columns the weights are eighths:
+    a bfloat16 element times such a weight is exact in float32, so a sum is
+    the same to the bit whether or not XLA's CPU fuses a multiply into the
+    add after it (it does in the interpreted kernels, not in ``jnp.take``'s
+    sums, and at 768 columns one lane of 98,304 then rounds otherwise)."""
     ks = jr.split(jr.PRNGKey(11), 5)
     rows = plan.slot_of.shape[0]
-    return {"x": jr.normal(ks[0], (T, DM)).astype(dtype),
-            "ys": jr.normal(ks[1], (rows, DM)).astype(dtype),
-            "w": jr.uniform(ks[2], (T, K)) + 0.1,
-            "g_rows": jr.normal(ks[3], (rows, DM)).astype(dtype),
-            "g_tokens": jr.normal(ks[4], (T, DM)).astype(dtype)}
+    w = jr.uniform(ks[2], (T, K)) + 0.1
+    return {"x": jr.normal(ks[0], (T, width)).astype(dtype),
+            "ys": jr.normal(ks[1], (rows, width)).astype(dtype),
+            "w": w if width == DM else jnp.round(w * 8) / 8,
+            "g_rows": jr.normal(ks[3], (rows, width)).astype(dtype),
+            "g_tokens": jr.normal(ks[4], (T, width)).astype(dtype)}
 
 
 def _movements(plan, how, o, dtype):
@@ -289,16 +296,25 @@ def _movements(plan, how, o, dtype):
 
 MOVES = [("even", 128), ("one_expert", 128), ("none_held", 128),
          ("all_experts", 128), ("ragged_tokens", 80)]
+# rows of 64 columns pack into fewer than 128 lanes; bfloat16 rows of 768
+# and 1792 columns into 128 lanes and 3 and 7 sublanes, laid at a stride
+# of 8: rows that are not whole (8, 128) tiles, as hidden 7168's 28
+WIDTHS = [("bfloat16", DM), ("float32", DM), ("bfloat16", 768),
+          ("bfloat16", 1792)]
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("dtype,width", WIDTHS, ids=[
+    d if w == DM else "%s-%d" % (d, w) for d, w in WIDTHS])
 @pytest.mark.parametrize("case,T", MOVES, ids=[c for c, _ in MOVES])
 def test_the_kernels_move_the_rows_the_gathers_move(small_tiles, case, T,
-                                                     dtype):
+                                                     dtype, width):
+    from mxnet_tpu.pallas_kernels import moe_rows
     dtype = jnp.dtype(dtype)
     plan, how = _planned(case, T, kernels=True)
     plain, _ = _planned(case, T, kernels=False)
-    o = _operands(plan, T, dtype)
+    o = _operands(plan, T, dtype, width)
+    _, lanes, sublanes, stride = moe_rows._words(width, dtype)
+    assert stride == (8 if width > DM else sublanes)
     used, rows = int(plan.used[0]), plan.slot_of.shape[0]
     assert used * TILE_T == int(jnp.sum(plan.sizes))
     if case == "one_expert":        # all but that expert's spare tile
@@ -318,13 +334,13 @@ def test_the_kernels_move_the_rows_the_gathers_move(small_tiles, case, T,
                 1.0 + float(jnp.max(jnp.abs(w)))), name
 
 
-def test_nothing_not_finite_comes_in_from_the_rows_no_slot_uses(small_tiles):
+def _nothing_not_finite(width):
     """NaN behind the tiles in use, in the rows of tokens none of whose
     slots is held, and in the cotangents behind the tiles in use: every
     output is finite, the padding rows of the tiles in use included."""
     T, dtype = 128, jnp.bfloat16
     plan, how = _planned("even", T, kernels=True)
-    o = _operands(plan, T, dtype)
+    o = _operands(plan, T, dtype, width)
     in_use = int(plan.used[0]) * TILE_T
     assert in_use < plan.slot_of.shape[0] - TILE_T
     behind = (jnp.arange(plan.slot_of.shape[0]) >= in_use)[:, None]
@@ -340,6 +356,79 @@ def test_nothing_not_finite_comes_in_from_the_rows_no_slot_uses(small_tiles):
     xs, _, _, dys, _ = _movements(plan, how, o, dtype)
     assert bool(jnp.all(xs[padding] == o["x"][0]))
     assert bool(jnp.all(dys[padding] == 0))
+
+
+def test_nothing_not_finite_comes_in_from_the_rows_no_slot_uses(small_tiles):
+    _nothing_not_finite(DM)
+
+
+def test_nothing_not_finite_comes_in_from_a_packed_rows_spare_sublanes(
+        small_tiles, monkeypatch):
+    """The same at 1792 columns, 7 sublanes a row at a stride of 8: what
+    the pack leaves in a row's eighth sublane (NaN here, as the packs are
+    made) reaches no output."""
+    from mxnet_tpu.pallas_kernels import moe_rows
+    pack = moe_rows.pack_rows
+
+    def spoilt(x, used, tile, interpret=False):
+        packed = pack(x, used, tile, interpret)
+        spare = jnp.arange(packed.shape[0]) % 8 == 7
+        return jnp.where(spare[:, None], jnp.uint32(0x7fc07fc0), packed)
+
+    monkeypatch.setattr(moe_rows, "pack_rows", spoilt)
+    assert moe_rows._words(1792, jnp.bfloat16)[2:] == (7, 8)
+    _nothing_not_finite(1792)
+
+
+def test_rows_pack_into_128_lanes_at_a_stride_of_whole_tiles():
+    """On the TPU a row fits where it packs into whole words of 128 lanes:
+    hidden 7168 in bfloat16 (28 sublanes) now does, at a stride of 32. At
+    hidden 2048 and 4096 (cells with experts at 8 and 16 sublanes) the
+    stride is the row itself: their packs are the layout there was."""
+    from mxnet_tpu.pallas_kernels import moe_rows
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert moe_rows.fits(7168, bf16, True)
+    assert moe_rows._words(7168, bf16) == (3584, 128, 28, 32)
+    for width, sublanes in ((2048, 8), (4096, 16)):
+        assert moe_rows._words(width, bf16)[2:] == (sublanes, sublanes)
+        assert moe_rows.fits(width, bf16, True)
+    assert moe_rows._words(4096, f32)[2:] == (32, 32)
+    # fewer than 128 lanes, or words that are not whole: the interpreter's
+    assert not moe_rows.fits(64, bf16, True) and moe_rows.fits(64, bf16, False)
+    assert not moe_rows.fits(7169, bf16, False)
+    assert not moe_rows.fits(1000, bf16, True)
+    assert not moe_rows.fits(2048, jnp.float16, False)
+
+
+def test_rows_in_kernel_counts_the_calls_whose_rows_the_kernels_moved(
+        weights, monkeypatch):
+    """``rows_in_kernel``, the eighth counter: 1 for a share whose rows
+    move through the ``mx_moe_*`` kernels (interpret mode), 0 on the plain
+    gathers; ``metrics()["moe"]["rows_apart"]`` is ``layers`` less it."""
+    import importlib
+    import mxnet_tpu as mx
+    gmm = importlib.import_module("mxnet_tpu.pallas_kernels.grouped_matmul")
+    monkeypatch.setattr(gmm, "TILE", 32)
+    sl = slice(4, 8)
+
+    def stats(interpret):
+        return X.moe_share(weights["x"], weights["router"], weights["bias"],
+                           weights["w_gate"][sl], weights["w_up"][sl],
+                           weights["w_down"][sl], None, k=K, first=4,
+                           route_scale=SCALE, interpret=interpret)[1]
+
+    moved, apart = stats(True), stats(False)
+    assert (int(moved[7]), int(apart[7])) == (1, 0)
+    rows = X.buffer_rows(B * S, K, 4, 32)
+    before = mx.profiler.metrics()["moe"]
+    holder = _Holder(X.sum_stats(jnp.stack([moved, moved, apart])), 4, rows)
+    X.track(holder)
+    after = mx.profiler.metrics()["moe"]
+    assert after["layers"] - before["layers"] == 3
+    assert after["rows_in_kernel"] - before["rows_in_kernel"] == 2
+    assert after["rows_apart"] - before["rows_apart"] == 1
+    assert after["rows_apart"] == after["layers"] - after["rows_in_kernel"]
+    holder.moe_counters = None
 
 
 def test_the_share_through_the_kernels_is_the_share_through_the_gathers(

@@ -186,16 +186,17 @@ def test_the_gated_pair_lowers_at_the_expert_cells_widths(monkeypatch, rows,
         assert name in text, name
 
 
-def test_the_expert_shares_row_movements_lower_at_the_trinity_cells_shape():
-    """16384 tokens x 8 slots of width 2048 in bfloat16, 32 experts held:
-    dispatch, combine and both transposes through ``mx_moe_pack``,
-    ``mx_moe_gather`` and ``mx_moe_sum``: a pack and a movement each."""
+def _row_movements(T, k, D, held, sharding=None):
+    """T tokens x k slots of width D in bfloat16, ``held`` experts on tiles
+    of 256: dispatch, combine and both transposes through ``mx_moe_pack``,
+    ``mx_moe_gather`` and ``mx_moe_sum``. -> (the function, its argument
+    shapes, the buffer's rows)."""
     import jax.numpy as jnp
     from mxnet_tpu.parallel import expert as X
     from mxnet_tpu.pallas_kernels import moe_rows
-    T, k, D, held, tile = 16384, 8, 2048, 32, 256
+    tile = 256
     rows = X.buffer_rows(T, k, held, tile)
-    assert rows == 139264 and moe_rows.fits(D, jnp.bfloat16, True)
+    assert moe_rows.fits(D, jnp.bfloat16, True)
     how = (tile, False)
 
     def movements(experts, x, ys, w, g_rows, g_tokens):
@@ -207,17 +208,36 @@ def test_the_expert_shares_row_movements_lower_at_the_trinity_cells_shape():
             ys, w, plan, how, jnp.bfloat16), ys, w)
         return (xs, y) + back(g_rows) + back_y(g_tokens)
 
-    s = jax.ShapeDtypeStruct
-    exported = jax.export.export(jax.jit(movements), platforms=["tpu"])(
-        s((T, k), jnp.int32), s((T, D), jnp.bfloat16),
-        s((rows, D), jnp.bfloat16), s((T, k), jnp.float32),
-        s((rows, D), jnp.bfloat16), s((T, D), jnp.bfloat16))
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return movements, (s((T, k), jnp.int32), s((T, D)), s((rows, D)),
+                       s((T, k), jnp.float32), s((rows, D)), s((T, D))), rows
+
+
+def _row_movements_lower(T, k, D, held):
+    movements, args, rows = _row_movements(T, k, D, held)
+    exported = jax.export.export(jax.jit(movements), platforms=["tpu"])(*args)
     assert [a.shape for a in exported.out_avals] == [
         (rows, D), (T, D), (T, D), (rows, D), (T, k)]
     text = exported.mlir_module()
     assert text.count(chip_smoke.MOSAIC_CALL) == 8
     for name in ("mx_moe_pack", "mx_moe_gather", "mx_moe_sum"):
         assert name in text, name
+    return rows
+
+
+def test_the_expert_shares_row_movements_lower_at_the_trinity_cells_shape():
+    """16384 tokens x 8 slots of width 2048 in bfloat16, 32 experts held:
+    a pack and a movement each, rows of 8 sublanes at a stride of 8."""
+    assert _row_movements_lower(16384, 8, 2048, 32) == 139264
+
+
+def test_the_expert_shares_row_movements_lower_at_the_gigachat_cells_shape():
+    """4096 tokens x 8 slots of width 7168 in bfloat16, 8 experts held:
+    rows of 28 sublanes, packed at a stride of 32, through the same eight
+    kernels."""
+    assert _row_movements_lower(4096, 8, 7168, 8) == 34816
 
 
 @pytest.fixture(scope="module")
@@ -346,3 +366,19 @@ def test_the_latent_attention_cells_kernels_compile_on_a_v5e(one_v5e,
         spec(rows, 2048), spec(rows // G.TILE, dtype=jnp.int32),
         spec(1, dtype=jnp.int32)).as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+
+
+def test_the_expert_shares_row_movements_compile_on_a_v5e_at_hidden_7168(
+        one_v5e):
+    """The GigaChat3.1 share's row movements (4096 tokens x 8 slots, 8
+    experts held, rows of 7168 in bfloat16: 28 sublanes at a stride of 32)
+    compiled for the described chip: the Mosaic compiler takes the strided
+    packs and the row copies of 32 sublanes, each kernel's VMEM under its
+    limit."""
+    movements, args, rows = _row_movements(4096, 8, 7168, 8, one_v5e)
+    text = _compiled_for(one_v5e, movements, *args).as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 8
+    for name in ("mx_moe_pack", "mx_moe_gather", "mx_moe_sum"):
+        assert name in text, name
+    # the packed buffer: 34816 rows of 32 sublanes of 128 words
+    assert "u32[%d,128]" % (rows * 32) in text

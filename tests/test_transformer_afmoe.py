@@ -183,8 +183,9 @@ def test_the_table_is_the_only_writer_of_a_layers_leaves(make):
 # chunk's exp otherwise once the rule's gradient also reads it. The loss
 # feeds nothing, so the later losses and the state are the parent's.
 # "pattern"'s lowered program re-taken when the expert shares' counters
-# gained ``gate_in_kernel``, a sixth int32 carried, and again for
-# ``tokens_to_held_groups``, a seventh (0 here); losses and state held.
+# gained ``gate_in_kernel``, a sixth int32 carried, again for
+# ``tokens_to_held_groups``, a seventh (0 here), and for ``rows_in_kernel``,
+# an eighth (0 here: off the kernels); losses and state held.
 PARENT = {
     "dense": {"losses": ["0x1.7cb0ee0000000p+2", "0x1.41f6f80000000p+2",
                          "0x1.daa90c0000000p+1"],
@@ -196,8 +197,8 @@ PARENT = {
                            "0x1.e07f7c0000000p+1"],
                 "state": "e711cab76d3d57be2a5ae0094902011627f360ba3552be6e90"
                          "180ac8fbcaa80b",
-                "lowered": "b13e239f9cfd83867c9ff6ffca9c66f94171f3aefb1cce73"
-                           "d03251d4ea83189f"},
+                "lowered": "6499bec6e7b9c4d4d109d893c4213e635102c4a2fa99a25a"
+                           "974e46e6f08d4616"},
 }
 
 

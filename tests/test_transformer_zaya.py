@@ -303,12 +303,13 @@ def test_the_moved_scopes_are_in_both_tables():
 # ``assumed`` type. The fields this model added default to those programs.
 # Re-taken at PR 38 (parent 3a185ac): its head takes the gradient in its
 # forward rule, which every one of these steps runs. The two shares' again
-# when their counters gained ``gate_in_kernel``, a sixth int32 carried, and
-# ``tokens_to_held_groups``, a seventh (0 where routing has no groups).
+# when their counters gained ``gate_in_kernel``, a sixth int32 carried,
+# ``tokens_to_held_groups``, a seventh (0 where routing has no groups), and
+# ``rows_in_kernel``, an eighth (0 off the row kernels).
 PARENT_LOWERED = {
     "dense": "0e5c5ceeaee5c46fed63c03b26df1cf0ce512645ed09a75e5ff765163345363a",
-    "afmoe": "3f33b97f39a18694743f93ae173cfbd5eca67289d27a40d53393bc4594408367",
-    "granite": "9ef25c21d6a52c72c7ddd89e098689281ad909e42d39e58483277862488159f5",
+    "afmoe": "814a1290f4a51f759d33f50b0fa65007798fb7bca690715a92872e6317486fc1",
+    "granite": "6d97e7686e12b1f43fc5043b95c6b026db4fc930480ae2b35ecfb984e91abfbb",
 }
 
 
